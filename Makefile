@@ -116,7 +116,7 @@ comm-cost:
 # attention/fused-kernel microbenchmark: XLA dense vs pallas vs chunked at
 # H in {50,1024,2048,4096} plus the fused hot-path legs (B in {256,1024} +
 # the gather+encode leg); refuses to run off-TPU (interpret mode measures
-# nothing) — benchmarks/chip_watcher.sh queues it for the next live window
+# nothing)
 pallas-bench:
 	@python benchmarks/pallas_bench.py
 

@@ -73,7 +73,7 @@ def measured_leg() -> dict:
     import jax.numpy as jnp
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-    from fedrec_tpu.compat import shard_map
+    from jax import shard_map
     from fedrec_tpu.shard.table import (
         ShardedNewsTable, a2a_bytes_per_gather, owner_bucketed_gather,
     )

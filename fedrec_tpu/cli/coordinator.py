@@ -233,8 +233,12 @@ def main(argv: list[str] | None = None) -> int:
     original_argv = list(sys.argv[1:] if argv is None else argv)
     args = parser.parse_args(argv)
     if args.supervise:
-        return _supervise(original_argv)
+        return _supervise(original_argv)  # stays off jax: its child owns the device
     supervised = os.environ.get("FEDREC_SUPERVISED") == "1"
+
+    from fedrec_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     from fedrec_tpu.parallel.multihost import (
         REFORM_SIGNAL,
@@ -367,7 +371,8 @@ def main(argv: list[str] | None = None) -> int:
     cfg.fed.num_clients = args.clients or len(jax.local_devices())
     # record the data source IN the config (config.json provenance);
     # --set data.* overrides below still win over the CLI flags
-    cfg.data.data_dir = args.data_dir
+    if args.data_dir is not None:
+        cfg.data.data_dir = args.data_dir
     if args.synthetic:
         cfg.data.dataset = "synthetic"
     cfg.apply_overrides(args.overrides)
@@ -479,10 +484,17 @@ def main(argv: list[str] | None = None) -> int:
                     f"{token_states.shape[0]} catalog rows from the table "
                     "checkpoint"
                 )
+        if token_states is None and cfg.data.dataset != "synthetic":
+            print(f"[coordinator] ERROR: no token states at {token_path}; "
+                  "precompute them or pass --token-states (or use "
+                  "--synthetic for a random catalog)", file=sys.stderr)
+            return 2
         if token_states is None:
+            # every process draws the same table from the same seed
             token_states = np.random.default_rng(0).standard_normal(
-                (data.num_news, data.title_len, cfg.model.bert_hidden)
-            ).astype(np.float32)
+                (data.num_news, data.title_len, cfg.model.bert_hidden),
+                dtype=np.float32,
+            )
 
     if args.dp_epsilon > 0:
         cfg.privacy.enabled = True

@@ -28,7 +28,7 @@ import numpy as np
 
 def build_parser() -> argparse.ArgumentParser:
     p = argparse.ArgumentParser(description=__doc__)
-    p.add_argument("--data-dir", default="/root/reference/UserData",
+    p.add_argument("--data-dir", required=True,
                    help="reference UserData/ artifact layout")
     p.add_argument("--token-states", default=None,
                    help="(N, L, bert_hidden) .npy of cached trunk states")
@@ -62,6 +62,9 @@ def collect_histories(data, max_his_len: int) -> dict[str, list[str]]:
 
 
 def main(argv: list[str] | None = None) -> int:
+    from fedrec_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     args = build_parser().parse_args(argv)
 
     import jax

@@ -38,12 +38,14 @@ def build_parser() -> argparse.ArgumentParser:
                    choices=["local", "grad_avg", "param_avg", "coordinator"])
     p.add_argument("--clients", type=int, default=None,
                    help="default: all visible devices")
-    p.add_argument("--data-dir", default="/root/reference/UserData",
-                   help="directory with bert_news_index.npy etc.")
+    p.add_argument("--data-dir", default=None,
+                   help="directory with bert_news_index.npy etc. (default: "
+                        "data.data_dir; must exist unless --synthetic)")
     p.add_argument("--token-states", default=None,
                    help="path to cached (N, L, H) trunk token states .npy; "
-                        "default <data-dir>/token_states.npy if present, else "
-                        "random states (smoke mode)")
+                        "default <data-dir>/token_states.npy. Required with "
+                        "--data-dir artifacts; with --synthetic a missing "
+                        "file means random states made on the device")
     p.add_argument("--dp-epsilon", type=float, default=0.0,
                    help="enable LDP with this epsilon (reference argv 4; 0 = off)")
     p.add_argument("--local-epochs", type=int, default=1)
@@ -70,6 +72,12 @@ def build_parser() -> argparse.ArgumentParser:
     return p
 
 
+# share of the synthetic catalog that positives are drawn from
+# (make_synthetic_mind's popularity signal); random_token_states marks the
+# same rows so the signal is visible to the text tower
+_SYNTHETIC_POPULAR_FRAC = 0.2
+
+
 def make_synthetic_from_args(args, cfg):
     """Shared synthetic-corpus construction for the run and coordinator
     drivers (one definition of the valid-set sizing)."""
@@ -78,19 +86,62 @@ def make_synthetic_from_args(args, cfg):
     return make_synthetic_mind(
         num_news=args.synthetic_news, num_train=args.synthetic_train,
         num_valid=max(args.synthetic_train // 8, 32),
-        title_len=cfg.data.max_title_len, popular_frac=0.2,
+        title_len=cfg.data.max_title_len,
+        popular_frac=_SYNTHETIC_POPULAR_FRAC,
     )
 
 
-def main(argv: list[str] | None = None) -> int:
-    args = build_parser().parse_args(argv)
+def random_token_states(
+    num_news: int, title_len: int, hidden: int, dtype, popular_frac: float
+):
+    """Random ``(num_news, title_len, hidden)`` trunk states for synthetic
+    runs, made on the device in the table's dtype a chunk of rows at a time.
 
+    The host never holds the table and the device holds it once, so a
+    MIND-small catalog (65,536 x 50 x 768 bf16, 5.0 GB) is reachable; a
+    float64 host draw of the same table is ~20 GB. The rows
+    ``make_synthetic_mind`` draws positives from (ids 1..popular) share one
+    offset direction, which gives the text tower something to learn.
+    """
+    from functools import partial
+
+    import jax
+    import jax.numpy as jnp
+
+    dtype = jnp.dtype(dtype)
+    chunk_rows = min(2048, num_news)
+    n_popular = int(popular_frac * num_news)
+    root = jax.random.PRNGKey(0)
+    offset = jax.random.normal(jax.random.fold_in(root, num_news), (hidden,))
+    offset = offset / jnp.linalg.norm(offset) * jnp.sqrt(hidden / 8.0)
+
+    @partial(jax.jit, donate_argnums=0)
+    def fill(buf, start):
+        block = jax.random.normal(
+            jax.random.fold_in(root, start), (chunk_rows, title_len, hidden)
+        )
+        rows = start + jnp.arange(chunk_rows)
+        popular = ((rows >= 1) & (rows <= n_popular))[:, None, None]
+        block = block + jnp.where(popular, offset, 0.0)
+        return jax.lax.dynamic_update_slice(
+            buf, block.astype(dtype), (start, 0, 0)
+        )
+
+    buf = jnp.zeros((num_news, title_len, hidden), dtype)
+    # a short last chunk is re-anchored so that it ends at the last row
+    for start in range(0, num_news, chunk_rows):
+        buf = fill(buf, jnp.int32(min(start, num_news - chunk_rows)))
+    return buf
+
+
+def load_inputs(args):
+    """``(cfg, data, token_states)`` for parsed arguments: everything
+    ``Trainer`` needs. Returns ``None`` (after printing why) when the
+    arguments name no usable data."""
     import jax
 
     from fedrec_tpu.config import ExperimentConfig
     from fedrec_tpu.data import load_mind_artifacts
-    from fedrec_tpu.privacy import calibrate_from_config
-    from fedrec_tpu.train.trainer import Trainer
 
     cfg = ExperimentConfig()
     cfg.fed.rounds = args.total_epochs
@@ -107,13 +158,20 @@ def main(argv: list[str] | None = None) -> int:
     # record the data source IN the config (snapshot config.json is the
     # provenance record of what a run trained on); --set data.* overrides
     # below still win over the CLI flags
-    cfg.data.data_dir = args.data_dir
+    if args.data_dir is not None:
+        cfg.data.data_dir = args.data_dir
     if args.synthetic:
         cfg.data.dataset = "synthetic"
     cfg.apply_overrides(args.overrides)
 
-    if cfg.data.dataset == "synthetic":
+    synthetic = cfg.data.dataset == "synthetic"
+    if synthetic:
         data = make_synthetic_from_args(args, cfg)
+    elif not Path(cfg.data.data_dir).is_dir():
+        print(f"[run] ERROR: no data directory {cfg.data.data_dir!r}; pass "
+              "--data-dir (or --synthetic for a generated corpus)",
+              file=sys.stderr)
+        return None
     else:
         # "mind" and "adressa" share the artifact schema (the Adressa
         # preprocessor writes the exact UserData/ layout), so one loader
@@ -123,15 +181,34 @@ def main(argv: list[str] | None = None) -> int:
     token_path = args.token_states or str(Path(cfg.data.data_dir) / "token_states.npy")
     if Path(token_path).exists():
         token_states = np.load(token_path)
+    elif synthetic:
+        token_states = random_token_states(
+            data.num_news, data.title_len, cfg.model.bert_hidden,
+            cfg.model.dtype, _SYNTHETIC_POPULAR_FRAC,
+        )
     else:
         print(
-            f"[run] no cached token states at {token_path}; using random states "
-            "(smoke mode — precompute with fedrec_tpu.models.bert for real runs)",
+            f"[run] ERROR: no token states at {token_path}; precompute them "
+            "with fedrec_tpu.models.bert or pass --token-states (or use "
+            "--synthetic for a random catalog)",
             file=sys.stderr,
         )
-        token_states = np.random.default_rng(0).standard_normal(
-            (data.num_news, data.title_len, cfg.model.bert_hidden)
-        ).astype(np.float32)
+        return None
+    return cfg, data, token_states
+
+
+def main(argv: list[str] | None = None) -> int:
+    from fedrec_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+    args = build_parser().parse_args(argv)
+    inputs = load_inputs(args)
+    if inputs is None:
+        return 2
+    cfg, data, token_states = inputs
+
+    from fedrec_tpu.privacy import calibrate_from_config
+    from fedrec_tpu.train.trainer import Trainer
 
     if args.dp_epsilon > 0:
         cfg.privacy.enabled = True

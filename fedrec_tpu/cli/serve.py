@@ -57,7 +57,9 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--synthetic", type=int, default=0, metavar="N",
                    help="serve a random N-item catalog with fresh-init params "
                         "(no artifacts needed; scores are meaningless)")
-    p.add_argument("--data-dir", default="/root/reference/UserData")
+    p.add_argument("--data-dir", default=None,
+                   help="reference UserData layout (required unless "
+                        "--synthetic)")
     p.add_argument("--snapshot-dir", default=None)
     p.add_argument("--token-states", default=None,
                    help="(N, L, bert_hidden) .npy of cached trunk states")
@@ -105,6 +107,10 @@ def _checkpoint_service(args, cfg):
     from fedrec_tpu.models import NewsRecommender
     from fedrec_tpu.serving.store import EmbeddingStore, publish_from_checkpoint
 
+    if args.data_dir is None:
+        print("[serve] ERROR: --data-dir is required unless --synthetic",
+              file=sys.stderr)
+        return None
     snap_dir = args.snapshot_dir or cfg.train.snapshot_dir
     data = load_mind_artifacts(args.data_dir)
     token_path = args.token_states or str(Path(args.data_dir) / "token_states.npy")
@@ -147,16 +153,9 @@ def _service(args, cfg, model, store, id_map):
     )
 
 
-def main(argv: list[str] | None = None) -> int:
-    args = build_parser().parse_args(argv)
-
-    from fedrec_tpu.config import ExperimentConfig
-    from fedrec_tpu.serving import serve_forever
-    from fedrec_tpu.utils.logging import MetricLogger
-
-    cfg = ExperimentConfig()
-    cfg.apply_overrides(args.overrides)
-
+def build_service(args, cfg):
+    """The warmed-up :class:`ServingService` for parsed arguments, or
+    ``None`` (after printing why) when they name no servable catalog."""
     if args.shard_store and args.clusters:
         print(
             "[serve] ERROR: --shard-store pairs with exact retrieval only "
@@ -164,13 +163,13 @@ def main(argv: list[str] | None = None) -> int:
             "--clusters or --shard-store",
             file=sys.stderr,
         )
-        return 2
+        return None
     service = (
         _synthetic_service(args, cfg) if args.synthetic
         else _checkpoint_service(args, cfg)
     )
     if service is None:
-        return 2
+        return None
     if cfg.obs.quality.enabled and cfg.obs.quality.probe_users > 0:
         # pre-swap drift probe: every {"cmd":"refresh"} hot-swap scores
         # the pinned probe set against both generations first, so a bad
@@ -181,6 +180,24 @@ def main(argv: list[str] | None = None) -> int:
             seed=cfg.obs.quality.seed,
         )
     service.warmup()  # compile every bucket before accepting traffic
+    return service
+
+
+def main(argv: list[str] | None = None) -> int:
+    from fedrec_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+    args = build_parser().parse_args(argv)
+
+    from fedrec_tpu.config import ExperimentConfig
+    from fedrec_tpu.serving import serve_forever
+    from fedrec_tpu.utils.logging import MetricLogger
+
+    cfg = ExperimentConfig()
+    cfg.apply_overrides(args.overrides)
+    service = build_service(args, cfg)
+    if service is None:
+        return 2
     import os as _os
 
     from fedrec_tpu.obs import ensure_fleet_identity, get_tracer

@@ -119,12 +119,12 @@ class ModelConfig:
     sigmoid_before_ce: bool = True
     dtype: str = "float32"             # compute dtype for encoders ("bfloat16" on TPU)
     # Route hot ops through the ISOLATED Pallas kernels. EXPERIMENTAL
-    # OPT-IN: at every chip-measured size so far the XLA dense path wins
-    # (20-dim heads pad to 128 lanes; benchmarks/pallas_bench.json). In
-    # the one regime needing O(L) attention — training at H>=2048, dense
-    # fwd+bwd OOM — the r3 chip window measured pallas AHEAD of the
-    # chunked scan (255 vs 299 ms fwd+bwd at H=2048), so this opt-in is
-    # the measured-better choice there. For the reference H=50 scale the
+    # OPT-IN: in the last chip measurement (jax 0.4.37; its artifact is no
+    # longer in the tree, and nothing is measured on the current one —
+    # ROADMAP S4) the XLA dense path won at every size that fit (20-dim
+    # heads pad to 128 lanes), and in the one regime needing O(L)
+    # attention — training at H>=2048, dense fwd+bwd OOM — pallas was
+    # AHEAD of the chunked scan (255 vs 299 ms fwd+bwd at H=2048). For the reference H=50 scale the
     # measured answer is fuse_hot_path below — isolated kernels lose to
     # per-call overhead there (50x at H=50 fwd); only a fused chain can
     # amortize the launch.
@@ -143,9 +143,10 @@ class ModelConfig:
     # per-example DP-SGD — the step builders fail fast. docs/DESIGN.md §5h.
     fuse_hot_path: bool = False
     # user-encoder self-attention implementation:
-    #   "auto"    — EVIDENCE-DRIVEN when a provenance-clean
-    #               benchmarks/pallas_bench.json exists for the current
-    #               jax version and a TPU backend is live: the measured
+    #   "auto"    — EVIDENCE-DRIVEN when benchmarks/pallas_bench.py has
+    #               written a provenance-clean artifact for the current
+    #               jax version (none is in the tree today) and a TPU
+    #               backend is live: the measured
     #               winner for the nearest (H, dtype) regime is picked
     #               (fedrec_tpu.ops.autotune). Otherwise the static
     #               defaults: dense XLA up to attn_chunk_threshold history

@@ -37,14 +37,25 @@ _lib: ctypes.CDLL | None = None
 _load_error: str | None = None
 
 
+def _is_stale() -> bool:
+    """True when the library is older than the source or the Makefile it
+    was built from (git does not carry the binary, a copied tree may)."""
+    built = _LIB_PATH.stat().st_mtime
+    return any(
+        src.exists() and src.stat().st_mtime > built
+        for src in (_NATIVE_DIR / "fedrec_data.cpp", _NATIVE_DIR / "Makefile")
+    )
+
+
 def ensure_built() -> bool:
-    """Build the shared library if missing. Returns True when present.
+    """Build the shared library if it is missing or older than its sources.
+    Returns True when an up-to-date library is present.
 
     A failed build is cached (``_load_error``) so repeated availability
     probes don't re-spawn ``make`` each time.
     """
     global _load_error
-    if _LIB_PATH.exists():
+    if _LIB_PATH.exists() and not _is_stale():
         return True
     if _load_error is not None:
         return False
@@ -52,8 +63,9 @@ def ensure_built() -> bool:
         _load_error = f"{_NATIVE_DIR}/Makefile missing"
         return False
     try:
+        # -B: a Makefile newer than the library must rebuild it too
         subprocess.run(
-            ["make", "-C", str(_NATIVE_DIR)],
+            ["make", "-B", "-C", str(_NATIVE_DIR), "libfedrec_data.so"],
             check=True,
             capture_output=True,
             timeout=120,

@@ -71,10 +71,17 @@ PEAK_FLOPS: dict[str, tuple[float, float]] = {
 
 
 def chip_peaks(device_kind: str) -> tuple[float, float, float] | None:
-    """(bf16 FLOP/s, f32 FLOP/s, HBM bytes/s) for a device-kind string,
-    or ``None`` when the chip is unknown (CPU, new silicon)."""
+    """(bf16 FLOP/s, f32 FLOP/s, HBM bytes/s) for a device-kind string.
+    ``None`` is for the CPU only: a TPU that is not in the table is an
+    error, since every MFU / roofline figure would silently vanish."""
     kind = (device_kind or "").lower()
-    return next((v for frag, v in CHIP_PEAKS.items() if frag in kind), None)
+    peaks = next((v for frag, v in CHIP_PEAKS.items() if frag in kind), None)
+    if peaks is None and "tpu" in kind:
+        raise ValueError(
+            f"no peak FLOP/s / HBM bandwidth known for TPU device kind "
+            f"{device_kind!r}; add it to fedrec_tpu.obs.perf.CHIP_PEAKS"
+        )
+    return peaks
 
 
 def peak_flops(device_kind: str, dtype: str) -> float | None:

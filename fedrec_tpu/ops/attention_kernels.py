@@ -13,8 +13,9 @@ Two kernels cover the recommender's attention math (reference
     ``softmax(tanh(x W1 + b1) w2) . x`` in one VMEM pass (reference
     ``attention.py:14-26``).
 
-Kernels auto-fall back to interpret mode off-TPU so the same code path is
-exercised by CPU tests. ``flash_attention``'s backward is a blocked Pallas
+Off-TPU the kernels run in interpret mode (``_interpret``), so the same code
+path is exercised by CPU tests; on a TPU backend a kernel is compiled or the
+call raises — nothing catches a Mosaic error and carries on. ``flash_attention``'s backward is a blocked Pallas
 kernel pair (FlashAttention-2 style: forward saves the per-row log-sum-exp;
 backward rebuilds p blockwise — O(L) memory end to end, VERDICT r2 item 6).
 ``additive_pool``'s backward stays a dense ``jax.vjp`` recompute: its math
@@ -34,11 +35,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# module-local alias, NOT a monkeypatch of jax's namespace: pre-rename jax
-# spells it TPUCompilerParams, and other libraries feature-detect on pltpu
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams", None
-)
+_CompilerParams = pltpu.CompilerParams
 
 _LANE = 128
 _SUBLANE = 8
@@ -46,6 +43,9 @@ _NEG_INF = -1e9
 
 
 def _interpret() -> bool:
+    """Interpret mode is for backends that cannot compile Mosaic (the CPU
+    tests). The one place that decides: every ``pallas_call`` here and in
+    ``fused_hot_path`` passes ``interpret=_interpret()``."""
     return jax.default_backend() != "tpu"
 
 

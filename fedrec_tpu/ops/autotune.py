@@ -2,8 +2,9 @@
 
 ``config.py`` used to hard-code the never-pallas comment ("dense XLA wins
 at every size that fits") — true when written, but a policy frozen at one
-measurement. This module reads the banked microbenchmark evidence
-(``benchmarks/pallas_bench.json``) and picks the MEASURED winner for the
+measurement. This module reads the microbenchmark evidence
+``benchmarks/pallas_bench.py`` writes (``benchmarks/pallas_bench.json``;
+none is in the tree until that harness runs on a chip) and picks the MEASURED winner for the
 model's (H, dtype) regime instead, falling back to the static defaults
 whenever no applicable clean evidence exists.
 
@@ -14,8 +15,7 @@ Evidence is applicable only when ALL of:
     so test behavior is deterministic);
   * the artifact is complete (no ``"partial"`` flag) and its provenance
     stamps the SAME installed jax version that is resolving now — a
-    runtime bump invalidates kernel timings exactly like it invalidates
-    cached bench replays (``bench._cache_delta``);
+    runtime bump invalidates kernel timings;
   * a row of the training-relevant op ("attention fwd+bwd") exists within
     2x of the model's history length, measured at the model's dtype (rows
     without a dtype tag are float32 — the pre-ISSUE-8 artifact schema).
@@ -63,8 +63,7 @@ def _resolve(path_str: str, mtime_ns: int, seq_len: int, dtype: str,
         (artifact.get("provenance") or {}).get("runtime_versions") or {}
     ).get("jax")
     if stamped is None or stamped != _current_jax_version():
-        # unknowable or stale runtime: timings describe another jax —
-        # the same fail-unsafe rule the cached-bench verdict applies
+        # unknowable or stale runtime: timings describe another jax
         return None
     best_row, best_dist = None, None
     for row in artifact.get("rows") or []:

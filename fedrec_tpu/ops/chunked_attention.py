@@ -2,9 +2,10 @@
 
 The reference materializes dense ``(bz, heads, L, L)`` attention scores
 (reference ``attention.py:38-44``); fine at its fixed L=50, impossible for
-long histories (L=4096 at B=64 x 20 heads = 85 GB of scores). The measured
-TPU answer (``benchmarks/pallas_bench.json``) is that XLA's fused dense path
-beats our Pallas flash kernel at every size that FITS — the 20-dim heads pad
+long histories (L=4096 at B=64 x 20 heads = 85 GB of scores). The last
+chip measurement (jax 0.4.37, not repeated on the current tree: ROADMAP S4)
+was that XLA's fused dense path beats our Pallas flash kernel at every size
+that FITS — the 20-dim heads pad
 to 128 lanes in a hand kernel, wasting 6.4x MXU/bandwidth, while XLA packs
 them. So the long-context strategy is:
 

@@ -1,8 +1,9 @@
 """Fused Pallas kernels for the train step's hot chain (ISSUE 8).
 
-``benchmarks/pallas_bench.json`` proved that ISOLATED kernels lose at the
-reference scale: at H=50 the flash-attention kernel is 50x slower than XLA
-dense (1.89 ms vs 0.038 ms fwd) because per-call overhead dominates ops
+The last chip measurement of the ISOLATED kernels (jax 0.4.37; its artifact
+is no longer in the tree) had them lose at the reference scale: at H=50 the
+flash-attention kernel was 50x slower than XLA dense (1.89 ms vs 0.038 ms
+fwd) because per-call overhead dominates ops
 this small. The only way a kernel wins here is by fusing the WHOLE chain
 and amortizing one launch across it. Two kernels cover the step's hot path:
 
@@ -61,15 +62,16 @@ Both kernels run in interpret mode off-TPU so tier-1 exercises the same
 code path; interpret executes the grid as a host loop (~ms/step), which is
 fine at test scale and is why the CPU bench legs run at reduced U.
 
-Chip-validation risk (open until the queued pallas_bench window runs):
-the gather kernel's table block is (1, T, Dh) with T=50 — NOT a sublane
-multiple, because the (N, T, Dh) table cannot be padded without either a
-per-step full-table copy or changing the dense path's no-mask pool
-numerics (zero token rows would still contribute bias logits). Modern
-Mosaic masks unaligned block windows, and Dh=768 keeps the lane dim
-aligned; if the first real-chip compile rejects it regardless, the
-fallback is ``model.fuse_hot_path=false`` (OPERATIONS §1b) while the
-layout gets a revisit — interpret mode cannot adjudicate this.
+What the chip's compiler says (``tests/test_chip_compile.py`` compiles
+these kernels for a described v5e at the flagship's shapes): the
+gather+encode kernel compiles, forward and backward, with its (1, 50, Dh)
+table block (equal to the array's last two dims, which Mosaic accepts); its
+per-id output and cotangent rows move in 8-row resident blocks because a
+(1, Dp) block is refused. The history-score kernel's forward compiles. Its
+BACKWARD is refused — it is written as einsums with no MXU form (outer
+products, two contracted axes) and needs a rewrite — so
+``model.fuse_hot_path=true`` cannot train on the chip today; ROADMAP S5
+decides. None of these kernels has been run or timed on a chip.
 """
 
 from __future__ import annotations
@@ -94,6 +96,9 @@ from fedrec_tpu.ops.attention_kernels import (
 
 _NEG_INF = -1e9
 _EPS = 1e-8  # the module's denominator epsilon (attention.py:41)
+# the gather kernel's per-id (1, Dp) output / cotangent rows move in f32
+# blocks of this many rows (the f32 sublane tile); the id axis is padded to it
+_ROWS = 8
 
 
 def _sub_mult(dtype) -> int:
@@ -107,6 +112,14 @@ def _lane_pad(x: jnp.ndarray, width: int) -> jnp.ndarray:
         return x
     pad = jnp.zeros(x.shape[:-1] + (width - x.shape[-1],), x.dtype)
     return jnp.concatenate([x, pad], axis=-1)
+
+
+def _row_dot(a: jnp.ndarray, w_row: jnp.ndarray) -> jnp.ndarray:
+    """(M, K) . (1, K) -> (M,) in f32 on the VPU. As a ``dot_general`` with
+    one output column Mosaic's lowering of the mixed bf16 -> f32 product
+    fails verification; the f32 products of bf16 operands are exact, so
+    only the summation order differs from the MXU form."""
+    return jnp.sum(a.astype(jnp.float32) * w_row.astype(jnp.float32), axis=-1)
 
 
 def _masked_softmax(
@@ -131,7 +144,6 @@ def _masked_softmax(
 # ===================================================== fused gather + encode
 def _gather_encode_fwd_kernel(
     ids_ref, row_ref, w1_ref, b1_ref, w2_ref, fcw_ref, fcb_ref, o_ref,
-    *, out_dtype,
 ):
     """One unique news id per grid step: the scalar-prefetch index map has
     already DMA'd ``token_states[ids[i]]`` into ``row_ref`` (the pipeline
@@ -149,10 +161,7 @@ def _gather_encode_fwd_kernel(
     ).astype(x.dtype)                                    # (T, Ah)
     # fc2's bias is a softmax-invariant constant shift under the max-
     # subtracted form — omitted exactly like additive_pool's kernel
-    lg = jax.lax.dot_general(
-        e, w2_ref[:], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ).reshape(1, t)                                      # (1, T) f32
+    lg = _row_dot(e, w2_ref[:]).reshape(1, t)            # (1, T) f32
     ones = jnp.ones((1, t), jnp.float32)                 # reference: no token mask
     alpha = _masked_softmax(lg, ones, t).astype(x.dtype)
     pooled = jax.lax.dot_general(
@@ -163,7 +172,9 @@ def _gather_encode_fwd_kernel(
         pooled, fcw_ref[:], (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     ) + fcb_ref[0][None, :].astype(jnp.float32)
-    o_ref[:] = out.astype(out_dtype)                     # (1, Dp)
+    # the output block holds _ROWS consecutive ids' vectors and stays in
+    # VMEM until the block index moves on: Mosaic takes no (1, Dp) block
+    o_ref[pl.ds(pl.program_id(0) % _ROWS, 1), :] = out   # (1, Dp) f32
 
 
 def _gather_encode_bwd_kernel(
@@ -197,10 +208,7 @@ def _gather_encode_bwd_kernel(
         + b1_ref[0][None, :].astype(jnp.float32)
     )
     e = e32.astype(x.dtype)
-    lg = jax.lax.dot_general(
-        e, w2_ref[:], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ).reshape(1, t)
+    lg = _row_dot(e, w2_ref[:]).reshape(1, t)
     ones = jnp.ones((1, t), jnp.float32)
     alpha = _masked_softmax(lg, ones, t)                 # (1, T) f32
     pooled = jax.lax.dot_general(
@@ -208,7 +216,7 @@ def _gather_encode_bwd_kernel(
         preferred_element_type=jnp.float32,
     )                                                    # (1, Dh) f32
 
-    g = g_ref[:].astype(jnp.float32)                     # (1, Dp)
+    g = g_ref[pl.ds(i % _ROWS, 1), :]                    # (1, Dp) f32
     dfcb_ref[:] += g
     dfcw_ref[:] += jax.lax.dot_general(
         pooled, g, (((0,), (0,)), ((), ())),
@@ -266,23 +274,25 @@ def _gather_encode_pads(table, w1, b1, w2, fcw, fcb):
 def _gather_encode(table, uniq, w1, b1, w2, fcw, fcb):
     t, dh_dim = table.shape[1], table.shape[2]
     u = uniq.shape[0]
+    uniq = _pad_to(uniq, 0, _ROWS)                       # pad ids: row 0
+    up = uniq.shape[0]
     w1p, b1p, w2p, fcwp, fcbp = _gather_encode_pads(table, w1, b1, w2, fcw, fcb)
     dp = fcwp.shape[1]
     out = pl.pallas_call(
-        functools.partial(_gather_encode_fwd_kernel, out_dtype=table.dtype),
+        _gather_encode_fwd_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(u,),
+            grid=(up,),
             in_specs=_gather_encode_specs(t, dh_dim, w1p.shape[1], dp),
-            out_specs=pl.BlockSpec((1, dp), lambda i, ids: (i, 0)),
+            out_specs=pl.BlockSpec((_ROWS, dp), lambda i, ids: (i // _ROWS, 0)),
         ),
-        out_shape=jax.ShapeDtypeStruct((u, dp), table.dtype),
+        out_shape=jax.ShapeDtypeStruct((up, dp), jnp.float32),
         compiler_params=_CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=_interpret(),
     )(uniq, table, w1p, b1p, w2p, fcwp, fcbp)
-    return out[:, : fcw.shape[1]]
+    return out[:u, : fcw.shape[1]].astype(table.dtype)
 
 
 def _gather_encode_fwd(table, uniq, w1, b1, w2, fcw, fcb):
@@ -293,12 +303,15 @@ def _gather_encode_fwd(table, uniq, w1, b1, w2, fcw, fcb):
 def _gather_encode_bwd(res, g):
     table, uniq, w1, b1, w2, fcw, fcb = res
     t, dh_dim = table.shape[1], table.shape[2]
-    u = uniq.shape[0]
+    ids = _pad_to(uniq, 0, _ROWS)
+    u = ids.shape[0]
     w1p, b1p, w2p, fcwp, fcbp = _gather_encode_pads(table, w1, b1, w2, fcw, fcb)
     ahp, dp = w1p.shape[1], fcwp.shape[1]
-    gp = _pad_to(g.astype(jnp.float32), 1, _LANE)        # (U, Dp), pads zero
+    # (Up, Dp), pads zero: a padded id's row contributes nothing
+    gp = _pad_to(_pad_to(g.astype(jnp.float32), 1, _LANE), 0, _ROWS)
     specs = _gather_encode_specs(t, dh_dim, ahp, dp)
-    specs.append(pl.BlockSpec((1, dp), lambda i, ids: (i, 0)))  # cotangent row
+    # the cotangent rows of _ROWS consecutive ids, resident like the output
+    specs.append(pl.BlockSpec((_ROWS, dp), lambda i, ids: (i // _ROWS, 0)))
     dw1, db1, dw2, dfcw, dfcb = pl.pallas_call(
         _gather_encode_bwd_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -324,7 +337,7 @@ def _gather_encode_bwd(res, g):
             dimension_semantics=("arbitrary",),
         ),
         interpret=_interpret(),
-    )(uniq, table, w1p, b1p, w2p, fcwp, fcbp, gp)
+    )(ids, table, w1p, b1p, w2p, fcwp, fcbp, gp)
     ah, d = w1.shape[1], fcw.shape[1]
     # the frozen table's cotangent is symbolically dropped by the caller's
     # stop_gradient; the zeros here are DCE'd, never materialized
@@ -442,15 +455,14 @@ def _hist_forward_core(
         )
         + pb1_ref[0][None, :].astype(jnp.float32)
     )                                                    # (bb*hp, Qp)
-    lg = jax.lax.dot_general(
-        e32.astype(dt), pw2_ref[:], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ).reshape(bb, hp)
+    lg = _row_dot(e32.astype(dt), pw2_ref[:]).reshape(bb, hp)
     alpha = _masked_softmax(lg, mask, h)                 # (bb, hp) f32
+    # (bb, 1, hp) x (bb, hp, d): Mosaic takes no dot whose left operand has
+    # only batch and contracting dimensions, so alpha carries a unit row
     user = jax.lax.dot_general(
-        alpha.astype(dt), ctx, (((1,), (1,)), ((0,), (0,))),
+        alpha[:, None, :].astype(dt), ctx, (((2,), (1,)), ((0,), (0,))),
         preferred_element_type=jnp.float32,
-    )                                                    # (bb, d) f32
+    )[:, 0, :]                                           # (bb, d) f32
     return qa, ka, va, attn_heads, ctx, e32, alpha, user
 
 
@@ -466,9 +478,10 @@ def _hist_score_fwd_kernel(
     )
     user_dt = user.astype(dt)
     sc = jax.lax.dot_general(
-        cand_ref[:, :, :d], user_dt, (((2,), (1,)), ((0,), (0,))),
+        cand_ref[:, :, :d], user[:, None, :].astype(dt),
+        (((2,), (2,)), ((0,), (0,))),
         preferred_element_type=jnp.float32,
-    )                                                    # (bb, Cp)
+    )[:, :, 0]                                           # (bb, Cp)
     scores_ref[:] = _lane_pad(sc.astype(dt), scores_ref.shape[1])
     user_ref[:] = _lane_pad(user_dt, user_ref.shape[1])
 
@@ -502,8 +515,8 @@ def _hist_score_bwd_kernel(
     )
     ctx32 = ctx.astype(jnp.float32)
     cand32 = cand_ref[:, :, :d].astype(jnp.float32)      # (bb, Cp, d)
-    gs = gsc_ref[:, :c].astype(jnp.float32)              # (bb, C)
-    gu = guser_ref[:, :d].astype(jnp.float32)            # (bb, d)
+    gs = gsc_ref[:, 0, :c].astype(jnp.float32)           # (bb, C)
+    gu = guser_ref[:, 0, :d].astype(jnp.float32)         # (bb, d)
 
     # ---- scorer
     dcand = jnp.einsum("bc,bd->bcd", gs, user)           # (bb, C, d)
@@ -699,8 +712,10 @@ def _hist_score_vjp_bwd(nh, block_b, res, g):
     np_, hp, dp = xp.shape
     cp, qp = candp.shape[1], padded[9].shape[1]
     cs = cp + (-cp) % _LANE
-    gscp = _pad_to(_pad_to(gsc.astype(jnp.float32), 0, bb), 1, cs)
-    guserp = _pad_to(_pad_to(guser.astype(jnp.float32), 0, bb), 1, dp)
+    # per-row cotangents ride a unit middle axis like the mask: a (bb, W)
+    # block with bb below the sublane multiple is refused, (bb, 1, W) is not
+    gscp = _pad_to(_pad_to(gsc.astype(jnp.float32), 0, bb), 1, cs)[:, None, :]
+    guserp = _pad_to(_pad_to(guser.astype(jnp.float32), 0, bb), 1, dp)[:, None, :]
     outs = pl.pallas_call(
         functools.partial(_hist_score_bwd_kernel, nh=nh, dh=dh, h=h, c=c),
         grid=(np_ // bb,),
@@ -709,8 +724,8 @@ def _hist_score_vjp_bwd(nh, block_b, res, g):
             pl.BlockSpec((bb, cp, dp), lambda i: (i, 0, 0)),
             pl.BlockSpec((bb, 1, maskp.shape[2]), lambda i: (i, 0, 0)),
             *_hist_score_wspecs(dp, qp),
-            pl.BlockSpec((bb, cs), lambda i: (i, 0)),
-            pl.BlockSpec((bb, dp), lambda i: (i, 0)),
+            pl.BlockSpec((bb, 1, cs), lambda i: (i, 0, 0)),
+            pl.BlockSpec((bb, 1, dp), lambda i: (i, 0, 0)),
         ],
         out_specs=(
             pl.BlockSpec((bb, hp, dp), lambda i: (i, 0, 0)),

@@ -32,7 +32,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from fedrec_tpu.compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 

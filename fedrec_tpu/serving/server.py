@@ -95,6 +95,9 @@ class ServingService:
             registry=self.registry,
         )
         self._fns: dict[int, Any] = {}
+        # bucket -> seconds its first (compiling) call took in the latest
+        # warmed scorer build
+        self.warmup_seconds: dict[int, float] = {}
         self._latencies: deque[float] = deque(maxlen=latency_window)
         self._conns: set = set()  # live TCP writers; closed on stop()
         self._started_at = time.time()
@@ -177,8 +180,10 @@ class ServingService:
         )
         if user_params is not None:
             for b in self.batcher.batch_sizes:
+                t0 = time.perf_counter()
                 hist = np.zeros((b, self.batcher.history_len), np.int32)
                 np.asarray(fn(user_params, hist)[0])
+                self.warmup_seconds[b] = time.perf_counter() - t0
         return fn
 
     def _cache_fn(self, generation: int, fn) -> None:

@@ -1,0 +1,246 @@
+"""Ask the chip's compiler, without the chip.
+
+The TPU compiler is installed here and compiles for a *described* v5e
+device (``/opt/skills/guides/on-chip-measurement`` section 2). Interpret
+mode — what every other test runs the Pallas kernels in — cannot see a
+block Mosaic refuses or a program that does not fit; these compiles can.
+Nothing runs, so nothing here says anything about results or times.
+
+All of it lives in this ONE file: the worker that is given it loads the
+TPU library inside the ``topo`` fixture, and no other worker may.
+"""
+
+from __future__ import annotations
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from fedrec_tpu.config import ExperimentConfig
+from fedrec_tpu.fed import get_strategy
+from fedrec_tpu.models import NewsRecommender
+from fedrec_tpu.ops import attention_kernels, fused_hot_path
+from fedrec_tpu.train import build_fed_train_step, build_param_sync
+from fedrec_tpu.train.state import init_client_state, replicate_state
+
+# the flagship's published widths (ModelConfig defaults)
+B, HIS, HEADS, HEAD_DIM = 64, 50, 20, 20
+TITLE, TRUNK, QUERY, NEWS_DIM = 50, 768, 200, 400
+CANDS = 5
+UNIQUE_CAP = B * (CANDS + HIS)      # 3520: what the joint step gathers per client
+TABLE_ROWS = 65_536                  # MIND-small
+STEP_TABLE_ROWS = 4_096              # the step cases: small enough to compile fast
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler on this rig
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described device can be written to the persistent
+    # cache but never read back; keep it off around these tests
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def compiled_kernels(monkeypatch):
+    """On a TPU backend a kernel compiles or the call raises; here the
+    backend is the CPU, so the test makes the kernels' choice for them."""
+    monkeypatch.setattr(attention_kernels, "_interpret", lambda: False)
+    monkeypatch.setattr(fused_hot_path, "_interpret", lambda: False)
+
+
+def _spec(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=sharding)
+
+
+def _compile(fn, *specs):
+    compiled = jax.jit(fn).lower(*specs).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+# ---------------------------------------------------------------- kernels
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("his_len", [HIS, 1024])
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_flash_attention_compiles(compiled_kernels, one_chip, direction, his_len, dtype):
+    qkv = _spec((B, his_len, HEADS, HEAD_DIM), dtype, one_chip)
+    mask = _spec((B, his_len), "float32", one_chip)
+
+    def fwd(q, k, v, m):
+        return attention_kernels.flash_attention(q, k, v, m)
+
+    def bwd(q, k, v, m):
+        return jax.grad(
+            lambda q, k, v: fwd(q, k, v, m).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2),
+        )(q, k, v)
+
+    _compile(fwd if direction == "fwd" else bwd, qkv, qkv, qkv, mask)
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_additive_pool_compiles(compiled_kernels, one_chip, dtype):
+    """The text head's pool over the rows one client's step gathers."""
+    _compile(
+        attention_kernels.additive_pool,
+        _spec((UNIQUE_CAP, TITLE, TRUNK), dtype, one_chip),
+        _spec((TRUNK, QUERY), dtype, one_chip),
+        _spec((QUERY,), dtype, one_chip),
+        _spec((QUERY,), dtype, one_chip),
+    )
+
+
+def _text_head_specs(dtype, sharding):
+    return {
+        "pool": {
+            "att_fc1": {"kernel": _spec((TRUNK, QUERY), dtype, sharding),
+                        "bias": _spec((QUERY,), dtype, sharding)},
+            "att_fc2": {"kernel": _spec((QUERY, 1), dtype, sharding),
+                        "bias": _spec((1,), dtype, sharding)},
+        },
+        "fc": {"kernel": _spec((TRUNK, NEWS_DIM), dtype, sharding),
+               "bias": _spec((NEWS_DIM,), dtype, sharding)},
+    }
+
+
+def _user_tower_specs(dtype, sharding):
+    proj = HEADS * HEAD_DIM
+    dense = lambda i, o: {"kernel": _spec((i, o), dtype, sharding),  # noqa: E731
+                          "bias": _spec((o,), dtype, sharding)}
+    attn = {"w_q": dense(NEWS_DIM, proj), "w_k": dense(NEWS_DIM, proj),
+            "w_v": dense(NEWS_DIM, proj)}
+    pool = {"att_fc1": dense(proj, QUERY), "att_fc2": dense(QUERY, 1)}
+    return attn, pool
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_fused_gather_encode_compiles(compiled_kernels, one_chip, direction):
+    """The open risk of fused_hot_path.py's header: the (1, 50, Dh) table
+    block over the real MIND-small table."""
+    table = _spec((TABLE_ROWS, TITLE, TRUNK), "bfloat16", one_chip)
+    uniq = _spec((UNIQUE_CAP,), "int32", one_chip)
+    params = _text_head_specs("float32", one_chip)
+
+    def fwd(table, uniq, params):
+        return fused_hot_path.fused_gather_encode(table, uniq, params)
+
+    def bwd(table, uniq, params):
+        return jax.grad(
+            lambda p: fwd(table, uniq, p).astype(jnp.float32).sum()
+        )(params)
+
+    _compile(fwd if direction == "fwd" else bwd, table, uniq, params)
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_fused_history_score_compiles(compiled_kernels, one_chip, direction):
+    """The forward (what serving's ``fused_user_vector`` runs) compiles. The
+    backward is REFUSED by the chip's compiler: its kernel is built from
+    einsums with no MXU form (outer products, two contracted axes), which is
+    a rewrite and not a local repair, so ``model.fuse_hot_path=true`` cannot
+    train on the chip and stays off by default. Roadmap S5 decides its
+    fate; when the kernel is rewritten or deleted this assertion goes."""
+    his = _spec((B, HIS, NEWS_DIM), "bfloat16", one_chip)
+    cand = _spec((B, CANDS, NEWS_DIM), "bfloat16", one_chip)
+    mask = _spec((B, HIS), "float32", one_chip)
+    attn, pool = _user_tower_specs("float32", one_chip)
+
+    def fwd(his, cand, mask, attn, pool):
+        return fused_hot_path.fused_history_score(
+            his, cand, mask, attn, pool, HEADS
+        )[0]
+
+    def bwd(his, cand, mask, attn, pool):
+        return jax.grad(
+            lambda a, p: fwd(his, cand, mask, a, p).astype(jnp.float32).sum(),
+            argnums=(0, 1),
+        )(attn, pool)
+
+    if direction == "fwd":
+        _compile(fwd, his, cand, mask, attn, pool)
+        return
+    with pytest.raises(Exception, match="TPU_DotDimensionNumbersAttr"):
+        _compile(bwd, his, cand, mask, attn, pool)
+
+
+# ------------------------------------------------------------ whole step
+def _joint_step_case(devices, num_clients):
+    """(step, sync, args): the default (XLA) joint train step and the
+    round-end sync over a mesh of described devices, full width, bf16."""
+    cfg = ExperimentConfig()
+    cfg.model.text_encoder_mode = "head"
+    cfg.model.dtype = "bfloat16"
+    cfg.fed.strategy = "param_avg"
+    cfg.fed.num_clients = num_clients
+    cfg.data.batch_size = B
+    model = NewsRecommender(cfg.model)
+    axis = cfg.fed.mesh_axis
+    mesh = Mesh(np.array(devices), (axis,))
+    per_client = NamedSharding(mesh, P(axis))
+    state = jax.eval_shape(
+        lambda: replicate_state(
+            init_client_state(
+                model, cfg, jax.random.PRNGKey(0), STEP_TABLE_ROWS, TITLE
+            ),
+            num_clients, jax.random.PRNGKey(1),
+        )
+    )
+    state = jax.tree_util.tree_map(
+        lambda x: _spec(x.shape, x.dtype, per_client), state
+    )
+    batch = {
+        "candidates": _spec((num_clients, B, CANDS), "int32", per_client),
+        "history": _spec((num_clients, B, HIS), "int32", per_client),
+        "labels": _spec((num_clients, B), "int32", per_client),
+    }
+    table = _spec(
+        (STEP_TABLE_ROWS, TITLE, TRUNK), "bfloat16", NamedSharding(mesh, P())
+    )
+    weights = _spec((num_clients,), "float32", NamedSharding(mesh, P()))
+    strategy = get_strategy("param_avg")
+    step = build_fed_train_step(model, cfg, strategy, mesh, mode="joint")
+    sync = build_param_sync(cfg, mesh)
+    return step, sync, (state, batch, table), weights
+
+
+def test_joint_step_compiles_for_one_chip(topo):
+    """8-client cohort, B=64 per client, on one described chip."""
+    step, _, args, _ = _joint_step_case(topo.devices[:1], num_clients=2)
+    compiled = step.lower(*args).compile()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert 0 < total < 16e9
+    assert "all-reduce" not in compiled.as_text()  # param_avg: no per-step sync
+
+
+def test_joint_step_compiles_for_four_chips(topo):
+    """One client per device over the ``clients`` mesh: the step's only
+    collective is the psum behind its mean-loss metric, and the round-end
+    sync averages the parameters with an all-reduce over the four chips."""
+    step, sync, args, weights = _joint_step_case(topo.devices[:4], num_clients=4)
+    step_text = step.lower(*args).compile().as_text()
+    assert "replica_groups={{0,1,2,3}}" in step_text
+    sync_text = sync.lower(args[0], weights).compile().as_text()
+    assert "all-reduce" in sync_text and "replica_groups={{0,1,2,3}}" in sync_text

@@ -38,6 +38,17 @@ def _run_cli(args: list[str], tmp_path, timeout: int = 300) -> str:
     return out
 
 
+def _reference_states(tmp_path) -> str:
+    """Token states for the demo shard's 225 news: fedrec-run trains on
+    random states only under --synthetic."""
+    import numpy as np
+
+    path = tmp_path / "token_states.npy"
+    np.save(path, np.random.default_rng(0).standard_normal(
+        (225, 50, 32), dtype=np.float32))
+    return str(path)
+
+
 def test_run_cli_synthetic_param_avg(tmp_path):
     """Two rounds of 2-client FedAvg on the synthetic corpus: exits 0,
     reports final metrics, and leaves a resumable snapshot tree."""
@@ -57,13 +68,13 @@ def test_run_cli_synthetic_param_avg(tmp_path):
 def test_run_cli_reference_artifacts(tmp_path):
     """The reference demo shard (``/root/reference/UserData``: 225 news,
     4 train / 1 valid samples — SURVEY §2.1 'Shipped data sample') loads and
-    trains through the same driver, with random token states (smoke mode)."""
+    trains through the same driver, given token states for its 225 news."""
     shard = "/root/reference/UserData"
     if not os.path.isdir(shard):
         pytest.skip("reference demo shard not present")
     out = _run_cli(
         ["1", "4", "1", "--strategy", "grad_avg", "--clients", "1",
-         "--data-dir", shard,
+         "--data-dir", shard, "--token-states", _reference_states(tmp_path),
          "--set", "model.bert_hidden=32", "--set", "model.news_dim=32",
          "--set", "model.num_heads=4", "--set", "model.head_dim=8",
          "--set", "model.query_dim=16", "--set", "data.max_his_len=10"],
@@ -85,7 +96,9 @@ def test_recommend_cli_after_training(tmp_path):
               "--set", "model.num_heads=4", "--set", "model.head_dim=8",
               "--set", "model.query_dim=16", "--set", "data.max_his_len=10"]
     _run_cli(["1", "2", "1", "--strategy", "param_avg", "--clients", "2",
-              "--data-dir", shard, *common], tmp_path)
+              "--data-dir", shard,
+              "--token-states", _reference_states(tmp_path), *common],
+             tmp_path)
     assert (tmp_path / "snapshots").exists()
 
     env = cpu_host_env()
@@ -218,7 +231,9 @@ def test_recommend_cli_round_trip_cnn_head(tmp_path):
               "--set", "model.query_dim=16", "--set", "data.max_his_len=10",
               "--set", "model.text_head_arch=cnn"]
     _run_cli(["1", "2", "1", "--strategy", "param_avg", "--clients", "2",
-              "--data-dir", shard, *common], tmp_path)
+              "--data-dir", shard,
+              "--token-states", _reference_states(tmp_path), *common],
+             tmp_path)
 
     env = cpu_host_env()
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")

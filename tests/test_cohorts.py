@@ -77,7 +77,7 @@ def test_cohort_sync_grads_is_exactly_the_global_mean():
     from functools import partial
 
     from jax.sharding import PartitionSpec as P
-    from fedrec_tpu.compat import shard_map
+    from jax import shard_map
 
     from fedrec_tpu.fed.strategies import GradAvg
     from fedrec_tpu.train.step import LOCAL_AXIS

@@ -87,6 +87,10 @@ def test_chip_peaks_lookup():
     assert peak_flops("cpu", "bfloat16") is None
     peaks = chip_peaks("TPU v5 lite pod slice")
     assert peaks == CHIP_PEAKS["v5 lite"] and peaks[2] == 819e9
+    # what one v5e chip reports as device_kind (chip run, PR 22)
+    assert chip_peaks("TPU v5 lite") == CHIP_PEAKS["v5 lite"]
+    with pytest.raises(ValueError, match="CHIP_PEAKS"):
+        chip_peaks("TPU v9 hypothetical")
 
 
 # --------------------------------------------------------- roofline verdict

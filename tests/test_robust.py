@@ -17,7 +17,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from fedrec_tpu.compat import shard_map
+from jax import shard_map
 from fedrec_tpu.fed import (
     get_strategy,
     participation_mask,

@@ -12,7 +12,7 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
-from fedrec_tpu.compat import shard_map
+from jax import shard_map
 
 from fedrec_tpu.parallel.ring import (
     ring_attention,

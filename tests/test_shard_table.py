@@ -18,7 +18,7 @@ import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from fedrec_tpu.compat import shard_map
+from jax import shard_map
 from fedrec_tpu.fed import get_strategy
 from fedrec_tpu.parallel import client_mesh, shard_batch
 from fedrec_tpu.shard.table import (
