@@ -371,6 +371,11 @@ class MembershipServer:
             if self._stop.is_set() or _now() > deadline:
                 return {"error": "membership server stopping"}
             with self._lock:
+                if j.event.is_set():
+                    # the epoch formed between the wait's timeout and this
+                    # lock: formation clears _joiners, which is not a
+                    # supersession
+                    break
                 if self._joiners.get(worker) is not j:
                     # the worker timed out client-side and re-joined: the
                     # NEW join owns the seat; this connection's thread must

@@ -33,7 +33,12 @@ from fedrec_tpu.serving.retrieval import (
     kmeans,
     recall_at_k,
 )
-from fedrec_tpu.serving.server import ServingService, serve_forever, start_server
+from fedrec_tpu.serving.server import (
+    ServingService,
+    serve_forever,
+    start_server,
+    stop_server,
+)
 from fedrec_tpu.serving.store import EmbeddingStore, EmptyStoreError, Generation
 
 __all__ = [
@@ -54,4 +59,5 @@ __all__ = [
     "recall_at_k",
     "serve_forever",
     "start_server",
+    "stop_server",
 ]

