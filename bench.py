@@ -179,6 +179,7 @@ def main() -> None:
     from fedrec_tpu.parallel import client_mesh, shard_batch
     from fedrec_tpu.train import build_fed_train_step
     from fedrec_tpu.train.state import init_client_state, replicate_state
+    from fedrec_tpu.train.step import commit_token_table
 
     device = jax.devices()[0]
     platform = device.platform
@@ -231,6 +232,9 @@ def main() -> None:
     )
     model = NewsRecommender(cfg.model)
     mesh = client_mesh(1)
+    # the layout the joint steps state for their table argument: committed
+    # once here, or every dispatch would relay the table out
+    token_states, _ = commit_token_table(token_states, mesh)
     step = build_fed_train_step(model, cfg, get_strategy("grad_avg"), mesh, mode="joint")
 
     def make_batch(seed: int, bsz: int, n_clients: int = 1):

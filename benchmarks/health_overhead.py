@@ -40,6 +40,7 @@ def build(sentry: bool, args):
     from fedrec_tpu.parallel import client_mesh, shard_batch
     from fedrec_tpu.train import build_fed_train_step
     from fedrec_tpu.train.state import init_client_state, replicate_state
+    from fedrec_tpu.train.step import commit_token_table
 
     cfg = ExperimentConfig()
     cfg.model.news_dim = 64
@@ -80,7 +81,8 @@ def build(sentry: bool, args):
         }))
         if len(batches) >= args.warmup + args.steps:
             break
-    return step, stacked, batches, np.asarray(token_states)
+    # where the joint step states its table rests (train/step.py)
+    return step, stacked, batches, commit_token_table(token_states, mesh)[0]
 
 
 def time_steps_state(step, state, batches, table, n: int):

@@ -113,6 +113,7 @@ def measure_lanes(repeats: int = 3) -> dict:
     from fedrec_tpu.parallel import client_mesh, shard_batch
     from fedrec_tpu.train import build_fed_train_step
     from fedrec_tpu.train.state import init_client_state, replicate_state
+    from fedrec_tpu.train.step import commit_token_table
 
     cfg = _gate_cfg()
     num_news, L = 128, cfg.data.max_title_len
@@ -124,6 +125,8 @@ def measure_lanes(repeats: int = 3) -> dict:
     )
     model = NewsRecommender(cfg.model)
     mesh = client_mesh(1)
+    # where the joint step states its table rests (train/step.py)
+    token_states, _ = commit_token_table(token_states, mesh)
     step = build_fed_train_step(
         model, cfg, get_strategy("grad_avg"), mesh, mode="joint"
     )

@@ -97,8 +97,13 @@ def span_seconds(events: list[dict], name: str) -> list[float]:
 
 def run_trainer(argv: list[str], name: str, mesh=None):
     """``fedrec-run``'s own path: parse, build inputs, ``Trainer.run()``,
-    with the run's snapshots under ``WORK_DIR / name``."""
+    with the run's snapshots under ``WORK_DIR / name``. Returns the trainer,
+    its history, the run's events, the seconds the inputs took and the
+    shapes of the last batch the step was fed."""
+    import jax
+
     from fedrec_tpu.cli import run as run_cli
+    from fedrec_tpu.obs import get_tracer
     from fedrec_tpu.train.trainer import Trainer
 
     argv = [*argv, "--set", f"train.snapshot_dir={WORK_DIR / name}"]
@@ -108,10 +113,72 @@ def run_trainer(argv: list[str], name: str, mesh=None):
     cfg, data, token_states = inputs
     token_states.block_until_ready()
     t_inputs = time.perf_counter() - t0
+    mark = get_tracer().event_count()
     trainer = Trainer(cfg, data, token_states, mesh=mesh)
-    mark = trainer.tracer.event_count()
+    # the trainer holds its own committed copy of the table; ours would be a
+    # second 5 GB on the chip for the whole run
+    del inputs, token_states
+    # what the step is fed, kept as shapes: check_table_at_rest lowers the
+    # same program again
+    step = trainer.train_step
+    fed: dict = {}
+
+    def keeping_shapes(state, batch, table):
+        fed["batch"] = jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=x.sharding),
+            batch,
+        )
+        return step(state, batch, table)
+
+    trainer.train_step = keeping_shapes
     history = trainer.run()
-    return trainer, history, trainer.tracer.events_since(mark), t_inputs
+    trainer.train_step = step
+    return trainer, history, trainer.tracer.events_since(mark), t_inputs, fed["batch"]
+
+
+HLO_DTYPES = {"bfloat16": "bf16", "float16": "f16", "float32": "f32"}
+
+
+def table_copies(hlo_text: str, table) -> list[str]:
+    """The ``copy`` instructions of an optimised HLO whose result has the
+    table's shape: a program that relays the whole table out again."""
+    import re
+
+    shape = f"{HLO_DTYPES[str(table.dtype)]}[{','.join(map(str, table.shape))}]"
+    pattern = re.compile(r"= " + re.escape(shape) + r"\S* copy\(")
+    return [ln.strip()[:200] for ln in hlo_text.splitlines() if pattern.search(ln)]
+
+
+def check_table_at_rest(trainer, events: list[dict], batch, phase: str) -> dict:
+    """The token-state table rests in the layout the step's gather reads,
+    set once when the trainer took it, and the compiled step does not
+    rewrite it (PERF.md section 5: a TPU's own layout for (N, 50, 768)
+    costs every step a copy of the whole table)."""
+    table = trainer.token_states
+    commits = [ev.get("args", {}) for ev in events if ev.get("name") == "table_commit"]
+    check(len(commits) == 1, f"{len(commits)} table_commit spans, expected 1")
+    compiled = trainer.train_step.__wrapped__.lower(
+        trainer.state, batch, table
+    ).compile()
+    stated = compiled.input_formats[0][2]
+    copies = table_copies(compiled.as_text(), table)
+    out = {
+        "table_format": str(table.format),
+        "table_commit": commits[0],
+        "table_bytes_on_device": [
+            int(s.data.on_device_size_in_bytes()) for s in table.addressable_shards
+        ],
+        "step_states_table_layout": str(stated.layout),
+    }
+    for k, v in out.items():
+        say(f"{phase}.{k}", v)
+    check(
+        tuple(stated.layout.major_to_minor) == tuple(table.format.layout.major_to_minor),
+        f"the step is compiled for {stated.layout}, the table rests in "
+        f"{table.format.layout}",
+    )
+    check(not copies, f"the compiled step copies the whole table: {copies}")
+    return out
 
 
 def check_history(history, rounds: int) -> list[float]:
@@ -124,7 +191,7 @@ def check_history(history, rounds: int) -> list[float]:
 def train_phase(argv: list[str]) -> dict:
     """8-client ``param_avg`` cohort on one device, two rounds, validation
     at the end. Returns what was observed."""
-    trainer, history, events, t_inputs = run_trainer(argv, "train")
+    trainer, history, events, t_inputs, batch = run_trainer(argv, "train")
     cfg = trainer.cfg
     losses = check_history(history, cfg.fed.rounds)
     check(losses[-1] < losses[0],
@@ -152,6 +219,7 @@ def train_phase(argv: list[str]) -> dict:
     }
     for k, v in out.items():
         say(f"train.{k}", v)
+    out.update(check_table_at_rest(trainer, events, batch, "train"))
     return out
 
 
@@ -287,7 +355,7 @@ def four_chip_phase(argv: list[str], rtol: float = FOUR_CHIP_LOSS_RTOL) -> dict:
 
     from fedrec_tpu.parallel import client_mesh
 
-    trainer, history, events, _ = run_trainer(argv, "per_chip")
+    trainer, history, events, _, batch = run_trainer(argv, "per_chip")
     cfg = trainer.cfg
     n = cfg.fed.num_clients
     check(trainer.mesh.size == n,
@@ -306,6 +374,7 @@ def four_chip_phase(argv: list[str], rtol: float = FOUR_CHIP_LOSS_RTOL) -> dict:
     host = [np.asarray(x) for x in leaves]
     check(all((x == x[0:1]).all() for x in host),
           "clients differ after the round-end sync")
+    check_table_at_rest(trainer, events, batch, "four_chip")
     auc = history[-1].val_metrics.get("auc")
     secs = span_seconds(events, "fed_round")
     # release the per-chip run's state and replicated table before the
@@ -313,7 +382,7 @@ def four_chip_phase(argv: list[str], rtol: float = FOUR_CHIP_LOSS_RTOL) -> dict:
     del trainer, leaves, history
     gc.collect()
 
-    ref, ref_history, ref_events, _ = run_trainer(
+    ref, ref_history, ref_events, _, _ = run_trainer(
         argv, "cohort", mesh=client_mesh(n, max_devices=1)
     )
     check(ref.mesh.size == 1, "the comparison run is not on one device")

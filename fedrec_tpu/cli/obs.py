@@ -656,7 +656,11 @@ def _cmd_replay(args) -> int:
     from fedrec_tpu.models import NewsRecommender
     from fedrec_tpu.parallel.mesh import client_mesh, shard_fed_batch
     from fedrec_tpu.train.state import init_client_state, replicate_state
-    from fedrec_tpu.train.step import build_fed_train_step, build_param_sync
+    from fedrec_tpu.train.step import (
+        build_fed_train_step,
+        build_param_sync,
+        commit_token_table,
+    )
 
     cfg = ExperimentConfig.from_dict(manifest["config"])
     if cfg.fed.seq_shards > 1:
@@ -693,7 +697,11 @@ def _cmd_replay(args) -> int:
         )
     except (OSError, ValueError) as e:
         return _fail(f"cannot restore the dumped state: {e}")
-    table = np.load(flight_dir / manifest["table_file"])
+    # a joint step's token states go where the step states they rest; the
+    # other modes' tables come back as they are (train/step.py)
+    table, _ = commit_token_table(
+        np.load(flight_dir / manifest["table_file"]), mesh
+    )
 
     step = build_fed_train_step(
         model, cfg, strategy, mesh, mode=manifest.get("mode") or None

@@ -294,6 +294,9 @@ def main(argv: list[str] | None = None) -> int:
                 proxy.stop()
     else:
         trainer = Trainer(cfg, data, token_states)
+        # the trainer keeps its own committed copy of the table; a device
+        # array of ours would be a second 5 GB on the chip for the whole run
+        del inputs, token_states
         history = trainer.run()
     if history and history[-1].val_metrics:
         m = history[-1].val_metrics

@@ -247,6 +247,7 @@ class CompileWatchdog:
                         _tls.suppress_compile_events = False
 
         wrapped.__name__ = f"watched_{name}"
+        wrapped.__wrapped__ = fn  # the jitted program: .lower(), ._cache_size()
         return wrapped
 
     # ------------------------------------------------------------ inspect

@@ -33,7 +33,10 @@ class ClientState:
     opt_user: Any                     # optax state for user_params
     opt_news: Any                     # optax state for news_params
     rng: jax.Array                    # per-client PRNG key
-    news_grad_accum: jnp.ndarray      # (N_news, D) embedding-grad scatter target
+    # (N_news, D) embedding-grad scatter target of the decoupled step; a
+    # scalar zero placeholder where the configured step is joint or
+    # finetune, which never read it (init_client_state)
+    news_grad_accum: jnp.ndarray
     # per-client error-feedback residual for the biased update codecs
     # (fed.dcn_compress = sign1bit/topk with fed.dcn_error_feedback): a
     # (user_params, news_params)-shaped pytree holding the mass the last
@@ -121,6 +124,15 @@ def init_client_state(
         )
     else:
         ef_residual = jnp.zeros((), jnp.float32)
+    # the per-nid accumulator exists where something reads it: the decoupled
+    # step scatters into it and news_update replays it. The joint and
+    # finetune steps only carried it: 839 MB of eight clients' state at
+    # MIND-small, copied again by every round-end sync (PERF.md section 6,
+    # PR 27: with it fed8.b64 does not fit beside the committed table)
+    if cfg.model.text_encoder_mode == "table":
+        news_grad_accum = jnp.zeros((num_news, cfg.model.news_dim), jnp.float32)
+    else:
+        news_grad_accum = jnp.zeros((), jnp.float32)
     return ClientState(
         step=jnp.zeros((), jnp.int32),
         user_params=user_params,
@@ -128,7 +140,7 @@ def init_client_state(
         opt_user=opt_user_tx.init(user_params),
         opt_news=opt_news_tx.init(news_params),
         rng=state_rng,
-        news_grad_accum=jnp.zeros((num_news, cfg.model.news_dim), jnp.float32),
+        news_grad_accum=news_grad_accum,
         ef_residual=ef_residual,
     )
 

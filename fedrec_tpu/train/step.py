@@ -31,6 +31,7 @@ steps because every shape is static.
 
 from __future__ import annotations
 
+import contextlib
 from functools import partial
 from typing import Any, Callable
 
@@ -38,7 +39,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.experimental.layout import Format, Layout
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax import shard_map
 
 from fedrec_tpu.config import ExperimentConfig
@@ -116,6 +118,106 @@ def _apply_update_fault(tree: Any, code: jnp.ndarray, scale: jnp.ndarray) -> Any
         return jnp.where(code == 0, u, faulted)
 
     return jax.tree_util.tree_map(one, tree)
+
+
+# ------------------------------------------------- token-state table at rest
+def is_token_state_table(table: Any) -> bool:
+    """A 3-D floating ``(N, L, Dh)`` table of cached trunk token states —
+    told from what it is, not from a setting: the ``(N, D)`` news-vector
+    table of ``decoupled`` mode and the ``(N, 2, L)`` int32 token table of
+    ``finetune`` mode are not."""
+    return getattr(table, "ndim", 0) == 3 and jnp.issubdtype(
+        table.dtype, jnp.floating
+    )
+
+
+def token_table_format(mesh: Mesh, spec: P = P()) -> Format:
+    """Where a token-state table's bytes lie at rest: row-major, the layout
+    the step's ``table[ids]`` gather reads. A TPU's own choice for
+    ``(N, 50, 768)`` puts the 50-axis major (no padding of 50), and every
+    program handed that rewrites the whole table to row-major before it can
+    gather (PERF.md section 5). :func:`commit_token_table` sets this format
+    once; every ``jax.jit`` that takes the table states it in its
+    ``in_shardings``, so no program relays the table out."""
+    return Format(Layout(major_to_minor=(0, 1, 2)), NamedSharding(mesh, spec))
+
+
+def commit_token_table(
+    table: Any, mesh: Mesh, spec: P = P()
+) -> tuple[Any, dict | None]:
+    """Commit a step's feature table to where it rests: a token-state table
+    (host or device array) goes to :func:`token_table_format` with one
+    ``device_put``; any other table (:func:`is_token_state_table`) comes
+    back untouched with ``None``. Whoever holds a table and calls a program
+    built here more than once calls this once first: a program that states
+    the format refuses a committed array in another layout and relays an
+    uncommitted one out at every dispatch.
+
+    Returns the table and what was done, the args of the trainer's
+    ``table_commit`` span: ``found`` (the ``major_to_minor`` it arrived in,
+    ``"host"`` for a host array), ``set``, ``relaid`` and
+    ``compiled_afresh``. A table that already rests there is returned as it
+    is; otherwise the result is a new array and the caller's is left alone.
+    On a TPU the relayout program is compiled afresh (about a second that
+    ``compile_cache_misses`` does not see): one loaded from the persistent
+    cache returns an array that misreports its layout
+    (:func:`fedrec_tpu.utils.compile_cache.compiled_afresh`)."""
+    from fedrec_tpu.utils.compile_cache import compiled_afresh
+
+    if not is_token_state_table(table):
+        return table, None
+    fmt = token_table_format(mesh, spec)
+    want = tuple(fmt.layout.major_to_minor)
+    at = getattr(table, "format", None)
+    found = None if at is None else tuple(at.layout.major_to_minor)
+    did = {
+        "found": "host" if found is None else str(found), "set": str(want),
+        "relaid": found != want, "compiled_afresh": False,
+    }
+    if (
+        found == want
+        and getattr(table, "committed", False)
+        and table.sharding.is_equivalent_to(fmt.sharding, table.ndim)
+    ):
+        return table, did
+    # only a TPU lays (N, 50, 768) out otherwise by itself, so only there
+    # does the device_put compile a program with an output layout of its own
+    afresh = mesh.devices.flat[0].platform == "tpu"
+    with compiled_afresh() if afresh else contextlib.nullcontext():
+        committed = jax.device_put(table, fmt)
+    did["compiled_afresh"] = afresh
+    at = tuple(committed.format.layout.major_to_minor)
+    if at != want:
+        raise RuntimeError(
+            f"the table was committed to {fmt.layout} but reports {at}: "
+            "every program that states the format would refuse it"
+        )
+    return committed, did
+
+
+def _resolve_mode(cfg: ExperimentConfig, mode: str | None) -> str:
+    """The step mode a builder was asked for, else the configured one."""
+    if mode is None:
+        mode = {"table": "decoupled", "head": "joint", "finetune": "finetune"}.get(
+            cfg.model.text_encoder_mode, "joint"
+        )
+    return mode
+
+
+def _table_in_shardings(
+    n_args: int, cfg: ExperimentConfig, mode: str | None, mesh: Mesh,
+    spec: P = P(),
+) -> tuple:
+    """``in_shardings`` of a step program whose third argument is the
+    feature table: a ``joint`` step reads token states and states their
+    at-rest format; the other modes' tables (and every other argument) are
+    left to the arrays that arrive."""
+    fmt = (
+        token_table_format(mesh, spec)
+        if _resolve_mode(cfg, mode) == "joint"
+        else None
+    )
+    return tuple(fmt if i == 2 else None for i in range(n_args))
 
 
 # vmap axis name for the in-device client cohort (num_clients > devices):
@@ -231,6 +333,7 @@ def _encode_gathered(
     chunk: int = 0,
     fused: bool = False,
     gather_fn: Callable | None = None,
+    batch_of_one: bool = False,
 ) -> jnp.ndarray:
     """Gather unique token-state rows and run the text head over them.
 
@@ -254,6 +357,19 @@ def _encode_gathered(
     ``stop_gradient`` on the table keeps the frozen-trunk contract and the
     kernel's VJP never computes a table cotangent anyway. Composes with
     ``chunk`` unchanged (the tile body swaps implementations).
+
+    ``batch_of_one``: run the head as a ``vmap`` over one client whose
+    parameters carry the batch axis too — the form it has inside a cohort's
+    ``vmap``. Same arithmetic; what changes is XLA:TPU's choice of layouts:
+    un-batched it lays the gathered rows out again for the head's first
+    product (a ``copy`` of ``bf16[28160,50,768]``: 7.03 ms and 2.16 GB of
+    temporaries a step in ``central.b512``), batched it fuses that into
+    the consumers, as it does for a cohort (PERF.md section 6, PR 27).
+    Taken only by the single-worker program (one client on one device),
+    whose step otherwise does not load beside two resident tables; the
+    one-client-a-device programs of several devices (and ``shard.fsdp``
+    against them, pinned bit for bit) keep the un-batched head until
+    roadmap S3 measures them on four chips.
 
     ``gather_fn(table, ids) -> rows`` swaps the local ``table[ids]`` for
     the sharded-catalog exchange (``shard.table``,
@@ -286,11 +402,20 @@ def _encode_gathered(
             states = checkpoint_name(
                 lax.stop_gradient(gather_fn(token_states, ids)), "token_gather"
             )
-            return model.apply(
-                {"params": {"text_head": news_params}},
-                states,
-                method=NewsRecommender.encode_news,
-            )
+
+            def head(params, rows):
+                return model.apply(
+                    {"params": {"text_head": params}},
+                    rows,
+                    method=NewsRecommender.encode_news,
+                )
+
+            if batch_of_one:
+                return jax.vmap(head)(
+                    jax.tree_util.tree_map(lambda x: x[None], news_params),
+                    states[None],
+                )[0]
+            return head(news_params, states)
 
     u = uniq.shape[0]
     if not chunk or u <= chunk:
@@ -312,6 +437,7 @@ def _batch_news_vecs(
     fused: bool = False,
     gather_fn: Callable | None = None,
     n_news: int | None = None,
+    batch_of_one: bool = False,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Encode the batch's unique news once; gather into cand/history slots.
 
@@ -323,7 +449,7 @@ def _batch_news_vecs(
     encoded — the worst case B*(C+H) wastes text-tower FLOPs on
     duplicate/padding rows. Exact while distinct ids <= cap; callers must
     surface :func:`unique_overflow` when setting it. ``chunk``: see
-    :func:`_encode_gathered`. ``gather_fn``/``n_news``: the sharded-
+    :func:`_encode_gathered`, as for ``batch_of_one``. ``gather_fn``/``n_news``: the sharded-
     catalog form (``shard.table``) — ``token_states`` is then this
     device's local row block, so the GLOBAL row count must come in
     explicitly (the local block's dim 0 would wrongly cap the dedup).
@@ -341,7 +467,7 @@ def _batch_news_vecs(
     )
     vecs = _encode_gathered(
         model, news_params, token_states, uniq, chunk, fused=fused,
-        gather_fn=gather_fn,
+        gather_fn=gather_fn, batch_of_one=batch_of_one,
     )
     flat = vecs[inv]
     cand_vecs = flat[: b * c].reshape(b, c, -1)
@@ -535,6 +661,22 @@ def encode_all_news_sharded(
     return enc(news_params, padded)[:n]
 
 
+def build_corpus_encode(model: NewsRecommender, mesh: Mesh, spec: P = P()) -> Callable:
+    """``encode(news_params, token_states) -> (N, D)`` news vectors as ONE
+    program whose table argument states the at-rest format
+    (:func:`token_table_format`; ``spec`` is the table's at-rest
+    partitioning), so the corpus encode reads the committed table where it
+    lies. Sharded over every mesh device when there are several
+    (:func:`encode_all_news_sharded`), else :func:`encode_all_news`."""
+
+    def encode(news_params, token_states):
+        if mesh.size > 1:
+            return encode_all_news_sharded(model, news_params, token_states, mesh)
+        return encode_all_news(model, news_params, token_states)
+
+    return jax.jit(encode, in_shardings=(None, token_table_format(mesh, spec)))
+
+
 def _reshard_state_out(fn: Callable, state_shardings: Any) -> Callable:
     """Wrap a compiled program so its STATE output is re-committed to the
     at-rest FSDP layout (``shard.policy``) inside the same program: the
@@ -588,10 +730,7 @@ def _build_local_step(
     capacity scaling with the mesh. Joint ("head") mode only; the
     unsupported combinations fail fast here, at build time.
     """
-    if mode is None:
-        mode = {"table": "decoupled", "head": "joint", "finetune": "finetune"}.get(
-            cfg.model.text_encoder_mode, "joint"
-        )
+    mode = _resolve_mode(cfg, mode)
     text_encoder = None
     if mode == "finetune":
         from fedrec_tpu.models.bert import make_text_encoder
@@ -855,6 +994,11 @@ def _build_local_step(
                                 sharded_table.num_rows
                                 if sharded_table is not None else None
                             ),
+                            # the single worker (one client, one device)
+                            # runs un-vmapped (_cohort_call): its head takes
+                            # the cohort's form, or the step does not fit
+                            # beside two resident tables (_encode_gathered)
+                            batch_of_one=k == 1 and mesh.size == 1,
                         )
                     if n_seq > 1:
                         # candidate encoding is replicated across seq shards;
@@ -961,6 +1105,13 @@ def _build_local_step(
             grads_flat = jnp.concatenate(
                 [cand_g.reshape(-1, d), his_g.reshape(-1, d)]
             )
+            if state.news_grad_accum.ndim != 2:
+                raise ValueError(
+                    "the decoupled step accumulates per-news gradients, but "
+                    "this state holds no accumulator: it was initialised for "
+                    f"model.text_encoder_mode={cfg.model.text_encoder_mode!r} "
+                    "(init_client_state keeps one only for 'table')"
+                )
             accum = state.news_grad_accum.at[ids].add(grads_flat)
 
             user_g = strategy.sync_grads(user_g, sync_axes)
@@ -1096,6 +1247,7 @@ def build_fed_train_step(
 
     return jax.jit(
         _reshard_state_out(sharded_step, state_shardings),
+        in_shardings=_table_in_shardings(3, cfg, mode, mesh, table_spec),
         donate_argnums=(0, 1) if donate_batch else (0,),
     )
 
@@ -1152,6 +1304,7 @@ def build_fed_train_scan(
 
     return jax.jit(
         _reshard_state_out(sharded_scan, state_shardings),
+        in_shardings=_table_in_shardings(3, cfg, mode, mesh, table_spec),
         donate_argnums=(0, 1) if donate_batch else (0,),
     )
 
@@ -1267,6 +1420,7 @@ def build_fed_round_scan(
 
     return jax.jit(
         _reshard_state_out(sharded_rounds, state_shardings),
+        in_shardings=_table_in_shardings(4, cfg, mode, mesh, table_spec),
         donate_argnums=(0, 1) if donate_batch else (0,),
     )
 
@@ -1345,6 +1499,7 @@ def build_news_update_step(
 
     return jax.jit(
         _reshard_state_out(sharded_update, state_shardings),
+        in_shardings=(None, token_table_format(mesh)),
         donate_argnums=(0,),
     )
 

@@ -52,8 +52,8 @@ from fedrec_tpu.train.step import (
     build_fed_train_scan,
     build_param_sync,
     compressed_sync_active,
-    encode_all_news,
-    encode_all_news_sharded,
+    build_corpus_encode,
+    commit_token_table,
     shard_round_batches,
     shard_scan_batches,
     stack_batches,
@@ -366,10 +366,12 @@ class Trainer:
                 self.table_spec = TableSpec(
                     cfg.fed.mesh_axis, s, -(-n // s), n
                 )
-        elif self.token_states is not None and self.mesh.size > 1:
-            # the steps read the table replicated (in_spec P()): commit it
-            # to every device once, or each dispatch copies it from device 0
-            self.token_states = self._replicate_table(self.token_states)
+        if self.token_states is not None:
+            # the steps read the table replicated (in_spec P()), or its row
+            # blocks under shard.table (P(axis)), in the layout their gather
+            # reads: commit it to every device ONCE, or each dispatch copies
+            # it from device 0 and each step rewrites all of it
+            self.token_states = self._commit_token_states(self.token_states)
         self._state_shardings = None
         if cfg.shard.fsdp > 1:
             from fedrec_tpu.shard.policy import fsdp_state_shardings
@@ -564,6 +566,11 @@ class Trainer:
         self.news_update = build_news_update_step(
             self.model, cfg, self.mesh, self.strategy,
             state_shardings=self._state_shardings,
+        )
+        # cached-trunk corpus encode (decoupled table refresh, evaluation,
+        # the serving export): reads the committed table where it lies
+        self._corpus_encode = build_corpus_encode(
+            self.model, self.mesh, self._token_table_spec()
         )
         # fed.dcn_compress="auto": until the warmup window pins the real
         # map, the codec-sync body runs with an all-"none" map (dense sync
@@ -1654,6 +1661,32 @@ class Trainer:
 
         return jax.device_put(table, NamedSharding(self.mesh, PartitionSpec()))
 
+    def _token_table_spec(self):
+        """The token-state table's at-rest partitioning: row blocks over
+        the clients axis under ``shard.table``, else replicated."""
+        from jax.sharding import PartitionSpec
+
+        if self.table_spec is not None:
+            return PartitionSpec(self.cfg.fed.mesh_axis)
+        return PartitionSpec()
+
+    def _commit_token_states(self, table: jnp.ndarray) -> jnp.ndarray:
+        """Commit the token-state table to the at-rest format every program
+        that takes it states (``train.step.token_table_format``): one
+        ``table_commit`` span says which layout it was found in and which it
+        rests in now. The caller's array is left alone; the copy made here
+        lives as long as this trainer does."""
+        tracer = get_tracer()
+        t0 = tracer.now()
+        table, did = commit_token_table(
+            table, self.mesh, self._token_table_spec()
+        )
+        jax.block_until_ready(table)
+        tracer.add_span(
+            "table_commit", tracer.now() - t0, bytes=int(table.nbytes), **did
+        )
+        return table
+
     def _refresh_table(self) -> jnp.ndarray:
         _, news_params = self._client0_params()
         self._table = self._encode_states(news_params)
@@ -1665,21 +1698,13 @@ class Trainer:
         corpus scale). The result is pinned replicated so every consumer —
         train step (in_spec ``P()``), per-batch eval gathers, serving
         export — pays the post-encode all-gather exactly once here."""
+        vecs = self._corpus_encode(news_params, self.token_states)
         if self.table_spec is not None:
             # sharded catalog: the at-rest rows are already P(clients) and
             # padded, so the sharded encode reshards nothing; only the REAL
             # rows leave (eval/serving index by catalog id)
-            vecs = encode_all_news_sharded(
-                self.model, news_params, self.token_states, self.mesh
-            )
-            return self._replicate_table(vecs[: self.table_spec.num_rows])
-        if self.mesh.size > 1:
-            return self._replicate_table(
-                encode_all_news_sharded(
-                    self.model, news_params, self.token_states, self.mesh
-                )
-            )
-        return encode_all_news(self.model, news_params, self.token_states)
+            vecs = vecs[: self.table_spec.num_rows]
+        return self._replicate_table(vecs) if self.mesh.size > 1 else vecs
 
     def _encode_corpus(self, news_params) -> jnp.ndarray:
         """(N, D) news-vector table from client params, any text-encoder mode."""

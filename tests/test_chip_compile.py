@@ -185,15 +185,16 @@ def test_fused_history_score_compiles(compiled_kernels, one_chip, direction):
 
 
 # ------------------------------------------------------------ whole step
-def _joint_step_case(devices, num_clients):
+def _joint_step_case(devices, num_clients, batch=B, strategy="param_avg",
+                     rows=STEP_TABLE_ROWS):
     """(step, sync, args): the default (XLA) joint train step and the
     round-end sync over a mesh of described devices, full width, bf16."""
     cfg = ExperimentConfig()
     cfg.model.text_encoder_mode = "head"
     cfg.model.dtype = "bfloat16"
-    cfg.fed.strategy = "param_avg"
+    cfg.fed.strategy = strategy
     cfg.fed.num_clients = num_clients
-    cfg.data.batch_size = B
+    cfg.data.batch_size = batch
     model = NewsRecommender(cfg.model)
     axis = cfg.fed.mesh_axis
     mesh = Mesh(np.array(devices), (axis,))
@@ -201,7 +202,7 @@ def _joint_step_case(devices, num_clients):
     state = jax.eval_shape(
         lambda: replicate_state(
             init_client_state(
-                model, cfg, jax.random.PRNGKey(0), STEP_TABLE_ROWS, TITLE
+                model, cfg, jax.random.PRNGKey(0), rows, TITLE
             ),
             num_clients, jax.random.PRNGKey(1),
         )
@@ -210,18 +211,33 @@ def _joint_step_case(devices, num_clients):
         lambda x: _spec(x.shape, x.dtype, per_client), state
     )
     batch = {
-        "candidates": _spec((num_clients, B, CANDS), "int32", per_client),
-        "history": _spec((num_clients, B, HIS), "int32", per_client),
-        "labels": _spec((num_clients, B), "int32", per_client),
+        "candidates": _spec((num_clients, batch, CANDS), "int32", per_client),
+        "history": _spec((num_clients, batch, HIS), "int32", per_client),
+        "labels": _spec((num_clients, batch), "int32", per_client),
     }
-    table = _spec(
-        (STEP_TABLE_ROWS, TITLE, TRUNK), "bfloat16", NamedSharding(mesh, P())
-    )
+    table = _spec((rows, TITLE, TRUNK), "bfloat16", NamedSharding(mesh, P()))
     weights = _spec((num_clients,), "float32", NamedSharding(mesh, P()))
-    strategy = get_strategy("param_avg")
-    step = build_fed_train_step(model, cfg, strategy, mesh, mode="joint")
+    step = build_fed_train_step(
+        model, cfg, get_strategy(strategy), mesh, mode="joint"
+    )
     sync = build_param_sync(cfg, mesh)
     return step, sync, (state, batch, table), weights
+
+
+def _table_copies(compiled, table) -> list[str]:
+    """``copy`` instructions whose result has the whole table's shape, as
+    ``chip_smoke.py`` looks for them in the step that ran on the chip."""
+    import sys
+    from pathlib import Path
+
+    sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
+    import chip_smoke
+
+    return chip_smoke.table_copies(compiled.as_text(), table)
+
+
+def _table_layout(compiled) -> tuple:
+    return tuple(compiled.input_formats[0][2].layout.major_to_minor)
 
 
 def test_joint_step_compiles_for_one_chip(topo):
@@ -233,6 +249,40 @@ def test_joint_step_compiles_for_one_chip(topo):
              + mem.output_size_in_bytes - mem.alias_size_in_bytes)
     assert 0 < total < 16e9
     assert "all-reduce" not in compiled.as_text()  # param_avg: no per-step sync
+    # the table is read where it rests: row-major, stated by the program
+    assert _table_layout(compiled) == (0, 1, 2)
+    assert _table_copies(compiled, args[2]) == []
+
+
+def test_the_chips_own_table_layout_costs_the_step_a_copy(topo):
+    """Why the step states the table's format (PERF.md section 5): the same
+    program left to the chip's own layout for (N, 50, 768) takes the table
+    with the 50-axis major and rewrites all of it before the gather."""
+    step, _, args, _ = _joint_step_case(topo.devices[:1], num_clients=2)
+    compiled = jax.jit(step.__wrapped__).lower(*args).compile()
+    assert _table_layout(compiled) == (1, 0, 2)
+    assert len(_table_copies(compiled, args[2])) == 1
+
+
+def test_single_worker_step_lays_the_gathered_rows_out_once(topo):
+    """``central.b512``'s step: one client, B=512, un-vmapped. Its text head
+    is compiled as a batch of one (``_encode_gathered``): un-batched,
+    XLA:TPU copies the 28,160 gathered rows into another layout for the
+    head's first product (7.03 ms a step; ledger, PR 26) and the step's
+    temporaries reach 6.28 GB, which does not fit beside the benchmark's
+    two resident tables (PERF.md section 6, PR 27)."""
+    import re
+
+    step, _, args, _ = _joint_step_case(
+        topo.devices[:1], num_clients=1, batch=512, strategy="grad_avg",
+        rows=32_768,    # more rows than the step gathers, as in the cell
+    )
+    compiled = step.lower(*args).compile()
+    gathered = re.escape(f"bf16[{512 * (CANDS + HIS)},{TITLE},{TRUNK}]")
+    # (inside a fusion such a copy is a ROOT and costs no buffer of its own)
+    assert re.findall(rf"\n  %\S+ = {gathered}\S* copy\(", compiled.as_text()) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 4.5e9
+    assert _table_copies(compiled, args[2]) == []
 
 
 def test_joint_step_compiles_for_four_chips(topo):
@@ -240,7 +290,10 @@ def test_joint_step_compiles_for_four_chips(topo):
     collective is the psum behind its mean-loss metric, and the round-end
     sync averages the parameters with an all-reduce over the four chips."""
     step, sync, args, weights = _joint_step_case(topo.devices[:4], num_clients=4)
-    step_text = step.lower(*args).compile().as_text()
+    compiled = step.lower(*args).compile()
+    step_text = compiled.as_text()
     assert "replica_groups={{0,1,2,3}}" in step_text
+    assert _table_layout(compiled) == (0, 1, 2)
+    assert _table_copies(compiled, args[2]) == []
     sync_text = sync.lower(args[0], weights).compile().as_text()
     assert "all-reduce" in sync_text and "replica_groups={{0,1,2,3}}" in sync_text

@@ -49,7 +49,29 @@ def test_trainer_phase(work_dir, capsys):
     assert 0.5 < out["val_auc"] <= 1.0  # the synthetic signal is learnable
     assert len(out["later_round_seconds"]) == 1
     assert (work_dir / "train").is_dir()  # the end-of-run snapshot
-    assert "train.final_loss:" in capsys.readouterr().out
+    # the table's at-rest check: committed once, row-major, no copy of it
+    assert out["table_commit"]["set"] == "(0, 1, 2)"
+    assert "major_to_minor=(0, 1, 2)" in out["step_states_table_layout"]
+    printed = capsys.readouterr().out
+    assert "train.final_loss:" in printed and "train.table_format:" in printed
+
+
+def test_a_copy_of_the_whole_table_in_the_step_is_found():
+    """The two ``copy`` lines are the parent's (ledger, PR 26: 16.33 ms a
+    step); the gather's result has another shape and is no such copy."""
+    import jax.numpy as jnp
+
+    table = jnp.zeros((64, 5, 8), jnp.bfloat16)
+    hlo = """
+  %table.1 = bf16[64,5,8]{2,0,1:T(8,128)(2,1)} parameter(57), sharding={replicated}
+  %copy.363 = bf16[64,5,8]{2,1,0:T(8,128)(2,1)} copy(%table.1), sharding={replicated}
+  %fusion = bf16[28,5,8]{2,1,0:T(8,128)(2,1)} fusion(%copy.363, %copy-done.35), kind=kCustom
+  %copy.445 = bf16[28,5,8]{0,2,1:T(8,128)(2,1)} copy(%fusion.2)
+  %copy.9 = bf16[64,5,8]{2,1,0} copy(bf16[64,5,8]{2,0,1} %p)
+"""
+    found = chip_smoke.table_copies(hlo, table)
+    assert len(found) == 2 and found[0].startswith("%copy.363")
+    assert chip_smoke.table_copies(hlo, jnp.zeros((64, 5, 8), jnp.float32)) == []
 
 
 def test_server_phase(capsys):
