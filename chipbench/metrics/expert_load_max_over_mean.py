@@ -1,0 +1,13 @@
+"""How uneven the routing is: the tokens on the fullest held expert of a
+layer over the mean of the held experts, averaged over the last round's
+steps, clients and layers, in per cent (100 = even). The program counts the
+(token, choice) pairs on each held expert inside the step and publishes the
+ratio as the gauge ``moe.expert_load_max_over_mean`` (``obs/registry.py``). A property of
+the traffic and the weights, not of the chip: the grouped products' time
+follows the fullest expert's tiles. Source: program counter. Layer:
+sparse-expert trunk. Moves ``train_samples_per_s``."""
+
+
+def read(run: dict):
+    routing = run.get("routing")
+    return None if not routing else 100.0 * routing["load_max_over_mean"]

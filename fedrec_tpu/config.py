@@ -103,6 +103,21 @@ class ModelConfig:
     # "head"     — frozen-trunk token states + trainable additive-attn/linear head
     # "finetune" — full DistilBERT fine-tuned in-loop (BASELINE config 5)
     text_encoder_mode: str = "table"
+    # trunk family for "finetune" mode:
+    #   "distilbert"    — dense bidirectional post-LN trunk (models/bert.py)
+    #   "sparse_expert" — causal decoder with routed ReGLU experts, grouped-
+    #                     query attention, global and sliding layers
+    #                     (models/sparse_trunk.py: SparseTrunkConfig holds
+    #                     the published widths; bert_hidden, trunk_layers,
+    #                     trunk_heads, trunk_ffn (one expert's width) and
+    #                     trunk_vocab (vocabulary rows held) are read from
+    #                     here, as for distilbert)
+    text_trunk: str = "distilbert"
+    # sparse_expert only: the share of every layer's experts held here (one
+    # chip of an expert-parallel group): ids first..first+held-1 of the
+    # family's experts; the router still scores all of them. 0 held = all.
+    trunk_first_expert: int = 0
+    trunk_experts_held: int = 0
     # trunk architecture for "finetune" mode (defaults = distilbert-base;
     # shrink for tests). dim is bert_hidden above.
     trunk_layers: int = 6

@@ -266,14 +266,26 @@ def trunk_config_from(model_cfg) -> DistilBertConfig:
 
 
 def make_text_encoder(model_cfg) -> "TextEncoder":
-    """Full trainable text tower for ``text_encoder_mode='finetune'``."""
+    """Full trainable text tower for ``text_encoder_mode='finetune'``; the
+    trunk's family is ``model.text_trunk``."""
     if getattr(model_cfg, "text_head_arch", "additive") != "additive":
         raise NotImplementedError(
             "text_encoder_mode='finetune' supports only the additive head; "
             "use text_head_arch='cnn' with mode 'head' or 'table'"
         )
+    if model_cfg.text_trunk == "distilbert":
+        trunk_cfg = trunk_config_from(model_cfg)
+    elif model_cfg.text_trunk == "sparse_expert":
+        from fedrec_tpu.models.sparse_trunk import sparse_trunk_config_from
+
+        trunk_cfg = sparse_trunk_config_from(model_cfg)
+    else:
+        raise ValueError(
+            f"unknown model.text_trunk {model_cfg.text_trunk!r} "
+            "(distilbert|sparse_expert)"
+        )
     return TextEncoder(
-        trunk_cfg=trunk_config_from(model_cfg),
+        trunk_cfg=trunk_cfg,
         news_dim=model_cfg.news_dim,
         stable_softmax=model_cfg.stable_softmax,
         dtype=jnp.dtype(model_cfg.dtype),
@@ -282,7 +294,10 @@ def make_text_encoder(model_cfg) -> "TextEncoder":
 
 
 class TextEncoder(nn.Module):
-    """Full text tower: DistilBERT trunk + additive-attention head.
+    """Full text tower: trunk + additive-attention head. The trunk is
+    DistilBERT, or for a ``SparseTrunkConfig`` the sparse-expert decoder
+    of ``models.sparse_trunk``, whose routing counters are sown into the
+    ``routing`` collection (``apply(..., mutable=["routing"])`` reads them).
 
     The in-loop fine-tuning path (``text_encoder_mode='finetune'``,
     BASELINE config 5). ``remat=True`` rematerializes each transformer block
@@ -291,7 +306,7 @@ class TextEncoder(nn.Module):
     jitted program over batched token ids.
     """
 
-    trunk_cfg: DistilBertConfig = DistilBertConfig()
+    trunk_cfg: Any = DistilBertConfig()
     news_dim: int = 400
     stable_softmax: bool = True
     dtype: jnp.dtype = jnp.float32
@@ -303,18 +318,29 @@ class TextEncoder(nn.Module):
     ) -> jnp.ndarray:
         """(..., 2, L) stacked [ids; mask] -> (..., news_dim)."""
         from fedrec_tpu.models.encoders import TextHead
+        from fedrec_tpu.models.sparse_trunk import SparseExpertTrunk, SparseTrunkConfig
 
         batch_shape = tokens.shape[:-2]
         flat = tokens.reshape(-1, 2, tokens.shape[-1])
         ids, mask = flat[:, 0].astype(jnp.int32), flat[:, 1].astype(jnp.int32)
-        states = DistilBert(
-            self.trunk_cfg, dtype=self.dtype, remat=self.remat, name="trunk"
-        )(ids, mask, train)
-        vecs = TextHead(
+        head = TextHead(
             news_dim=self.news_dim,
             bert_hidden=self.trunk_cfg.dim,
             stable_softmax=self.stable_softmax,
             dtype=self.dtype,
             name="head",
-        )(states)  # reference passes no token mask to the pooler (encoder.py:28)
+        )  # reference passes no token mask to the pooler (encoder.py:28)
+        if isinstance(self.trunk_cfg, SparseTrunkConfig):
+            states, routing = SparseExpertTrunk(
+                self.trunk_cfg, dtype=self.dtype, remat=self.remat, name="trunk"
+            )(ids, mask)
+            for name, value in routing.items():
+                self.sow("routing", name, value)
+            with jax.named_scope("text_head"):
+                vecs = head(states)
+        else:
+            states = DistilBert(
+                self.trunk_cfg, dtype=self.dtype, remat=self.remat, name="trunk"
+            )(ids, mask, train)
+            vecs = head(states)
         return vecs.reshape(*batch_shape, self.news_dim)

@@ -1,0 +1,430 @@
+"""Sparse-expert decoder trunk, read as a text encoder (Flax linen).
+
+A causal decoder whose feed-forward is a routed mixture of ReGLU experts:
+token ids -> decoder layers -> final RMSNorm -> per-token states, which
+``models.bert.TextEncoder`` pools with the repo's additive head. Trained in
+loop by the click loss (``text_encoder_mode='finetune'``); no language-model
+head is built (nothing in the click loss reads logits).
+
+One layer, for tokens ``x`` (T x d)::
+
+    h   = RMSNorm(x; g1)
+    r   = h Wr                      router over ALL experts, reads the
+    S,I = top_k(r); p = softmax(S)  pre-attention normed input; float32
+    q,k,v = h Wq, h Wk, h Wv        rotary on q,k in sliding layers only
+    a   = softmax(q k^T / sqrt(D) + mask_l) v   grouped-query
+    x'  = x + a Wo
+    u   = RMSNorm(x'; g2)
+    y   = sum over e in I, e HELD HERE, of p_e Wdown_e(relu(Wgate_e u) * Wup_e u)
+    out = x' + y
+
+Layer ``l`` with ``l % global_every == 0`` is global (full causal mask, no
+positional encoding); the others are sliding (causal, keys ``j`` with
+``i - j < sliding_window``, rotary over the whole head).
+
+The expert layer is told which experts it holds (``first_expert``,
+``experts_held``): it routes over all of them and computes its own experts'
+part of the result, as one chip of an expert-parallel group does before the
+exchange. What the absent experts would add is left out; nothing stands in
+for the absent chips. The embedding likewise holds ``vocab_held`` rows from
+``vocab_first``; an id outside the slice embeds to zero.
+
+No token is dropped: the assignments are sorted by expert and the grouped
+products (``grouped_matmul``: ``jax.lax.ragged_dot``, XLA:TPU's grouped
+matmul kernel) run over the true group sizes. Static shapes make the sorted
+buffer as long as the worst case (every choice of every token on a held
+expert), so the layer works through the tokens in chunks of at most
+``MAX_CHUNK_TOKENS``, each chunk rematerialised in the backward pass: memory
+is bounded by one chunk's worst case, compute follows the rows really there.
+"""
+
+from __future__ import annotations
+
+from dataclasses import dataclass
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+from flax import linen as nn
+from jax import lax
+
+# the expert layer's sorted buffer holds chunk x experts_per_token rows,
+# padded to whole tiles of ROW_TILE rows
+MAX_CHUNK_TOKENS = 16384
+ROW_TILE = 512
+
+
+@dataclass(frozen=True)
+class SparseTrunkConfig:
+    """Architecture knobs; defaults = ``SmallThinker-21BA3B-Instruct``
+    (huggingface.co/PowerInfer/SmallThinker-21BA3B-Instruct, config.json),
+    whole: every expert and every vocabulary row held here."""
+
+    vocab_size: int = 151936
+    dim: int = 2560                    # hidden_size
+    n_layers: int = 52
+    n_heads: int = 28
+    n_kv_heads: int = 4
+    head_dim: int = 128
+    n_experts: int = 64                # moe_num_primary_experts
+    experts_per_token: int = 6         # moe_num_active_primary_experts
+    expert_dim: int = 768              # moe_ffn_hidden_size
+    rms_norm_eps: float = 1e-6
+    rope_theta: float = 1.5e6
+    sliding_window: int = 4096
+    global_every: int = 4              # sliding_window_layout / rope_layout period
+    # this chip's share of a layer
+    first_expert: int = 0
+    experts_held: int = 64
+    vocab_first: int = 0
+    vocab_held: int = 151936
+
+    def __post_init__(self):
+        if self.n_heads % self.n_kv_heads:
+            raise ValueError(
+                f"{self.n_heads} query heads do not group over "
+                f"{self.n_kv_heads} key/value heads"
+            )
+        if not 0 < self.experts_held <= self.n_experts - self.first_expert:
+            raise ValueError(
+                f"experts {self.first_expert}..{self.first_expert + self.experts_held - 1} "
+                f"are not among the {self.n_experts} of a layer"
+            )
+        if not 0 < self.vocab_held <= self.vocab_size - self.vocab_first:
+            raise ValueError(
+                f"vocabulary rows {self.vocab_first}..{self.vocab_first + self.vocab_held - 1} "
+                f"are not among the {self.vocab_size}"
+            )
+
+    def is_global(self, layer: int) -> bool:
+        return layer % self.global_every == 0
+
+
+def sparse_trunk_config_from(model_cfg) -> SparseTrunkConfig:
+    """SparseTrunkConfig from a ``ModelConfig``: the depth, the share held
+    and the widths tests shrink come from it, the rest is as published."""
+    base = SparseTrunkConfig()
+    return SparseTrunkConfig(
+        dim=model_cfg.bert_hidden,
+        n_layers=model_cfg.trunk_layers,
+        n_heads=model_cfg.trunk_heads,
+        expert_dim=model_cfg.trunk_ffn,
+        first_expert=model_cfg.trunk_first_expert,
+        experts_held=model_cfg.trunk_experts_held or base.n_experts,
+        vocab_held=model_cfg.trunk_vocab,
+    )
+
+
+class RMSNorm(nn.Module):
+    eps: float
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
+        x32 = x.astype(jnp.float32)
+        y = x32 * lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + self.eps)
+        return (y * scale).astype(self.dtype)
+
+
+def rotary(x: jnp.ndarray, theta: float) -> jnp.ndarray:
+    """Rotary embedding over the whole head, half-split pairing
+    (``rotate_half``): x is (N, L, ..., D), position = index along L."""
+    half = x.shape[-1] // 2
+    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    angle = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * inv_freq
+    shape = (1, x.shape[1]) + (1,) * (x.ndim - 3) + (half,)
+    cos, sin = jnp.cos(angle).reshape(shape), jnp.sin(angle).reshape(shape)
+    x32 = x.astype(jnp.float32)
+    x1, x2 = x32[..., :half], x32[..., half:]
+    return jnp.concatenate(
+        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
+    ).astype(x.dtype)
+
+
+def attention_allowed(
+    mask: jnp.ndarray, window: int | None
+) -> jnp.ndarray:
+    """(N, L) key mask -> (N, 1, 1, L, L) bool: query i may read key j when
+    j <= i, key j is a real token and, in a sliding layer, i - j < window."""
+    L = mask.shape[-1]
+    i, j = jnp.arange(L)[:, None], jnp.arange(L)[None, :]
+    allowed = j <= i
+    if window is not None:
+        allowed &= (i - j) < window
+    return allowed[None, None, None] & (mask[:, None, None, None, :] > 0)
+
+
+class _Attention(nn.Module):
+    """Grouped-query causal self-attention, no bias. Query head ``h`` reads
+    key/value head ``h // (n_heads / n_kv_heads)``."""
+
+    cfg: SparseTrunkConfig
+    is_global: bool
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, h: jnp.ndarray, mask: jnp.ndarray) -> jnp.ndarray:
+        c = self.cfg
+        n, L, _ = h.shape
+        kv, group, hd = c.n_kv_heads, c.n_heads // c.n_kv_heads, c.head_dim
+        dense = lambda width, name: nn.Dense(  # noqa: E731
+            width, use_bias=False, dtype=self.dtype, name=name
+        )
+        q = dense(c.n_heads * hd, "q_proj")(h).reshape(n, L, kv, group, hd)
+        k = dense(kv * hd, "k_proj")(h).reshape(n, L, kv, hd)
+        v = dense(kv * hd, "v_proj")(h).reshape(n, L, kv, hd)
+        if not self.is_global:
+            q, k = rotary(q, c.rope_theta), rotary(k, c.rope_theta)
+        scores = jnp.einsum(
+            "nqkgd,nskd->nkgqs", q, k, preferred_element_type=jnp.float32
+        ) / jnp.sqrt(jnp.float32(hd))
+        allowed = attention_allowed(
+            mask, None if self.is_global else c.sliding_window
+        )
+        probs = jax.nn.softmax(jnp.where(allowed, scores, -1e30), axis=-1)
+        ctx = jnp.einsum("nkgqs,nskd->nqkgd", probs.astype(self.dtype), v)
+        return dense(c.dim, "o_proj")(ctx.reshape(n, L, c.n_heads * hd))
+
+
+def route(
+    h: jnp.ndarray, w_router: jnp.ndarray, k: int
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Router in float32 whatever the model's dtype, so that the choice of
+    experts does not turn on a rounding of ``w_router``: (T, d) -> the
+    chosen experts (T, k) int32 and their weights (T, k), a softmax over
+    the k chosen. The chosen logits are read back through a one-hot
+    product, whose transpose is a product too (``top_k``'s is a scatter,
+    which a TPU runs row by row)."""
+    logits = jnp.einsum(
+        "td,de->te", h.astype(jnp.float32), w_router.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST,
+    )
+    _, idx = lax.top_k(lax.stop_gradient(logits), k)
+    chosen = jax.nn.one_hot(idx, logits.shape[-1], dtype=logits.dtype)
+    top = jnp.einsum("tke,te->tk", chosen, logits, precision=lax.Precision.HIGHEST)
+    return idx.astype(jnp.int32), jax.nn.softmax(top, axis=-1)
+
+
+# Rows move between pair order (token t's choice c is pair t*k + c) and
+# expert order (pairs sorted by held expert, then the absent ones, then
+# padding) by gathers only: the sort gives the permutation and its inverse,
+# so each gather's cotangent goes back by a gather too, where the transpose
+# of a gather is a scatter (which a TPU runs row by row).
+@partial(jax.custom_vjp, nondiff_argnums=(3,))
+def to_expert_order(u, order, back, k):
+    """(T, d) token rows -> (rows, d): row i is the token of pair order[i]."""
+    return u[jnp.minimum(order // k, u.shape[0] - 1)]
+
+
+def _to_expert_order_bwd(k, back, ct):
+    per_pair = ct[back].reshape(back.shape[0] // k, k, ct.shape[-1])
+    return jnp.sum(per_pair, axis=1, dtype=jnp.float32).astype(ct.dtype), None, None
+
+
+to_expert_order.defvjp(
+    lambda u, order, back, k: (to_expert_order(u, order, back, k), back),
+    _to_expert_order_bwd,
+)
+
+
+@jax.custom_vjp
+def to_pair_order(y, order, back):
+    """(rows, d) in expert order -> (T*k, d) in pair order."""
+    return y[back]
+
+
+def _to_pair_order_bwd(order, ct):
+    padded = jnp.pad(ct, ((0, order.shape[0] - ct.shape[0]), (0, 0)))
+    return padded[order], None, None
+
+
+to_pair_order.defvjp(
+    lambda y, order, back: (y[back], order), _to_pair_order_bwd
+)
+
+
+def _chunks(tokens: int) -> int:
+    """The fewest equal chunks of at most MAX_CHUNK_TOKENS tokens."""
+    return next(
+        c for c in range(1, tokens + 1)
+        if tokens % c == 0 and tokens // c <= MAX_CHUNK_TOKENS
+    )
+
+
+# The grouped product: rows of ``x`` in consecutive groups of ``sizes``, group
+# g times ``w[g]``. XLA:TPU compiles ``lax.ragged_dot`` to its grouped-matmul
+# kernel but refuses it a batch dimension, which is what ``vmap`` over an
+# in-device cohort of clients gives it; so under ``vmap`` the product and its
+# transposes run client by client (``lax.map``), everything around them stays
+# batched. The loop is not differentiated: the cotangents are stated here.
+_product = jax.custom_batching.sequential_vmap(lax.ragged_dot)
+
+
+@jax.custom_batching.sequential_vmap
+def _product_transposes(x, w, sizes, ct):
+    _, pull = jax.vjp(lambda x, w: lax.ragged_dot(x, w, sizes), x, w)
+    return pull(ct)
+
+
+@jax.custom_vjp
+def grouped_matmul(x: jnp.ndarray, w: jnp.ndarray, sizes: jnp.ndarray) -> jnp.ndarray:
+    """(rows, d) x (groups, d, f) -> (rows, f); rows past the groups are
+    left unwritten."""
+    return _product(x, w, sizes)
+
+
+grouped_matmul.defvjp(
+    lambda x, w, sizes: (_product(x, w, sizes), (x, w, sizes)),
+    lambda res, ct: (*_product_transposes(*res, ct), None),
+)
+
+
+def held_experts_output(
+    u: jnp.ndarray, idx: jnp.ndarray, p: jnp.ndarray,
+    w_gate: jnp.ndarray, w_up: jnp.ndarray, w_down: jnp.ndarray,
+    first_expert: int,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """The held experts' part of the layer's output for one chunk of tokens.
+
+    ``u`` (T, d) normed inputs, ``idx`` / ``p`` (T, k) every token's chosen
+    experts and their weights, ``w_*`` the held experts' weights
+    ``(held, ...)`` in the dtype to compute in. Returns ``y`` (T, d) and the
+    tokens routed to each held expert, (held,) int32.
+
+    The (token, choice) pairs are sorted by held expert, pairs on absent
+    experts last; the three grouped products run over the held groups. Rows
+    past the held groups are pairs on absent experts (and padding): a
+    grouped product leaves them unwritten, so they are zeroed on the way in
+    and out (and, by the same selects transposed, in the backward pass).
+    """
+    t, k = idx.shape
+    held = w_gate.shape[0]
+    with jax.named_scope("moe_route"):
+        local = idx.reshape(-1) - first_expert
+        group = jnp.where((local >= 0) & (local < held), local, held)
+        # whole row tiles: the grouped-matmul kernel is several times slower
+        # on a row count that is not a multiple of its tile
+        group = jnp.pad(group, (0, -(t * k) % ROW_TILE), constant_values=held)
+        order = jnp.argsort(group, stable=True)
+        back = jnp.argsort(order)[: t * k]
+        sizes = jnp.sum(
+            group[:, None] == jnp.arange(held)[None, :], axis=0, dtype=jnp.int32
+        )
+        here = (jnp.arange(group.shape[0]) < jnp.sum(sizes))[:, None]
+        xs = jnp.where(here, to_expert_order(u, order, back, k), 0)
+    with jax.named_scope("moe_experts"):
+        gate = jnp.where(here, grouped_matmul(xs, w_gate, sizes), 0)
+        up = jnp.where(here, grouped_matmul(xs, w_up, sizes), 0)
+        down = grouped_matmul(jax.nn.relu(gate) * up, w_down, sizes)
+        down = jnp.where(here, down, 0)
+    with jax.named_scope("moe_combine"):
+        per_choice = to_pair_order(down, order, back).reshape(t, k, -1)
+        y = jnp.einsum(
+            "tkd,tk->td", per_choice, p.astype(down.dtype),
+            preferred_element_type=jnp.float32,
+        ).astype(down.dtype)
+    return y, sizes
+
+
+class _Experts(nn.Module):
+    """The experts held here: ReGLU, no bias."""
+
+    cfg: SparseTrunkConfig
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(
+        self, u: jnp.ndarray, idx: jnp.ndarray, p: jnp.ndarray
+    ) -> tuple[jnp.ndarray, jnp.ndarray]:
+        c = self.cfg
+        init = nn.initializers.variance_scaling(
+            1.0, "fan_in", "normal", in_axis=-2, out_axis=-1, batch_axis=(0,)
+        )
+        held, d, f = c.experts_held, c.dim, c.expert_dim
+        weights = tuple(
+            self.param(name, init, shape).astype(self.dtype)
+            for name, shape in (
+                ("w_gate", (held, d, f)), ("w_up", (held, d, f)),
+                ("w_down", (held, f, d)),
+            )
+        )
+
+        def chunk(args):
+            return held_experts_output(*args, *weights, c.first_expert)
+
+        n = _chunks(u.shape[0])
+        if n == 1:
+            return chunk((u, idx, p))
+        split = lambda x: x.reshape(n, -1, x.shape[-1])  # noqa: E731
+        y, sizes = lax.map(jax.checkpoint(chunk), (split(u), split(idx), split(p)))
+        return y.reshape(u.shape), jnp.sum(sizes, axis=0)
+
+
+class _DecoderLayer(nn.Module):
+    cfg: SparseTrunkConfig
+    is_global: bool
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(
+        self, x: jnp.ndarray, mask: jnp.ndarray
+    ) -> tuple[jnp.ndarray, jnp.ndarray]:
+        c = self.cfg
+        n, L, d = x.shape
+        norm = lambda name: RMSNorm(c.rms_norm_eps, self.dtype, name=name)  # noqa: E731
+        with jax.named_scope("trunk_attention"):
+            h = norm("attn_norm")(x)
+        with jax.named_scope("moe_route"):
+            w_router = self.param(
+                "router", nn.initializers.lecun_normal(), (d, c.n_experts)
+            )
+            idx, p = route(h.reshape(n * L, d), w_router, c.experts_per_token)
+        with jax.named_scope("trunk_attention"):
+            x = x + _Attention(c, self.is_global, self.dtype, name="attn")(h, mask)
+        with jax.named_scope("moe_route"):
+            u = norm("ffn_norm")(x)
+        y, counts = _Experts(c, self.dtype, name="experts")(
+            u.reshape(n * L, d), idx, p
+        )
+        return x + y.reshape(n, L, d), counts
+
+
+class SparseExpertTrunk(nn.Module):
+    """Token ids + attention mask -> per-token states (N, L, dim), and what
+    the routers did: ``expert_tokens`` (layers, experts_held) int32, the
+    (token, choice) pairs that fell on each held expert, and
+    ``absent_share``, the share of all pairs that fell on absent experts."""
+
+    cfg: SparseTrunkConfig = SparseTrunkConfig()
+    dtype: jnp.dtype = jnp.float32
+    remat: bool = False               # jax.checkpoint each layer
+
+    @nn.compact
+    def __call__(
+        self, input_ids: jnp.ndarray, attention_mask: jnp.ndarray
+    ) -> tuple[jnp.ndarray, dict]:
+        c = self.cfg
+        with jax.named_scope("trunk_embed"):
+            table = self.param(
+                "embedding", nn.initializers.normal(0.02), (c.vocab_held, c.dim)
+            )
+            local = input_ids - c.vocab_first
+            held = (local >= 0) & (local < c.vocab_held)
+            rows = table[jnp.clip(local, 0, c.vocab_held - 1)]
+            x = jnp.where(held[..., None], rows, 0).astype(self.dtype)
+        layer_cls = nn.remat(_DecoderLayer) if self.remat else _DecoderLayer
+        counts = []
+        for i in range(c.n_layers):
+            x, n = layer_cls(c, c.is_global(i), self.dtype, name=f"layer_{i}")(
+                x, attention_mask
+            )
+            counts.append(n)
+        x = RMSNorm(c.rms_norm_eps, self.dtype, name="final_norm")(x)
+        expert_tokens = jnp.stack(counts)
+        pairs = c.n_layers * input_ids.size * c.experts_per_token
+        routing = {
+            "expert_tokens": expert_tokens,
+            "absent_share": 1.0 - jnp.sum(expert_tokens) / jnp.float32(pairs),
+        }
+        return x, routing

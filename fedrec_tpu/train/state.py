@@ -92,7 +92,23 @@ def init_client_state(
     num_news: int,
     title_len: int | None = None,
 ) -> ClientState:
-    """Initialize one client's state (shapes from config; no data needed)."""
+    """Initialize one client's state (shapes from config; no data needed).
+    With a trunk to train (``finetune``) as ONE compiled program: run op by
+    op, a trunk's init compiles a hundred small programs on the way."""
+    if cfg.model.text_encoder_mode == "finetune":
+        return jax.jit(
+            lambda key: _init_client_state(model, cfg, key, num_news, title_len)
+        )(rng)
+    return _init_client_state(model, cfg, rng, num_news, title_len)
+
+
+def _init_client_state(
+    model: NewsRecommender,
+    cfg: ExperimentConfig,
+    rng: jax.Array,
+    num_news: int,
+    title_len: int | None,
+) -> ClientState:
     title_len = title_len or cfg.data.max_title_len
     init_rng, state_rng = jax.random.split(rng)
     dummy_states = jnp.zeros((1, title_len, cfg.model.bert_hidden), cfg.model.dtype)

@@ -501,14 +501,15 @@ def _encode_unique_tokens(
     ids: jnp.ndarray,
     dropout_rng: jax.Array | None,
     cap: int = 0,
-) -> jnp.ndarray:
+) -> tuple[jnp.ndarray, dict]:
     """Encode a flat id vector's unique news through the full TextEncoder.
 
     Gathers the unique token rows from the (N, 2, L) table, runs trunk +
     head once per distinct news, and scatters back to (len(ids), D).
     ``cap`` bounds the unique slots like in :func:`_batch_news_vecs` — it
     matters MOST here, where every slot pays a full trunk forward+backward;
-    callers must surface :func:`unique_overflow`.
+    callers must surface :func:`unique_overflow`. Also returns the trunk's
+    routing counters (``models.sparse_trunk``; empty for a dense trunk).
     """
     size = min(ids.shape[0], tokens_table.shape[0])
     if cap:
@@ -516,13 +517,15 @@ def _encode_unique_tokens(
     uniq, inv = jnp.unique(ids, size=size, fill_value=0, return_inverse=True)
     toks = tokens_table[uniq]  # (size, 2, L)
     train = dropout_rng is not None
-    vecs = text_encoder.apply(
+    vecs, sown = text_encoder.apply(
         {"params": news_params},
         toks,
         train,
         rngs={"dropout": dropout_rng} if train else None,
+        mutable=["routing"],
     )  # (size, D)
-    return vecs[inv]
+    routing = {k: v[0] for k, v in sown.get("routing", {}).items()}
+    return vecs[inv], routing
 
 
 def _batch_news_vecs_tokens(
@@ -533,18 +536,19 @@ def _batch_news_vecs_tokens(
     history: jnp.ndarray,
     dropout_rng: jax.Array | None,
     cap: int = 0,
-) -> tuple[jnp.ndarray, jnp.ndarray]:
+) -> tuple[jnp.ndarray, jnp.ndarray, dict]:
     """Finetune-mode analogue of ``_batch_news_vecs``: one joint dedup over
-    candidate + history ids, full trainable TextEncoder on the unique rows."""
+    candidate + history ids, full trainable TextEncoder on the unique rows;
+    with the trunk's routing counters."""
     b, c = candidates.shape
     h = history.shape[1]
     ids = jnp.concatenate([candidates.reshape(-1), history.reshape(-1)])
-    flat = _encode_unique_tokens(
+    flat, routing = _encode_unique_tokens(
         text_encoder, news_params, tokens_table, ids, dropout_rng, cap=cap
     )
     cand_vecs = flat[: b * c].reshape(b, c, -1)
     his_vecs = flat[b * c :].reshape(b, h, -1)
-    return cand_vecs, his_vecs
+    return cand_vecs, his_vecs, routing
 
 
 def _encode_tokens_rows(
@@ -567,7 +571,7 @@ def _encode_tokens_rows(
     are free to differ.
     """
     b, k = ids_2d.shape
-    flat = _encode_unique_tokens(
+    flat, _ = _encode_unique_tokens(
         text_encoder, news_params, tokens_table, ids_2d.reshape(-1), dropout_rng
     )
     return flat.reshape(b, k, -1)
@@ -891,6 +895,7 @@ def _build_local_step(
         # gets the bound its own B implies (bucketed policy or the global)
         cap = resolve_unique_cap(cfg, batch["labels"].shape[0])
         dp_stats = None
+        routing: dict = {}
         sentry_grads: tuple = ()
         sentry_updates: tuple = ()
         rng, dropout_rng, noise_rng = jax.random.split(state.rng, 3)
@@ -959,6 +964,7 @@ def _build_local_step(
             else:
 
                 def loss_fn(user_params, news_params):
+                    routing: dict = {}
                     if mode == "finetune" and n_seq > 1:
                         # candidates and the local history shard are encoded
                         # separately so the candidate row layout — and hence
@@ -977,7 +983,7 @@ def _build_local_step(
                     elif mode == "finetune":
                         # table = raw (N, 2, L) token rows; full trunk + head
                         # runs (and trains) on the batch's unique news
-                        cand_vecs, his_vecs = _batch_news_vecs_tokens(
+                        cand_vecs, his_vecs, routing = _batch_news_vecs_tokens(
                             text_encoder, news_params, table,
                             batch["candidates"], batch["history"], enc_rng,
                             cap=cap,
@@ -1011,13 +1017,14 @@ def _build_local_step(
                         train=True,
                         rngs={"dropout": dropout_rng},
                     )
-                    return score_loss(
+                    loss = score_loss(
                         scores, batch["labels"], cfg.model.sigmoid_before_ce
                     )
+                    return loss, routing
 
-                loss, (user_g, news_g) = jax.value_and_grad(loss_fn, argnums=(0, 1))(
-                    state.user_params, state.news_params
-                )
+                (loss, routing), (user_g, news_g) = jax.value_and_grad(
+                    loss_fn, argnums=(0, 1), has_aux=True
+                )(state.user_params, state.news_params)
                 if n_seq > 1:
                     # each seq shard holds a partial param grad (its history
                     # slice); sum -> full grad, replicated over seq
@@ -1135,6 +1142,9 @@ def _build_local_step(
 
         mean_loss = lax.pmean(loss, axis_name=sync_axes)
         metrics = {"loss": loss, "mean_loss": mean_loss}
+        # a sparse-expert trunk's routing counters (models.sparse_trunk):
+        # tokens on each held expert a layer, share of pairs on absent ones
+        metrics.update({f"moe.{name}": v for name, v in routing.items()})
         if sentry:
             grad_norm = _tree_global_norm(*sentry_grads)
             update_norm = _tree_global_norm(*sentry_updates)

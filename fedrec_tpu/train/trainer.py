@@ -623,7 +623,11 @@ class Trainer:
         stacked = replicate_state(
             state0, cfg.fed.num_clients, jax.random.PRNGKey(cfg.train.seed + 1)
         )
+        # the stack is a copy: let go of the first before the placement makes
+        # a third (a trunk's state is gigabytes)
+        del state0
         self.state = self._place_state(stacked)
+        del stacked
         if self._pop_engine:
             # the pristine sidecar template a never-before-selected (or
             # quarantine-healed) logical client starts from: slot 0's
@@ -881,6 +885,25 @@ class Trainer:
             "unique-news cap overflow count (client-summed over steps; "
             "nonzero aborts the round)",
         )
+        # a sparse-expert trunk's routing counters (models.sparse_trunk),
+        # returned with the step's metrics and published at the round's end
+        self._m_expert_tokens = self._g_absent_share = self._g_expert_load = None
+        if self.mode == "finetune" and cfg.model.text_trunk == "sparse_expert":
+            self._m_expert_tokens = self.registry.counter(
+                "moe.expert_tokens_total",
+                "(token, choice) pairs routed to each held expert",
+                labels=("layer", "expert"),
+            )
+            self._g_absent_share = self.registry.gauge(
+                "moe.absent_share",
+                "share of the last round's (token, choice) pairs that fell "
+                "on experts not held here",
+            )
+            self._g_expert_load = self.registry.gauge(
+                "moe.expert_load_max_over_mean",
+                "last round's mean over steps, clients and layers of the "
+                "fullest held expert's tokens over the held experts' mean",
+            )
         # per-step fusion gauge: how many fused Pallas hot-path kernels the
         # compiled step launches (model.fuse_hot_path; 2 = gather+encode
         # AND attention+pool+score, 1 = scoring kernel only — cnn text
@@ -2622,6 +2645,7 @@ class Trainer:
         # sentry aux vectors, same deal: appended as device arrays, one
         # host fetch at the round-end health check
         health_rows: list[dict] = []
+        routing_rows: list[dict] = []  # sparse-expert trunk counters, same deal
         scan_s = cfg.train.scan_steps if self.train_scan is not None else 1
 
         tracer = self.tracer
@@ -2634,6 +2658,10 @@ class Trainer:
             row = {k: v for k, v in metrics.items() if k.startswith("health.")}
             if row:
                 health_rows.append(row)
+            if self._m_expert_tokens is not None:
+                routing_rows.append(
+                    {k: v for k, v in metrics.items() if k.startswith("moe.")}
+                )
 
         def dispatch(group: list, table) -> None:
             self._count_steps(len(group))
@@ -2800,9 +2828,31 @@ class Trainer:
             if total > 0:
                 self._m_overflow.inc(total)
                 raise RuntimeError(self._overflow_message(total))
+        if routing_rows:
+            self._publish_routing(routing_rows)
         result = RoundResult(round_idx, train_loss)
         self._eval_if_due(result)
         return result
+
+    def _publish_routing(self, rows: list[dict]) -> None:
+        """The round's routing counters to the registry: one host fetch of
+        what the steps returned. ``moe.expert_tokens`` is (..., layers,
+        held) per step entry, leading axes steps and clients."""
+        tokens = np.concatenate([
+            np.asarray(r["moe.expert_tokens"]).reshape(
+                (-1,) + r["moe.expert_tokens"].shape[-2:]
+            )
+            for r in rows
+        ])  # (steps x clients, layers, held)
+        first = self.cfg.model.trunk_first_expert
+        for (layer, expert), n in np.ndenumerate(tokens.sum(axis=0)):
+            self._m_expert_tokens.inc(float(n), layer=layer, expert=first + expert)
+        self._g_absent_share.set(float(np.mean(
+            [np.mean(np.asarray(r["moe.absent_share"])) for r in rows]
+        )))
+        self._g_expert_load.set(float(np.mean(
+            tokens.max(axis=-1) / np.maximum(tokens.mean(axis=-1), 1e-9)
+        )))
 
     # ------------------------------------------- aggregation topologies
     def _agg_param_stacks(self) -> tuple[Any, Any]:

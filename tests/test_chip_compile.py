@@ -297,3 +297,40 @@ def test_joint_step_compiles_for_four_chips(topo):
     assert _table_copies(compiled, args[2]) == []
     sync_text = sync.lower(args[0], weights).compile().as_text()
     assert "all-reduce" in sync_text and "replica_groups={{0,1,2,3}}" in sync_text
+
+
+# ------------------------------------------ the sparse-expert trunk's layer
+@pytest.mark.parametrize("clients", [1, 2], ids=["one-client", "cohort-of-two"])
+def test_held_experts_compile_at_published_widths(topo, one_chip, clients):
+    """One chunk of the expert layer of ``st21b-ep4.b16`` (11,000 tokens, 6
+    choices, 16 of 64 experts of 2560 x 768 held), forward and backward: the
+    grouped products reach XLA:TPU's grouped-matmul kernel un-batched. It
+    refuses them a batch dimension, so under a cohort's ``vmap`` they run
+    client by client (``sparse_trunk.grouped_matmul``)."""
+    from fedrec_tpu.models import sparse_trunk
+
+    tokens, k, held, d, f = 11_000, 6, 16, 2560, 768
+    lead = () if clients == 1 else (clients,)
+    args = (
+        _spec(lead + (tokens, d), "bfloat16", one_chip),
+        _spec(lead + (tokens, k), "int32", one_chip),
+        _spec(lead + (tokens, k), "float32", one_chip),
+        _spec(lead + (held, d, f), "bfloat16", one_chip),
+        _spec(lead + (held, d, f), "bfloat16", one_chip),
+        _spec(lead + (held, f, d), "bfloat16", one_chip),
+    )
+
+    def loss(u, idx, p, w_gate, w_up, w_down):
+        y, sizes = sparse_trunk.held_experts_output(u, idx, p, w_gate, w_up, w_down, 0)
+        return jnp.sum(y.astype(jnp.float32)), sizes
+
+    def both(*a):
+        grad = jax.grad(loss, argnums=(0, 3, 4, 5), has_aux=True)
+        return (jax.vmap(grad) if clients > 1 else grad)(*a)
+
+    text = _compile(both, *args).as_text()
+    # three products forward, and for each its two transposes
+    assert text.count('custom_call_target="tpu_custom_call"') >= 9
+    assert "lhs_batch_dims={0}" not in "".join(
+        line for line in text.splitlines() if "ragged-dot(" in line
+    )
