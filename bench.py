@@ -107,7 +107,7 @@ def _promote_best_sweep_row(out: dict, sweep: dict, flops_of, peak, ratios) -> N
     exists (module docstring: B=64 is dispatch-bound and noisy; large-B rows
     are compute-bound and stable — so even a B=64 reading that beats every
     sweep row is not a better number; ADVICE r3). Idempotent and called
-    after EVERY sweep point — the B=64 capped row is captured into b64_*
+    after EVERY sweep point — the B=64 host-deduped row is captured into b64_*
     exactly once, on first promotion. ``flops_of(b)`` returns analytic step FLOPs at batch
     ``b``; ``ratios(rate, our_sweep=...)`` returns the baseline-ratio
     fields. Module-level so the policy is unit-testable.
@@ -119,7 +119,7 @@ def _promote_best_sweep_row(out: dict, sweep: dict, flops_of, peak, ratios) -> N
     if out.get("headline_source") == "flagship_b64":
         out["b64_samples_per_sec"] = out["value"]
         out["b64_sec_per_step"] = out["sec_per_step"]
-        out["b64_unique_news_cap"] = out["unique_news_cap"]
+        out["b64_encode_rows"] = out["encode_rows"]
         out["b64_flops_per_step"] = out.get("flops_per_step")
         if "mfu_estimate" in out:
             out["b64_mfu_estimate"] = out["mfu_estimate"]
@@ -128,7 +128,7 @@ def _promote_best_sweep_row(out: dict, sweep: dict, flops_of, peak, ratios) -> N
     out["value"] = best_rate
     out["batch_size"] = bb
     out["sec_per_step"] = round(dt_best, 6)
-    out["unique_news_cap"] = 0  # sweep rows run the uncapped step
+    out["encode_rows"] = 0  # sweep rows dedup on the device, at the slot count
     out["headline_source"] = "b_sweep_uncapped"
     # clamp candidates: the sweep rows plus the B=64 flagship (a measured,
     # dispatch-bound — hence conservative — point inside the baseline's
@@ -237,20 +237,20 @@ def main() -> None:
     token_states, _ = commit_token_table(token_states, mesh)
     step = build_fed_train_step(model, cfg, get_strategy("grad_avg"), mesh, mode="joint")
 
-    def make_batch(seed: int, bsz: int, n_clients: int = 1):
+    def host_batch(seed: int, bsz: int, n_clients: int = 1) -> dict:
         r = np.random.default_rng(seed)
-        return shard_batch(
-            mesh,
-            {
-                "candidates": r.integers(
-                    0, num_news, (n_clients, bsz, C)
-                ).astype(np.int32),
-                "history": r.integers(
-                    0, num_news, (n_clients, bsz, H)
-                ).astype(np.int32),
-                "labels": np.zeros((n_clients, bsz), np.int32),
-            },
-        )
+        return {
+            "candidates": r.integers(
+                0, num_news, (n_clients, bsz, C)
+            ).astype(np.int32),
+            "history": r.integers(
+                0, num_news, (n_clients, bsz, H)
+            ).astype(np.int32),
+            "labels": np.zeros((n_clients, bsz), np.int32),
+        }
+
+    def make_batch(seed: int, bsz: int, n_clients: int = 1):
+        return shard_batch(mesh, host_batch(seed, bsz, n_clients))
 
     def measure(bsz: int, iters: int, warmup: int = 3, the_step=None,
                 feats=None, n_clients: int = 1, the_cfg=None,
@@ -287,57 +287,37 @@ def main() -> None:
             label=f"step (B={bsz})", trace=_tr,
         )
 
-    # Flagship step: unique-news cap ON (VERDICT r2 item 3). The B=64 batch gathers at most
-    # B*(C+H)=3,520 slots but holds ~2.4k distinct ids; the cap trims the
-    # text tower to 2,560 slots. The math stays exact — checked before any
-    # timing, and a tripped cap falls back to the uncapped step (then
-    # flagship_cap=0 records that the headline ran uncapped).
-    flagship_cap = 2560
-    step_flag, cfg_flag = step, cfg
-    import copy
+    # Flagship step: the dedup runs on the host, as the Trainer's round loop
+    # runs it (train/step.py: host_news_dedup). The B=64 batch gathers
+    # B*(C+H)=3,520 slots but holds ~2.4k distinct ids; the step encodes the
+    # size the code derives from the timed batches' own counts, and a batch
+    # that exceeded it would be served at the full size, so the math is exact.
+    from fedrec_tpu.train.step import (
+        encode_rows_for,
+        host_news_dedup,
+        most_distinct_news,
+    )
 
-    # exactness check on EVERY batch measure() will time (seeds 0-7),
-    # host-side: same deterministic draws as make_batch, so a distinct
-    # count over the cap on any of them falls back to the uncapped step
-    def batch_distinct(seed: int, bsz: int) -> int:
-        r = np.random.default_rng(seed)
-        cand = r.integers(0, num_news, (1, bsz, C))
-        his = r.integers(0, num_news, (1, bsz, H))
-        return len(np.unique(np.concatenate([cand.ravel(), his.ravel()])))
+    flagship_rows = encode_rows_for(
+        max(
+            most_distinct_news(hb["candidates"], hb["history"])
+            for hb in (host_batch(s, B) for s in range(8))
+        ),
+        min(B * (C + H), num_news),
+    )
 
-    if flagship_cap and max(batch_distinct(s, B) for s in range(8)) <= flagship_cap:
-        cfg_cap = copy.deepcopy(cfg)
-        cfg_cap.data.unique_news_cap = flagship_cap
-        step_cap = build_fed_train_step(
-            model, cfg_cap, get_strategy("grad_avg"), mesh, mode="joint"
+    def make_deduped_batch(seed: int, bsz: int, n_clients: int = 1):
+        hb = host_batch(seed, bsz, n_clients)
+        entries, _ = host_news_dedup(
+            hb["candidates"], hb["history"], flagship_rows, num_news
         )
-        # belt-and-braces on-device check: the step's OWN overflow
-        # metric on one real batch, so the headline can never be timed
-        # on a silently-corrupted gather even if the host replica of
-        # make_batch's draws ever drifts from the step's dedup
-        st0 = replicate_state(
-            init_client_state(model, cfg, jax.random.PRNGKey(0), num_news, L),
-            1, jax.random.PRNGKey(1),
-        )
-        _, m_chk = step_cap(st0, make_batch(0, B), token_states)
-        if int(np.max(np.asarray(m_chk["unique_overflow"]))) > 0:
-            raise RuntimeError(
-                "host-side distinct count and the step's unique_overflow "
-                "metric disagree — make_batch/dedup drift; fix bench.py"
-            )
-        step_flag, cfg_flag = step_cap, cfg_cap
-    elif flagship_cap:
-        sys.stderr.write(
-            f"[bench] unique_news_cap={flagship_cap} would overflow a "
-            "bench batch; flagship falls back to the uncapped step\n"
-        )
-        flagship_cap = 0
+        return shard_batch(mesh, {**hb, **entries})
 
     dt = measure(
         B,
         iters=2 if smoke else 50,
         warmup=2 if smoke else 3,
-        the_step=step_flag,
+        batch_maker=make_deduped_batch,
     )
     samples_per_sec = B / dt
 
@@ -352,7 +332,7 @@ def main() -> None:
         "dtype": cfg.model.dtype,
         "sec_per_step": round(dt, 6),
         "batch_size": B,
-        "unique_news_cap": flagship_cap,
+        "encode_rows": flagship_rows,
         "headline_source": "flagship_b64",
         "baseline": "torch-cpu reference-equivalent, see benchmarks/baseline_host.json",
     }
@@ -370,20 +350,19 @@ def main() -> None:
     out.update(baseline_ratios(samples_per_sec))
 
     if on_tpu:
-        flops = _flops_per_train_step(cfg_flag, B, num_news)
+        flops = _flops_per_train_step(cfg, B, num_news, flagship_rows)
         peak = peak_flops(device.device_kind, cfg.model.dtype)
         out["mfu_estimate"] = round(flops / dt / peak, 4)
         out["flops_per_step"] = flops
 
-        # uncapped step at B=64: continuity with the round-1/2 headline
-        # (whose flagship had no unique-news cap).
-        if flagship_cap:
-            dt_unc = measure(B, iters=50, the_step=step)
-            out["uncapped_samples_per_sec"] = round(B / dt_unc, 2)
+        # device-side dedup at the slot count, B=64: continuity with the
+        # round-1/2 headline (whose flagship encoded every slot)
+        dt_unc = measure(B, iters=50)
+        out["uncapped_samples_per_sec"] = round(B / dt_unc, 2)
 
         # batch-size sweep (VERDICT r2 item 3): where is the throughput
-        # knee? Uncapped step (a 2,560 cap would overflow at B>=128, where
-        # the dedup bound is num_news anyway). B=512 is the 8-client
+        # knee? Device-side dedup (at B>=128 the dedup bound is num_news
+        # anyway). B=512 is the 8-client
         # grad-avg equivalent: with per-step gradient averaging all clients
         # stay in lockstep, so 8 clients x B=64 on one chip is
         # mathematically one B=512 step.

@@ -383,11 +383,30 @@ def main() -> int:
                 "full_fwd_bwd": (full_fwd_bwd, token_states),
             }
             if B == 64:
-                def full_fwd_bwd_capped(ts):
-                    # the FLAGSHIP configuration: unique-news cap 2560 (bench.py)
+                # the FLAGSHIP configuration: the dedup done on the host, at
+                # the size the round loop would choose (bench.py)
+                from fedrec_tpu.train.step import (
+                    NEWS_INVERSE, NEWS_ROWS, encode_rows_for, host_news_dedup,
+                    most_distinct_news,
+                )
+
+                cand_np = np.asarray(candidates)[None]
+                his_np = np.asarray(history)[None]
+                entries, _ = host_news_dedup(
+                    cand_np, his_np,
+                    encode_rows_for(most_distinct_news(cand_np, his_np), U),
+                    num_news,
+                )
+                host_dedup = (
+                    jnp.asarray(entries[NEWS_ROWS][0]),
+                    jnp.asarray(entries[NEWS_INVERSE][0]),
+                )
+
+                def full_fwd_bwd_host_dedup(ts):
                     def loss(ps):
                         cv, hv = _batch_news_vecs(
-                            model, ps["text"], ts, candidates, history, cap=2560
+                            model, ps["text"], ts, candidates, history,
+                            host_dedup=host_dedup,
                         )
                         scores = model.apply(
                             {"params": {"user_encoder": ps["user"]}}, cv, hv
@@ -396,7 +415,9 @@ def main() -> int:
                     g = jax.grad(loss)({"text": text_p, "user": user_p})
                     return sum(l.sum() for l in jax.tree_util.tree_leaves(g))
 
-                comps["full_fwd_bwd_capped"] = (full_fwd_bwd_capped, token_states)
+                comps["full_fwd_bwd_host_dedup"] = (
+                    full_fwd_bwd_host_dedup, token_states
+                )
 
             res = {}
             entry = {"components_ms": res}

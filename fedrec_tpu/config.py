@@ -43,21 +43,6 @@ class DataConfig:
     # host trains the full corpus even multi-process.
     num_shards: int = 0
     shard_index: int = 0
-    # static bound on unique news encoded per joint-mode step. 0 = the exact
-    # worst case B*(C+H). Real batches hold far fewer distinct ids (history
-    # padding collapses to one <unk> row; popular news repeat), so a cap cuts
-    # text-tower FLOPs proportionally. Exact while the batch's distinct count
-    # stays <= cap; the step emits a `unique_overflow` metric (count of
-    # clients whose batch overflowed — results invalid if ever nonzero).
-    unique_news_cap: int = 0
-    # per-B bucketed cap policy: "64:2560,256:4096" means per-client batches
-    # up to B=64 cap at 2,560 unique slots, up to B=256 at 4,096; batches
-    # larger than every bucket run uncapped (exact). A batch's dedup bound
-    # scales with B, so one global constant either over-caps small batches
-    # or under-caps large ones (a 2,560 cap overflows every B>=128 batch).
-    # Empty = use the global unique_news_cap. Resolved at trace time per
-    # compiled batch shape (train.step.resolve_unique_cap).
-    unique_news_cap_buckets: str = ""
     # tile the unique token-state gather + text encode in lax.map chunks of
     # this many rows, with the chunk body rematerialized in backward: the
     # (unique, L, bert_hidden) gather result is never materialized in HBM

@@ -93,9 +93,14 @@ def peak_flops(device_kind: str, dtype: str) -> float | None:
 
 
 # ------------------------------------------------------------- flops model
-def flops_per_train_step(cfg, batch_size: int, num_news: int) -> float:
+def flops_per_train_step(
+    cfg, batch_size: int, num_news: int, encode_rows: int = 0
+) -> float:
     """Analytic matmul FLOPs for one joint-mode train step (fwd + bwd),
-    PER CLIENT at per-client batch ``batch_size``.
+    PER CLIENT at per-client batch ``batch_size``. ``encode_rows``: the size
+    R the round loop compiled the step's text tower at (the
+    ``train.encode_rows`` gauge); 0 where the step dedups on the device and
+    encodes every slot.
 
     Counts the dominating dense ops; backward ~= 2x forward for matmuls.
     Moved here from ``bench.py`` (which imports it back) so the bench
@@ -111,15 +116,11 @@ def flops_per_train_step(cfg, batch_size: int, num_news: int) -> float:
     heads, dk = cfg.model.num_heads, cfg.model.head_dim
     Q = cfg.model.query_dim
 
-    # unique-news slots encoded per step — resolved through the SAME policy
-    # the compiled step uses (global cap or per-B buckets), so the FLOPs
-    # model can never over-count text-tower work the step skipped
-    from fedrec_tpu.train.step import resolve_unique_cap
-
+    # news rows the compiled step encodes: the model never counts text-tower
+    # work the step skipped, so a smaller R lowers the count, not the MFU
     size = min(B * (C + H), num_news)
-    cap = resolve_unique_cap(cfg, B)
-    if cap:
-        size = min(size, cap)
+    if encode_rows:
+        size = min(size, encode_rows)
     att_hidden = Dh // 2               # text-head additive attention hidden
     text = size * (2 * L * Dh * att_hidden + 2 * L * att_hidden + 2 * Dh * D)
     mha = B * (3 * 2 * H * D * D + 2 * 2 * heads * H * H * dk + 2 * H * D)
@@ -440,6 +441,7 @@ class PerfMonitor:
         self.peak_fl = peak_flops(device_kind, cfg.model.dtype)
         peaks = chip_peaks(device_kind)
         self.peak_bw = peaks[2] if peaks else None
+        self._num_news = num_news
         self.flops_per_step = flops_per_train_step(
             cfg, cfg.data.batch_size, num_news
         )
@@ -538,6 +540,14 @@ class PerfMonitor:
         self.last_round: dict | None = None
 
     # ------------------------------------------------------------- rounds
+    def set_encode_rows(self, rows: int) -> None:
+        """The round loop chose (or re-derived) the step's encode size R:
+        price the text tower at it from here on."""
+        self.flops_per_step = flops_per_train_step(
+            self.cfg, self.cfg.data.batch_size, self._num_news, rows
+        )
+        self._g_step_flops.set(self.flops_per_step)
+
     def begin_round(self) -> None:
         """Mark the tracer/step-counter positions a round's digest diffs
         against; call at round (or chunk) entry."""

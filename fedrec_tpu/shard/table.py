@@ -23,14 +23,15 @@ four-phase exchange, every shape static so nothing retraces:
 The result is bit-identical to ``full_table[ids]`` for every id in
 ``[0, num_rows)`` (pinned in ``tests/test_shard_table.py``), so the
 train step's downstream math — dedup inverse scatter, text-head encode,
-``data.gather_chunk`` tiling, the unique-cap policy — is untouched.
+``data.gather_chunk`` tiling — is untouched.
 Capacity scales linearly with devices: ``rows_per_device = ceil(N / S)``.
 
 Why fixed shapes: a "send only what each shard needs" exchange would put
 a data-dependent dimension inside the compiled step (retrace per batch,
 illegal under ``lax.scan`` rounds-in-jit). The ``(S, U)`` worst-case
-bucket wastes wire on padding slots, which is exactly what
-``data.unique_news_cap`` bounds — the cap lever prices the exchange.
+bucket wastes wire on padding slots, which is exactly what the round
+loop's host-side dedup bounds: ``U`` is the encode size it chose from the
+traffic (``train/step.py: host_news_dedup``), not the slot count.
 docs/DESIGN.md §5i.
 """
 

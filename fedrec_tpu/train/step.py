@@ -273,58 +273,6 @@ def _cohort_call(local_fn: Callable, k: int, n_args_mapped: int, *args):
     return jax.vmap(local_fn, in_axes=in_axes, axis_name=LOCAL_AXIS)(*args)
 
 
-def parse_cap_buckets(spec: str) -> list[tuple[int, int]]:
-    """Parse ``data.unique_news_cap_buckets`` ("64:2560,256:4096") into a
-    B-ascending list of (max_batch, cap) pairs. Raises on malformed entries
-    so a typo'd policy fails at build time, not silently uncapped."""
-    buckets = []
-    for item in spec.split(","):
-        item = item.strip()
-        if not item:
-            continue
-        try:
-            b_s, cap_s = item.split(":")
-            b, cap = int(b_s), int(cap_s)
-        except ValueError:
-            raise ValueError(
-                f"data.unique_news_cap_buckets entry {item!r} is not "
-                "'<max_batch>:<cap>' (e.g. '64:2560,256:4096')"
-            ) from None
-        if b <= 0 or cap <= 0:
-            raise ValueError(
-                f"data.unique_news_cap_buckets entry {item!r}: both the "
-                "batch bound and the cap must be positive"
-            )
-        buckets.append((b, cap))
-    bounds = [b for b, _ in buckets]
-    if len(set(bounds)) != len(bounds):
-        raise ValueError(
-            f"data.unique_news_cap_buckets has duplicate batch bounds "
-            f"({spec!r}); each bound may appear once"
-        )
-    return sorted(buckets)
-
-
-def resolve_unique_cap(cfg: ExperimentConfig, batch_size: int) -> int:
-    """The unique-news cap for one compiled per-client batch size.
-
-    With ``data.unique_news_cap_buckets`` set, picks the cap of the smallest
-    bucket whose batch bound covers ``batch_size``; batches larger than
-    every bucket run uncapped (0 = exact worst-case bound) — a fixed global
-    cap either over-caps small batches or silently overflows large ones
-    (the flagship 2,560 cap overflows every B>=128 batch against the 4,096
-    bench corpus). Without buckets, the global ``data.unique_news_cap``.
-    Called at trace time, so each compiled batch shape gets its own bound.
-    """
-    buckets = parse_cap_buckets(cfg.data.unique_news_cap_buckets)
-    if buckets:
-        for b, cap in buckets:
-            if batch_size <= b:
-                return cap
-        return 0
-    return cfg.data.unique_news_cap
-
-
 def _encode_gathered(
     model: NewsRecommender,
     news_params: Any,
@@ -426,13 +374,102 @@ def _encode_gathered(
     return vecs.reshape(-1, vecs.shape[-1])[:u]
 
 
+# The host half of the joint step's dedup (:func:`host_news_dedup`) rides the
+# batch dict under these two keys; a batch without them is deduped on the
+# device (:func:`_batch_news_vecs`).
+NEWS_ROWS = "news_rows"          # (K, R) int32: a client's distinct ids, 0-padded
+NEWS_INVERSE = "news_inverse"    # (K, B*(C+H)) int32: slot -> row of NEWS_ROWS
+
+# How the round loop sizes R from the distinct counts it measured
+# (:func:`encode_rows_for`). The counts of one traffic are narrow (sd under 1%
+# of the mean, the same from seed to seed: PERF.md section 6, PR 30), so 3% on
+# the largest count seen is 3 sd or more of room above a maximum that is
+# itself 2-3 sd above the mean. R is 64 past a multiple of 128, as the
+# flagship's slot count 3,520 is: whole (16, 128) bf16 tiles of rows either
+# way, but given a whole number of 128-row tiles XLA:TPU lays a cohort's
+# gathered rows out a second time with R minor for the head's weight gradient
+# (a 1.8 GB copy a step at 8 x 2,944 rows; tests/test_chip_compile.py).
+ENCODE_ROOM = 0.03
+ENCODE_ROW_QUANTUM = 128
+ENCODE_ROW_RESIDUE = 64
+
+
+def encode_rows_for(most: int, full: int) -> int:
+    """The encode size R for a run whose largest distinct count so far is
+    ``most``: room for the spread, rounded up to the next size the compiled
+    program tiles well, never above ``full`` = ``min(B*(C+H), N)`` (which
+    every count fits)."""
+    want = int(most * (1.0 + ENCODE_ROOM)) - ENCODE_ROW_RESIDUE
+    quanta = max(-(-want // ENCODE_ROW_QUANTUM), 0)
+    return min(full, quanta * ENCODE_ROW_QUANTUM + ENCODE_ROW_RESIDUE)
+
+
+def _client_news_ids(candidates: np.ndarray, history: np.ndarray) -> np.ndarray:
+    """(K, B, C) and (K, B, H) ids -> (K, B*(C+H)), in the slot order
+    :func:`_batch_news_vecs` scatters back into."""
+    k = candidates.shape[0]
+    return np.concatenate(
+        [candidates.reshape(k, -1), history.reshape(k, -1)], axis=1
+    )
+
+
+def most_distinct_news(candidates: np.ndarray, history: np.ndarray) -> int:
+    """The largest count of distinct news ids over a step's clients."""
+    return max(
+        np.unique(row).size for row in _client_news_ids(candidates, history)
+    )
+
+
+def host_news_dedup(
+    candidates: np.ndarray, history: np.ndarray, rows: int, n_news: int
+) -> tuple[dict[str, np.ndarray], int]:
+    """Dedup each client's news ids on the host, where the ids are known
+    before the step is dispatched and a compiled program's shapes are not.
+
+    ``candidates`` (K, B, C), ``history`` (K, B, H). Returns the two batch
+    entries and the largest distinct count over the clients. The distinct ids
+    are padded with id 0 to ``rows``, or to the full size
+    ``min(B*(C+H), n_news)`` when some client's count exceeds ``rows``: the
+    step is then the same function at another shape, and no id is ever
+    dropped.
+    """
+    ids = _client_news_ids(candidates, history)
+    k, slots = ids.shape
+    deduped = [np.unique(row, return_inverse=True) for row in ids]
+    most = max(u.size for u, _ in deduped)
+    size = rows if most <= rows else min(slots, n_news)
+    news_rows = np.zeros((k, size), np.int32)
+    for c, (u, _) in enumerate(deduped):
+        news_rows[c, : u.size] = u
+    inverse = np.stack([inv for _, inv in deduped]).astype(np.int32)
+    return {NEWS_ROWS: news_rows, NEWS_INVERSE: inverse}, most
+
+
+def batch_host_dedup(batch: dict) -> tuple[Any, Any] | None:
+    """The (:data:`NEWS_ROWS`, :data:`NEWS_INVERSE`) entries of a step's
+    batch, or None where the step dedups on the device: the batch has none,
+    or a caller re-cut ``candidates`` / ``history`` under them, so that they
+    no longer describe its slots (the device-side dedup is exact for any
+    batch). Reads shapes only, per client or with a leading clients axis:
+    the step asks at trace time and the round loop asks for its ``dispatch``
+    span, of the same batch, so the span says what the step encodes."""
+    # a dict's keys are static under jit
+    if NEWS_ROWS not in batch:  # fedrec-lint: disable=TS105
+        return None
+    cand, his = batch["candidates"], batch["history"]
+    slots = cand.shape[-2] * cand.shape[-1] + his.shape[-2] * his.shape[-1]
+    if batch[NEWS_INVERSE].shape[-1] != slots:
+        return None
+    return batch[NEWS_ROWS], batch[NEWS_INVERSE]
+
+
 def _batch_news_vecs(
     model: NewsRecommender,
     news_params: Any,
     token_states: jnp.ndarray,
     candidates: jnp.ndarray,
     history: jnp.ndarray,
-    cap: int = 0,
+    host_dedup: tuple[jnp.ndarray, jnp.ndarray] | None = None,
     chunk: int = 0,
     fused: bool = False,
     gather_fn: Callable | None = None,
@@ -444,27 +481,36 @@ def _batch_news_vecs(
     ``token_states``: (N_news, L, bert_hidden) HBM-resident feature table.
     Returns cand_vecs (B, C, D) and his_vecs (B, H, D).
 
-    ``cap`` (``data.unique_news_cap`` / the bucketed policy resolved by
-    :func:`resolve_unique_cap`): static bound on the unique slots actually
-    encoded — the worst case B*(C+H) wastes text-tower FLOPs on
-    duplicate/padding rows. Exact while distinct ids <= cap; callers must
-    surface :func:`unique_overflow` when setting it. ``chunk``: see
-    :func:`_encode_gathered`, as for ``batch_of_one``. ``gather_fn``/``n_news``: the sharded-
-    catalog form (``shard.table``) — ``token_states`` is then this
-    device's local row block, so the GLOBAL row count must come in
-    explicitly (the local block's dim 0 would wrongly cap the dedup).
+    One algorithm, encode the distinct rows and scatter by the inverse, whose
+    size comes from the input: with ``host_dedup``, a batch's
+    (:data:`NEWS_ROWS`, :data:`NEWS_INVERSE`) entries
+    (:func:`host_news_dedup`), the R rows given are encoded, a size that
+    follows the traffic, with no sort in the program; without, the ids are
+    deduped here by ``jnp.unique`` at the worst case ``min(B*(C+H), N)``,
+    whose repeats come back as padding rows of id 0 and are encoded too.
+    ``chunk`` and ``batch_of_one``: see :func:`_encode_gathered`.
+    ``gather_fn``/``n_news``: the sharded-catalog form (``shard.table``) —
+    ``token_states`` is then this device's local row block, so the GLOBAL
+    row count must come in explicitly (the local block's dim 0 would wrongly
+    bound the dedup).
     """
     b, c = candidates.shape
     h = history.shape[1]
-    ids = jnp.concatenate([candidates.reshape(-1), history.reshape(-1)])
-    if n_news is None:
-        n_news = token_states.shape[0]
-    size = min(ids.shape[0], n_news)
-    if cap:
-        size = min(size, cap)
-    uniq, inv = jnp.unique(
-        ids, size=size, fill_value=0, return_inverse=True
-    )
+    if host_dedup is not None:
+        uniq, inv = host_dedup
+        if inv.shape != (b * (c + h),):
+            raise ValueError(
+                f"host dedup inverse of shape {inv.shape} does not describe "
+                f"the batch's {b * (c + h)} news slots"
+            )
+    else:
+        ids = jnp.concatenate([candidates.reshape(-1), history.reshape(-1)])
+        if n_news is None:
+            n_news = token_states.shape[0]
+        uniq, inv = jnp.unique(
+            ids, size=min(ids.shape[0], n_news), fill_value=0,
+            return_inverse=True,
+        )
     vecs = _encode_gathered(
         model, news_params, token_states, uniq, chunk, fused=fused,
         gather_fn=gather_fn, batch_of_one=batch_of_one,
@@ -475,45 +521,21 @@ def _batch_news_vecs(
     return cand_vecs, his_vecs
 
 
-def unique_overflow(
-    candidates: jnp.ndarray,
-    history: jnp.ndarray,
-    cap: int,
-    n_news: int,
-) -> jnp.ndarray:
-    """1 when this batch's distinct news ids exceed the static ``cap``.
-
-    ``jnp.unique(size=cap)`` silently drops ids past the cap, corrupting the
-    gather — so a capped step must emit this flag; any nonzero value in
-    training metrics means the cap is too small and results are invalid.
-    """
-    ids = jnp.concatenate([candidates.reshape(-1), history.reshape(-1)])
-    sorted_ids = jnp.sort(ids)
-    distinct = 1 + jnp.sum((jnp.diff(sorted_ids) != 0).astype(jnp.int32))
-    bound = min(cap, ids.shape[0], n_news)
-    return (distinct > bound).astype(jnp.int32)
-
-
 def _encode_unique_tokens(
     text_encoder: Any,
     news_params: Any,
     tokens_table: jnp.ndarray,
     ids: jnp.ndarray,
     dropout_rng: jax.Array | None,
-    cap: int = 0,
 ) -> tuple[jnp.ndarray, dict]:
     """Encode a flat id vector's unique news through the full TextEncoder.
 
     Gathers the unique token rows from the (N, 2, L) table, runs trunk +
-    head once per distinct news, and scatters back to (len(ids), D).
-    ``cap`` bounds the unique slots like in :func:`_batch_news_vecs` — it
-    matters MOST here, where every slot pays a full trunk forward+backward;
-    callers must surface :func:`unique_overflow`. Also returns the trunk's
-    routing counters (``models.sparse_trunk``; empty for a dense trunk).
+    head once per distinct news, and scatters back to (len(ids), D). Also
+    returns the trunk's routing counters (``models.sparse_trunk``; empty
+    for a dense trunk).
     """
     size = min(ids.shape[0], tokens_table.shape[0])
-    if cap:
-        size = min(size, cap)
     uniq, inv = jnp.unique(ids, size=size, fill_value=0, return_inverse=True)
     toks = tokens_table[uniq]  # (size, 2, L)
     train = dropout_rng is not None
@@ -535,7 +557,6 @@ def _batch_news_vecs_tokens(
     candidates: jnp.ndarray,
     history: jnp.ndarray,
     dropout_rng: jax.Array | None,
-    cap: int = 0,
 ) -> tuple[jnp.ndarray, jnp.ndarray, dict]:
     """Finetune-mode analogue of ``_batch_news_vecs``: one joint dedup over
     candidate + history ids, full trainable TextEncoder on the unique rows;
@@ -544,7 +565,7 @@ def _batch_news_vecs_tokens(
     h = history.shape[1]
     ids = jnp.concatenate([candidates.reshape(-1), history.reshape(-1)])
     flat, routing = _encode_unique_tokens(
-        text_encoder, news_params, tokens_table, ids, dropout_rng, cap=cap
+        text_encoder, news_params, tokens_table, ids, dropout_rng
     )
     cand_vecs = flat[: b * c].reshape(b, c, -1)
     his_vecs = flat[b * c :].reshape(b, h, -1)
@@ -891,9 +912,6 @@ def _build_local_step(
         )
 
     def local_step(state: ClientState, batch: dict, table: jnp.ndarray):
-        # trace-time cap resolution: each compiled per-client batch shape
-        # gets the bound its own B implies (bucketed policy or the global)
-        cap = resolve_unique_cap(cfg, batch["labels"].shape[0])
         dp_stats = None
         routing: dict = {}
         sentry_grads: tuple = ()
@@ -986,13 +1004,12 @@ def _build_local_step(
                         cand_vecs, his_vecs, routing = _batch_news_vecs_tokens(
                             text_encoder, news_params, table,
                             batch["candidates"], batch["history"], enc_rng,
-                            cap=cap,
                         )
                     else:
                         cand_vecs, his_vecs = _batch_news_vecs(
                             model, news_params, table,
                             batch["candidates"], batch["history"],
-                            cap=cap,
+                            host_dedup=batch_host_dedup(batch),
                             chunk=cfg.data.gather_chunk,
                             fused=fuse_gather,
                             gather_fn=table_gather,
@@ -1166,32 +1183,6 @@ def _build_local_step(
             if dp_stats is not None:
                 metrics["health.clip_rate"] = dp_stats["clip_rate"]
                 metrics["health.clip_max_norm"] = dp_stats["max_norm"]
-        capped = (
-            cap
-            and not use_dpsgd
-            and (mode == "joint" or (mode == "finetune" and n_seq == 1))
-        )
-        if capped:
-            # ids are data, not params — computed outside the grad closure;
-            # any nonzero total means the cap corrupted this step. (Under
-            # DP-SGD the cap is inert — each example encodes its own ids —
-            # and the seq-parallel finetune path encodes rows separately,
-            # bypassing the capped joint dedup — so no flag there.)
-            flag = unique_overflow(
-                batch["candidates"], batch["history"],
-                cap,
-                # sharded table: the LOCAL block's dim 0 is rows/shard, not
-                # the catalog — the dedup bound must use the global count
-                sharded_table.num_rows if sharded_table is not None
-                else table.shape[0],
-            )
-            if n_seq > 1:
-                # each seq shard dedups its own history slice, so overflow
-                # is per-shard; without this sum the P(clients) out-spec
-                # (check_vma=False) would report only seq-shard 0's flag and
-                # silently swallow corruption on the others
-                flag = lax.psum(flag, seq_ax)
-            metrics["unique_overflow"] = lax.psum(flag, axis_name=sync_axes)
         return new_state, metrics
 
     if n_seq > 1:

@@ -43,6 +43,7 @@ from fedrec_tpu.parallel.mesh import (
 from fedrec_tpu.train.checkpoint import SnapshotManager
 from fedrec_tpu.train.state import init_client_state, replicate_state
 from fedrec_tpu.train.step import (
+    batch_host_dedup,
     build_eval_step,
     build_fed_round_scan,
     build_fed_train_step,
@@ -54,6 +55,9 @@ from fedrec_tpu.train.step import (
     compressed_sync_active,
     build_corpus_encode,
     commit_token_table,
+    encode_rows_for,
+    host_news_dedup,
+    most_distinct_news,
     shard_round_batches,
     shard_scan_batches,
     stack_batches,
@@ -525,6 +529,20 @@ class Trainer:
             sharded_table=self.table_spec,
             state_shardings=self._state_shardings,
         )
+        # The joint step's dedup runs on the host wherever the round loop
+        # feeds one batch a dispatch (train/step.py: host_news_dedup): only
+        # the host holds a step's ids before it dispatches. The encode size
+        # R is chosen from the first round's own batches before the first
+        # dispatch (_choose_encode_rows): None until then, and for good on
+        # the paths whose step dedups on the device at the slot count.
+        self._host_dedup = (
+            self.mode == "joint"
+            and cfg.fed.seq_shards <= 1
+            and cfg.train.scan_steps <= 1
+            and not (cfg.privacy.enabled and cfg.privacy.mechanism == "dpsgd")
+        )
+        self._encode_rows: int | None = None
+        self._encode_full = 0  # min(B*(C+H), N), which every count fits
         # epoch-in-jit chains (train.scan_steps > 1): one dispatch per
         # scan_steps batches; the tail of an epoch uses train_step
         self.train_scan = (
@@ -880,10 +898,17 @@ class Trainer:
             buckets=(0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 25.0, 50.0,
                      100.0, 250.0, 500.0, 1000.0),
         )
-        self._m_overflow = self.registry.counter(
-            "train.cap_overflow_total",
-            "unique-news cap overflow count (client-summed over steps; "
-            "nonzero aborts the round)",
+        self._g_encode_rows = self.registry.gauge(
+            "train.encode_rows",
+            "news rows a client-step gathers and encodes: the size R the "
+            "round loop chose for its host-side dedup (0 = the step dedups "
+            "on the device, at the slot count)",
+        )
+        self._g_encode_rows.set(0.0)
+        self._m_full_size_steps = self.registry.counter(
+            "train.encode_full_size_steps_total",
+            "steps whose distinct news exceeded train.encode_rows and were "
+            "served, exactly, at the full size min(B*(C+H), N)",
         )
         # a sparse-expert trunk's routing counters (models.sparse_trunk),
         # returned with the step's metrics and published at the round's end
@@ -963,23 +988,13 @@ class Trainer:
         )
         self._a2a_bytes_per_step = 0
         if self.table_spec is not None:
-            from fedrec_tpu.shard.table import a2a_bytes_per_gather
-            from fedrec_tpu.train.step import resolve_unique_cap
-
             spec = self.table_spec
-            b = cfg.data.batch_size
-            worst = b * (1 + cfg.data.npratio + cfg.data.max_his_len)
-            uniq = min(worst, spec.num_rows)
-            cap = resolve_unique_cap(cfg, b)
-            if cap:
-                uniq = min(uniq, cap)
-            self._a2a_bytes_per_step = a2a_bytes_per_gather(
-                uniq, tuple(self.token_states.shape[1:]),
-                self.token_states.dtype, spec,
+            worst = cfg.data.batch_size * (
+                1 + cfg.data.npratio + cfg.data.max_his_len
             )
+            self._price_table_exchange(min(worst, spec.num_rows))
             self._g_table_rows.set(float(spec.rows_per_shard))
             self._g_table_occ.set(spec.num_rows / spec.padded_rows)
-            self._g_remote_rows.set(float(spec.num_shards * uniq))
         elif self.token_states is not None:
             self._g_table_rows.set(float(self.token_states.shape[0]))
             self._g_table_occ.set(1.0)
@@ -1786,25 +1801,98 @@ class Trainer:
             self.cfg.fed.num_clients, epoch_idx
         )
 
-    def _epoch_batch_iter(self, epoch_idx: int, extra: dict | None = None):
+    def _epoch_batch_iter(
+        self,
+        epoch_idx: int,
+        extra: dict | None = None,
+        distinct: list | None = None,
+    ):
         """Epoch batches as step-ready dicts, built ahead on a bounded
         producer thread when ``data.prefetch_batches`` > 0 — batch t+1
-        assembles (shuffle, negative sampling, packing) while step t runs
-        on device, closing the dispatch gap the step_profile host-pipeline
-        rows measure. Off (0) = plain inline iteration, identical batches
-        either way (tests/test_prefetch.py). ``extra`` (the round's chaos
-        fault vectors) is merged into every batch dict."""
+        assembles (shuffle, negative sampling, packing, dedup) while step t
+        runs on device, closing the dispatch gap the step_profile
+        host-pipeline rows measure. Off (0) = plain inline iteration,
+        identical batches either way (tests/test_prefetch.py). ``extra``
+        (the round's chaos fault vectors) is merged into every batch dict.
+        Once the run's encode size is chosen, every batch also carries its
+        clients' distinct news ids and their inverse
+        (``train.step.host_news_dedup``), and ``distinct`` receives each
+        step's largest distinct count (read by the caller after the epoch)."""
         extra = extra or {}
-        return maybe_prefetch(
-            self._epoch_batches_source(epoch_idx),
-            self.cfg.data.prefetch_batches,
-            transform=lambda b: {
+        rows = self._encode_rows
+        n_news = self._num_news() if rows is not None else None
+
+        def transform(b):
+            batch = {
                 "candidates": b.candidates,
                 "history": b.history,
                 "labels": b.labels,
                 **extra,
-            },
+            }
+            if rows is not None:
+                entries, most = host_news_dedup(
+                    b.candidates, b.history, rows, n_news
+                )
+                batch.update(entries)
+                if distinct is not None:
+                    distinct.append(most)
+            return batch
+
+        return maybe_prefetch(
+            self._epoch_batches_source(epoch_idx),
+            self.cfg.data.prefetch_batches,
+            transform=transform,
         )
+
+    # how many of a round's first steps the encode size is chosen from. A
+    # sample, not a round: the largest of 32 counts lies about 2 sd above
+    # their mean and the largest of a million about 5, so with counts whose
+    # sd is under 1% of the mean step.ENCODE_ROOM covers a run of any
+    # length, and a step past it is served at the full size all the same.
+    # The batcher is deterministic by epoch, but building a step's batch to
+    # count it costs the host 1.5-4 ms (PERF.md section 5), so a long epoch
+    # is not counted whole.
+    ENCODE_ROWS_STEPS = 32
+
+    def _num_news(self) -> int:
+        """Catalog rows the joint step's dedup is bounded by (the GLOBAL
+        count under ``shard.table``, whose local block is a slice)."""
+        if self.table_spec is not None:
+            return self.table_spec.num_rows
+        return int(self.token_states.shape[0])
+
+    def _choose_encode_rows(self, epoch_idx: int) -> None:
+        """Choose the run's encode size R before its first dispatch: the
+        largest distinct count over the clients of the epoch's first steps,
+        with room (``train.step.encode_rows_for``). One size serves every
+        client of a cohort and every device of a clients mesh."""
+        most = slots = 0
+        for _, b in zip(
+            range(self.ENCODE_ROWS_STEPS), self._epoch_batches_source(epoch_idx)
+        ):
+            most = max(most, most_distinct_news(b.candidates, b.history))
+            slots = b.candidates[0].size + b.history[0].size
+        if slots:
+            self._encode_full = min(slots, self._num_news())
+            self._set_encode_rows(most)
+
+    def _set_encode_rows(self, most: int) -> None:
+        self._encode_rows = encode_rows_for(most, self._encode_full)
+        self._g_encode_rows.set(float(self._encode_rows))
+        if self.perf is not None:
+            self.perf.set_encode_rows(self._encode_rows)
+        if self.table_spec is not None:
+            self._price_table_exchange(self._encode_rows)
+
+    def _price_table_exchange(self, rows: int) -> None:
+        """The sharded-gather wire model at ``rows`` ids a gather."""
+        from fedrec_tpu.shard.table import a2a_bytes_per_gather
+
+        self._a2a_bytes_per_step = a2a_bytes_per_gather(
+            rows, tuple(self.token_states.shape[1:]),
+            self.token_states.dtype, self.table_spec,
+        )
+        self._g_remote_rows.set(float(self.table_spec.num_shards * rows))
 
     # ------------------------------------------------- health / forensics
     def _host_state(self) -> Any:
@@ -2147,8 +2235,8 @@ class Trainer:
 
     def _flightrec_on_exception(self, e: BaseException) -> None:
         """Last-chance forensics: a run dying to an exception that never
-        reached a round-end health check (dispatch error, cap-overflow
-        abort) still dumps its batch ring + chunk-entry state."""
+        reached a round-end health check (a dispatch error) still dumps its
+        batch ring + chunk-entry state."""
         if self.flightrec is None or self.flightrec.dump_count > 0:
             return
         if not isinstance(e, Exception):
@@ -2641,7 +2729,8 @@ class Trainer:
 
         losses = []
         raw_losses = []  # per-client loss cells: the NaN-robust fallback
-        overflows = []  # device arrays; read once at round end (no per-step sync)
+        # each host-deduped step's largest distinct count over its clients
+        distinct: list[int] = []
         # sentry aux vectors, same deal: appended as device arrays, one
         # host fetch at the round-end health check
         health_rows: list[dict] = []
@@ -2653,8 +2742,6 @@ class Trainer:
         def keep_metrics(metrics) -> None:
             losses.append(metrics["mean_loss"])
             raw_losses.append(metrics["loss"])
-            if "unique_overflow" in metrics:
-                overflows.append(metrics["unique_overflow"])
             row = {k: v for k, v in metrics.items() if k.startswith("health.")}
             if row:
                 health_rows.append(row)
@@ -2682,7 +2769,14 @@ class Trainer:
                         sharded = shard_fed_batch(self.mesh, g, cfg)
                     if self._perf_keep_batch:
                         self._perf_last_batch = sharded
-                    with tracer.span("dispatch", kind="step", n=1):
+                    # what the step will encode, by the step's own rule
+                    entries = batch_host_dedup(sharded)
+                    encode = (
+                        {"rows": entries[0].shape[-1],
+                         "slots": entries[1].shape[-1]}
+                        if entries else {}
+                    )
+                    with tracer.span("dispatch", kind="step", n=1, **encode):
                         self.state, metrics = self.train_step(
                             self.state, sharded, table
                         )
@@ -2690,12 +2784,14 @@ class Trainer:
                 return
             keep_metrics(metrics)  # scan chain: (scan_s, clients) entries
 
+        if self._host_dedup and self._encode_rows is None:
+            self._choose_encode_rows(round_idx * cfg.fed.local_epochs)
         step_in_round = 0
         for local_epoch in range(cfg.fed.local_epochs):
             epoch_idx = round_idx * cfg.fed.local_epochs + local_epoch
             table = self._feature_table()
             group: list = []
-            it = self._epoch_batch_iter(epoch_idx, chaos_extra)
+            it = self._epoch_batch_iter(epoch_idx, chaos_extra, distinct)
             src = iter(it)
             try:
                 while True:
@@ -2818,16 +2914,12 @@ class Trainer:
         self._check_health(
             round_idx, health_rows=health_rows, round_losses=[train_loss]
         )
-        if overflows:
-            # per entry: max over clients (replicated psum total per step),
-            # then sum over the entry's steps — a scan chain contributes a
-            # (scan_steps, clients) array and must count EACH overflowed step
-            total = int(
-                np.sum([np.asarray(o).max(axis=-1).sum() for o in overflows])
-            )
-            if total > 0:
-                self._m_overflow.inc(total)
-                raise RuntimeError(self._overflow_message(total))
+        full_size = sum(most > self._encode_rows for most in distinct)
+        if full_size:
+            # those steps were served exactly, at the full size; the traffic
+            # has outgrown R, so the next round's is chosen from this one's
+            self._m_full_size_steps.inc(full_size)
+            self._set_encode_rows(max(distinct))
         if routing_rows:
             self._publish_routing(routing_rows)
         result = RoundResult(round_idx, train_loss)
@@ -3039,20 +3131,6 @@ class Trainer:
         finite = loss_cells[np.isfinite(loss_cells)]
         return float(finite.mean()) if finite.size else float("nan")
 
-    def _overflow_message(self, total: int) -> str:
-        cfg = self.cfg
-        policy = (
-            f"data.unique_news_cap_buckets={cfg.data.unique_news_cap_buckets!r}"
-            if cfg.data.unique_news_cap_buckets
-            else f"data.unique_news_cap={cfg.data.unique_news_cap}"
-        )
-        return (
-            f"{policy} overflowed on {total} step(s) this round — the "
-            "capped unique-news dedup dropped ids and the gradients are "
-            "invalid. Raise the cap (or set it to 0 for the exact "
-            "worst-case bound)."
-        )
-
     def _eval_if_due(self, result: RoundResult) -> None:
         """Round-cadence evaluation (train.eval_every), shared by the
         host-driven round and the rounds-in-jit chunk tail."""
@@ -3257,15 +3335,6 @@ class Trainer:
             round_idx, metrics3d=metrics,
             round_losses=[r.train_loss for r in results],
         )
-        if "unique_overflow" in metrics:
-            # (rounds, steps, clients): max over clients (replicated psum
-            # total), then count every overflowed step in the chunk
-            total = int(
-                np.asarray(metrics["unique_overflow"]).max(axis=-1).sum()
-            )
-            if total > 0:
-                self._m_overflow.inc(total)
-                raise RuntimeError(self._overflow_message(total))
         # only the chunk's last round can sit on an eval boundary
         # (_round_chunk guarantees it); earlier rounds get no metrics, same
         # as host-driven rounds off the eval cadence
@@ -3565,8 +3634,8 @@ class Trainer:
                 self.snapshots.wait()  # settle async saves before handing back
         except BaseException as e:
             # forensics on EVERY failing exit path: an exception that never
-            # reached a round-end health check (dispatch error, cap
-            # overflow) still dumps the batch ring + chunk-entry state
+            # reached a round-end health check (a dispatch error) still
+            # dumps the batch ring + chunk-entry state
             self._flightrec_on_exception(e)
             raise
         finally:
@@ -3577,8 +3646,8 @@ class Trainer:
             if self.perf is not None:
                 self.perf.close()
                 self._perf_last_batch = None
-            # artifacts on EVERY exit path: a run that died to a cap
-            # overflow (or any mid-round error) is exactly the run whose
+            # artifacts on EVERY exit path: a run that died to a
+            # mid-round error is exactly the run whose
             # trace/registry state is needed — and the failing round never
             # reached its _after_round snapshot
             if self._obs_dir is not None:

@@ -38,7 +38,7 @@ def flagship_out(value=12970.0):
     return {
         "value": value,
         "sec_per_step": 64 / value,
-        "unique_news_cap": 2560,
+        "encode_rows": 2560,
         "batch_size": 64,
         "headline_source": "flagship_b64",
         "flops_per_step": _flops_of(64),
@@ -57,7 +57,7 @@ def test_promotion_is_unconditional_even_when_b64_beats_sweep():
     assert out["batch_size"] == 256
     # the flagship point is preserved under b64_*, not promoted
     assert out["b64_samples_per_sec"] == 12970.0
-    assert out["b64_unique_news_cap"] == 2560
+    assert out["b64_encode_rows"] == 2560
 
 
 def test_promotion_recomputes_flops_and_mfu_for_promoted_row():

@@ -26,6 +26,7 @@ from fedrec_tpu.models import NewsRecommender
 from fedrec_tpu.ops import attention_kernels, fused_hot_path
 from fedrec_tpu.train import build_fed_train_step, build_param_sync
 from fedrec_tpu.train.state import init_client_state, replicate_state
+from fedrec_tpu.train.step import NEWS_INVERSE, NEWS_ROWS, encode_rows_for
 
 # the flagship's published widths (ModelConfig defaults)
 B, HIS, HEADS, HEAD_DIM = 64, 50, 20, 20
@@ -186,9 +187,10 @@ def test_fused_history_score_compiles(compiled_kernels, one_chip, direction):
 
 # ------------------------------------------------------------ whole step
 def _joint_step_case(devices, num_clients, batch=B, strategy="param_avg",
-                     rows=STEP_TABLE_ROWS):
+                     rows=STEP_TABLE_ROWS, encode_rows=0):
     """(step, sync, args): the default (XLA) joint train step and the
-    round-end sync over a mesh of described devices, full width, bf16."""
+    round-end sync over a mesh of described devices, full width, bf16.
+    ``encode_rows``: the batch carries the host's dedup at that size."""
     cfg = ExperimentConfig()
     cfg.model.text_encoder_mode = "head"
     cfg.model.dtype = "bfloat16"
@@ -210,11 +212,15 @@ def _joint_step_case(devices, num_clients, batch=B, strategy="param_avg",
     state = jax.tree_util.tree_map(
         lambda x: _spec(x.shape, x.dtype, per_client), state
     )
+    slots = batch * (CANDS + HIS)
     batch = {
         "candidates": _spec((num_clients, batch, CANDS), "int32", per_client),
         "history": _spec((num_clients, batch, HIS), "int32", per_client),
         "labels": _spec((num_clients, batch), "int32", per_client),
     }
+    if encode_rows:
+        batch[NEWS_ROWS] = _spec((num_clients, encode_rows), "int32", per_client)
+        batch[NEWS_INVERSE] = _spec((num_clients, slots), "int32", per_client)
     table = _spec((rows, TITLE, TRUNK), "bfloat16", NamedSharding(mesh, P()))
     weights = _spec((num_clients,), "float32", NamedSharding(mesh, P()))
     step = build_fed_train_step(
@@ -283,6 +289,69 @@ def test_single_worker_step_lays_the_gathered_rows_out_once(topo):
     assert re.findall(rf"\n  %\S+ = {gathered}\S* copy\(", compiled.as_text()) == []
     assert compiled.memory_analysis().temp_size_in_bytes < 4.5e9
     assert _table_copies(compiled, args[2]) == []
+
+
+def _gathered_rows_copies(compiled, clients, rows) -> list[str]:
+    """Top-level ``copy`` instructions of the gathered rows' size (inside a
+    fusion such a copy is a ROOT and costs no buffer of its own)."""
+    import re
+
+    shapes = "|".join(re.escape(s) for s in (
+        f"bf16[{clients * rows},{TITLE},{TRUNK}]",
+        f"bf16[{clients},{rows},{TITLE},{TRUNK}]",
+    ))
+    return re.findall(rf"\n  %\S+ = (?:{shapes})\S* copy\(", compiled.as_text())
+
+
+# the largest distinct count of a round's first 32 steps under ``rounds32``
+# (PERF.md section 6, PR 30), and the size the round loop derives from it
+HEAD_CELLS = {
+    "fed8.b64": dict(num_clients=8, batch=64, strategy="param_avg", most=2_738),
+    "central.b512": dict(num_clients=1, batch=512, strategy="grad_avg", most=13_970),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(HEAD_CELLS))
+def test_host_deduped_step_gathers_and_encodes_r_rows(topo, cell):
+    """The head-mode cells' step fed the host's dedup at the size the round
+    loop chooses: the dedup's sort is gone from the program, the gather
+    brings R rows a client and nothing of the slot count, and the rows are
+    laid out once."""
+    import re
+
+    case = dict(HEAD_CELLS[cell])
+    slots = case["batch"] * (CANDS + HIS)
+    rows = encode_rows_for(case.pop("most"), slots)
+    assert rows == {"fed8.b64": 2_880, "central.b512": 14_400}[cell]
+    step, _, args, _ = _joint_step_case(
+        topo.devices[:1], rows=32_768, encode_rows=rows, **case
+    )
+    compiled = step.lower(*args).compile()
+    text = compiled.as_text()
+    k = case["num_clients"]
+    assert f"bf16[{k * rows},{TITLE},{TRUNK}]" in text
+    assert f"bf16[{k * slots},{TITLE},{TRUNK}]" not in text
+    # the one sort left orders the inverse for the scatter-add of the news
+    # vectors' cotangents (the transpose of ``vecs[inv]``), as at the parent
+    sorts = re.findall(r" sort\(", text)
+    assert len(sorts) == 1 and f"s32[{k * slots}]" in text
+    assert _gathered_rows_copies(compiled, k, rows) == []
+    assert _table_copies(compiled, args[2]) == []
+    assert _table_layout(compiled) == (0, 1, 2)
+
+
+def test_a_whole_number_of_row_tiles_costs_the_cohort_a_copy(topo):
+    """Why R is 64 past a multiple of 128 (``train/step.py:
+    ENCODE_ROW_RESIDUE``), as the slot count 3,520 is: at 2,944 = 23 x 128
+    rows XLA:TPU lays the cohort's gathered rows out a second time, R minor,
+    for the head's weight gradient: 1.8 GB more of temporaries a step."""
+    case = dict(HEAD_CELLS["fed8.b64"])
+    case.pop("most")
+    step, _, args, _ = _joint_step_case(
+        topo.devices[:1], rows=32_768, encode_rows=2_944, **case
+    )
+    compiled = step.lower(*args).compile()
+    assert len(_gathered_rows_copies(compiled, 8, 2_944)) == 1
 
 
 def test_joint_step_compiles_for_four_chips(topo):

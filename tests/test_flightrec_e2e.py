@@ -108,20 +108,24 @@ def test_rounds_in_jit_nan_dumps_and_replays(tmp_path, fresh_obs, capsys):
     assert verdict["first_nonfinite"]["step"] == man["trigger"]["step"]
 
 
+def _device_lost(state, batch, table):
+    raise RuntimeError("device lost")
+
+
 def test_exception_abort_still_dumps(tmp_path, fresh_obs):
-    """A mid-round abort that never reaches the health check (cap
-    overflow) dumps the ring + chunk-entry state with kind=exception."""
+    """A mid-round abort that never reaches the health check (a dispatch
+    error) dumps the ring + chunk-entry state with kind=exception."""
     cfg = small_cfg()
     cfg.model.text_encoder_mode = "head"
     cfg.fed.strategy = "param_avg"
     cfg.fed.rounds = 1
     cfg.train.snapshot_dir = str(tmp_path / "snap")
     cfg.train.eval_every = 1000
-    cfg.data.unique_news_cap = 2  # every batch overflows -> RuntimeError
     cfg.obs.dir = str(tmp_path / "obs")
     data, _, token_states, _, _, _ = make_setup(cfg, num_train=64, seed=0)
     t = Trainer(cfg, data, np.asarray(token_states))
-    with pytest.raises(RuntimeError, match="overflowed"):
+    t.train_step = _device_lost  # the round's one step raises at dispatch
+    with pytest.raises(RuntimeError, match="device lost"):
         t.run()
     man = json.loads(
         (tmp_path / "obs" / "flightrec" / "manifest.json").read_text()

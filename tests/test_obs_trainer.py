@@ -146,9 +146,9 @@ def test_epsilon_spent_gauge_tracks_rounds(tmp_path, fresh_obs):
 
 
 def test_artifacts_written_when_training_dies(tmp_path, fresh_obs):
-    """A run that aborts mid-round (cap overflow) still leaves the obs
+    """A run that aborts mid-round (a dispatch error) still leaves the obs
     artifact trio — the failed run is exactly the one whose telemetry is
-    needed, and the overflow counter must be in the dumped snapshot."""
+    needed, and the steps it did dispatch must be in the dumped snapshot."""
     reg, _ = fresh_obs
     cfg = small_cfg()
     cfg.model.text_encoder_mode = "head"
@@ -156,18 +156,22 @@ def test_artifacts_written_when_training_dies(tmp_path, fresh_obs):
     cfg.fed.rounds = 1
     cfg.train.snapshot_dir = str(tmp_path / "snap")
     cfg.train.eval_every = 1000
-    cfg.data.unique_news_cap = 2  # every batch draws far more ids -> raise
     cfg.obs.dir = str(tmp_path / "obs")
     data, _, token_states, _, _, _ = make_setup(cfg, num_train=64, seed=0)
     t = Trainer(cfg, data, np.asarray(token_states))
-    with pytest.raises(RuntimeError, match="overflowed"):
+
+    def device_lost(state, batch, table):
+        raise RuntimeError("device lost")
+
+    t.train_step = device_lost  # the round's one step raises at dispatch
+    with pytest.raises(RuntimeError, match="device lost"):
         t.run()
     for f in ("metrics.jsonl", "trace.json", "prometheus.txt"):
         assert (tmp_path / "obs" / f).exists(), f"missing {f} after abort"
-    # the dumped exposition carries the overflow evidence
+    # the dumped exposition carries the aborted round's evidence
     prom = (tmp_path / "obs" / "prometheus.txt").read_text()
-    assert "train_cap_overflow_total" in prom
-    assert reg.counter("train.cap_overflow_total").value() > 0
+    assert "train_steps_total" in prom and "train_encode_rows" in prom
+    assert reg.counter("train.steps_total").value() == 1
 
 
 def test_no_trace_capacity_blowup_config_roundtrip():

@@ -66,19 +66,19 @@ def test_bench_imports_the_shared_flops_model():
     assert "from bench import _flops_per_train_step" not in prof_src
 
 
-def test_flops_model_scales_and_respects_cap():
+def test_flops_model_scales_and_saturates_at_the_catalog():
     cfg = ExperimentConfig()
     base = flops_per_train_step(cfg, 64, 4096)
     assert base > 0
     # more batch = more flops; the text tower term saturates at num_news
     assert flops_per_train_step(cfg, 128, 4096) > base
-    # a unique-news cap trims the text-tower term through the SAME policy
-    # the compiled step resolves
-    import copy
-
-    capped = copy.deepcopy(cfg)
-    capped.data.unique_news_cap = 256
-    assert flops_per_train_step(capped, 64, 4096) < base
+    # a catalog smaller than the 3,520 slots bounds the text-tower term,
+    # as it bounds the rows the compiled step encodes
+    assert flops_per_train_step(cfg, 64, 256) < base
+    # the text tower is priced at the size the round loop compiled it at,
+    # never above the slots (work the step skipped is not counted)
+    assert flops_per_train_step(cfg, 64, 4096, encode_rows=2880) < base
+    assert flops_per_train_step(cfg, 64, 4096, encode_rows=10**6) == base
 
 
 def test_chip_peaks_lookup():
@@ -392,6 +392,23 @@ def test_monitor_mfu_with_chip_peaks_and_eval_exclusion(fresh_obs):
     assert out["perf.samples_per_sec"] == pytest.approx(
         8 * cfg.fed.num_clients * cfg.data.batch_size / 2.0, rel=1e-6
     )
+
+
+def test_monitor_prices_the_text_tower_at_the_encode_size(fresh_obs):
+    """Once the round loop has chosen the step's encode size, the published
+    step FLOPs (and with them the MFU gauge) count that many text-tower
+    rows: work the step skipped is never counted."""
+    from fedrec_tpu.obs.report import snapshot_value
+
+    reg, tr = fresh_obs
+    cfg, mon = _mk_monitor(reg, tr, device_kind="TPU v4")
+    every_slot = flops_per_train_step(cfg, cfg.data.batch_size, 64)
+    assert snapshot_value(reg.snapshot(), "perf.step_flops") == every_slot
+    mon.set_encode_rows(32)
+    at_r = flops_per_train_step(cfg, cfg.data.batch_size, 64, encode_rows=32)
+    assert at_r < every_slot
+    assert mon.flops_per_step == at_r
+    assert snapshot_value(reg.snapshot(), "perf.step_flops") == at_r
 
 
 def test_monitor_capture_needs_obs_dir(fresh_obs):

@@ -159,36 +159,6 @@ def test_trainer_scan_steps_matches_per_batch(tmp_path):
     np.testing.assert_allclose(l1, l4, rtol=1e-6)
 
 
-def test_scan_overflow_count_matches_per_batch(tmp_path):
-    """A tripped unique_news_cap raises with a PER-STEP count under both
-    dispatch modes (the scan chain's (scan_steps, clients) overflow entry
-    must count each overflowed step, not collapse to 1)."""
-    import re
-
-    from fedrec_tpu.train.trainer import Trainer
-
-    def overflow_count(scan_steps, snap):
-        cfg = small_cfg()
-        cfg.model.text_encoder_mode = "head"  # joint mode — the capped path
-        cfg.fed.strategy = "param_avg"
-        cfg.fed.rounds = 1
-        cfg.train.scan_steps = scan_steps
-        cfg.train.snapshot_dir = str(snap)
-        cfg.train.eval_every = 1000
-        cfg.data.unique_news_cap = 2  # every batch draws far more ids
-        data, token_states = _trainer_fixture(cfg, num_train=4 * 64)
-        t = Trainer(cfg, data, token_states)
-        with pytest.raises(RuntimeError, match="overflowed") as exc:
-            t.run()
-        m = re.search(r"overflowed on (\d+) step", str(exc.value))
-        assert m, str(exc.value)
-        return int(m.group(1))
-
-    n1 = overflow_count(1, tmp_path / "a")
-    n2 = overflow_count(2, tmp_path / "b")
-    assert n1 == n2 and n1 >= 2, (n1, n2)
-
-
 def test_scan_cohorts_gru_compose():
     """Every axis of the round-3 feature matrix in one program: the GRU
     user tower, k=2 cohorts, and an epoch-in-jit scan chain — matching the

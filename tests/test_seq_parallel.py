@@ -416,61 +416,3 @@ def test_user_encoder_seq_parallel_grads_match():
     flat_g, _ = jax.tree_util.tree_flatten(g_got)
     for a, b_ in zip(flat_g, flat_w):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_), atol=2e-4)
-
-
-def test_unique_cap_overflow_detected_on_nonzero_seq_shard():
-    """The cap-corruption guard must see overflow on EVERY seq shard: the
-    batch is engineered so only seq shard 3's history slice exceeds the cap
-    (shard 0 stays under it) — without the psum over the seq axis the
-    P(clients) out-spec reports shard 0's zero and the corruption is silent."""
-    from fedrec_tpu.config import ExperimentConfig
-    from fedrec_tpu.fed import get_strategy
-    from fedrec_tpu.models import NewsRecommender
-    from fedrec_tpu.parallel import fed_mesh, shard_fed_batch
-    from fedrec_tpu.train import build_fed_train_step
-    from fedrec_tpu.train.state import init_client_state, replicate_state
-
-    cfg = ExperimentConfig()
-    cfg.model.news_dim = 32
-    cfg.model.num_heads = 4
-    cfg.model.head_dim = 8
-    cfg.model.query_dim = 16
-    cfg.model.bert_hidden = 48
-    cfg.model.dropout_rate = 0.0
-    cfg.model.text_encoder_mode = "head"
-    cfg.data.max_his_len = 16
-    cfg.data.max_title_len = 8
-    cfg.data.batch_size = 4
-    cfg.fed.num_clients = 2
-    cfg.fed.seq_shards = 4
-    cfg.data.unique_news_cap = 6
-
-    num_news, n_cli = 32, 2
-    rng = np.random.default_rng(5)
-    token_states = jnp.asarray(
-        rng.standard_normal((num_news, 8, 48)).astype(np.float32)
-    )
-    # candidates: one repeated id; history: shard s = columns [4s, 4s+4).
-    # shards 0-2 hold a single id (2 distinct with candidates, under cap 6);
-    # shard 3 holds 16 distinct ids -> 17 distinct > 6 on that shard only
-    candidates = np.full((n_cli, 4, 5), 1, np.int32)
-    history = np.full((n_cli, 4, 16), 2, np.int32)
-    history[:, :, 12:16] = (
-        np.arange(3, 3 + 16, dtype=np.int32).reshape(4, 4)[None, :, :]
-    )
-    raw_batch = {
-        "candidates": candidates,
-        "history": history,
-        "labels": np.zeros((n_cli, 4), np.int32),
-    }
-
-    model = NewsRecommender(cfg.model)
-    state0 = init_client_state(model, cfg, jax.random.PRNGKey(0), num_news, 8)
-    stacked = replicate_state(state0, n_cli, jax.random.PRNGKey(1))
-    mesh = fed_mesh(cfg)
-    batch = shard_fed_batch(mesh, raw_batch, cfg)
-    step = build_fed_train_step(
-        model, cfg, get_strategy("grad_avg"), mesh, mode="joint"
-    )
-    _, metrics = step(stacked, batch, token_states)
-    assert int(np.max(np.asarray(metrics["unique_overflow"]))) > 0

@@ -37,6 +37,8 @@ from fedrec_tpu.train import (
     stack_rounds,
 )
 
+from fedrec_tpu.train.step import NEWS_ROWS, host_news_dedup
+
 from test_train import _batch_dict, make_setup, small_cfg
 
 
@@ -173,11 +175,8 @@ def test_sharded_step_bitwise_equals_dense_all_dispatch_modes():
     _assert_trees_equal(rd.news_params, rs.news_params)
 
 
-def test_sharded_step_composes_with_chunk_and_cap():
-    cfg = small_cfg(
-        model__text_encoder_mode="head", data__gather_chunk=16,
-        data__unique_news_cap=60,
-    )
+def test_sharded_step_composes_with_chunk_and_host_dedup():
+    cfg = small_cfg(model__text_encoder_mode="head", data__gather_chunk=16)
     data, batcher, token_states, model, _, mesh = make_setup(cfg, seed=0)
     tab = ShardedNewsTable.create(np.asarray(token_states), mesh, "clients")
     b = _batch_dict(next(iter(batcher.epoch_batches_sharded(8, 0))))
@@ -191,21 +190,18 @@ def test_sharded_step_composes_with_chunk_and_cap():
     _, md = step_d(make_setup(cfg, seed=0)[4], shard_batch(mesh, b), token_states)
     _, ms = step_s(make_setup(cfg, seed=0)[4], shard_batch(mesh, b), tab.rows)
     np.testing.assert_array_equal(np.asarray(md["loss"]), np.asarray(ms["loss"]))
-    # overflow bound uses the GLOBAL catalog rows, not the local block:
-    # 60 slots hold this batch's distinct ids, so the flag stays zero
-    assert int(np.asarray(ms["unique_overflow"]).max()) == 0
-    # a cap below the distinct count must flag on the sharded path too
-    cfg_bad = small_cfg(
-        model__text_encoder_mode="head", data__unique_news_cap=8
+    # the exchange takes the ids it is given: fed the host's dedup at 60
+    # rows (below the 64 catalog rows the device-side dedup is bounded by,
+    # the GLOBAL count, not the local block's 8), both programs still agree
+    entries, most = host_news_dedup(b["candidates"], b["history"], 60, 64)
+    assert most <= 60 and entries[NEWS_ROWS].shape == (8, 60)
+    hb = {**b, **entries}
+    _, mdh = step_d(make_setup(cfg, seed=0)[4], shard_batch(mesh, hb), token_states)
+    _, msh = step_s(make_setup(cfg, seed=0)[4], shard_batch(mesh, hb), tab.rows)
+    np.testing.assert_array_equal(np.asarray(mdh["loss"]), np.asarray(msh["loss"]))
+    np.testing.assert_allclose(
+        np.asarray(msh["loss"]), np.asarray(ms["loss"]), rtol=1e-6
     )
-    step_bad = build_fed_train_step(
-        model, cfg_bad, get_strategy("param_avg"), mesh, mode="joint",
-        sharded_table=tab.spec,
-    )
-    _, mb = step_bad(
-        make_setup(cfg_bad, seed=0)[4], shard_batch(mesh, b), tab.rows
-    )
-    assert int(np.asarray(mb["unique_overflow"]).max()) > 0
 
 
 # ------------------------------------------------------------------ guards

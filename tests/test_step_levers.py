@@ -1,8 +1,5 @@
 """Hot-path levers added for the MFU-cliff work (train/step.py):
 
-  * ``resolve_unique_cap`` — the per-B bucketed unique-news-cap policy
-    (one global constant either over-caps small batches or silently
-    overflows large ones);
   * ``data.gather_chunk`` — tiled, rematerialized token-state gather+encode
     (exact same math, bounded HBM footprint);
   * ``donate_batch`` — builder option the Trainer uses to let XLA reclaim
@@ -12,44 +9,14 @@
 from __future__ import annotations
 
 import numpy as np
-import pytest
 
 import jax
 
 from fedrec_tpu.fed import get_strategy
 from fedrec_tpu.parallel import client_mesh, shard_batch
-from fedrec_tpu.train import build_fed_train_step, resolve_unique_cap
+from fedrec_tpu.train import build_fed_train_step
 
 from test_train import make_setup, small_cfg, _batch_dict
-
-
-def test_resolve_unique_cap_buckets():
-    cfg = small_cfg()
-    cfg.data.unique_news_cap_buckets = "64:2560,256:4096"
-    assert resolve_unique_cap(cfg, 8) == 2560
-    assert resolve_unique_cap(cfg, 64) == 2560
-    assert resolve_unique_cap(cfg, 65) == 4096
-    assert resolve_unique_cap(cfg, 256) == 4096
-    # past every bucket: uncapped (exact) — the fix for the flagship 2,560
-    # cap overflowing every B>=128 batch
-    assert resolve_unique_cap(cfg, 1024) == 0
-    # no buckets -> the global constant
-    cfg.data.unique_news_cap_buckets = ""
-    cfg.data.unique_news_cap = 7
-    assert resolve_unique_cap(cfg, 1024) == 7
-    # entries may arrive unsorted and spaced
-    cfg.data.unique_news_cap_buckets = " 256:4096 , 64:2560 "
-    assert resolve_unique_cap(cfg, 10) == 2560
-
-
-@pytest.mark.parametrize(
-    "bad", ["64", "64:2560:1", "x:1", "0:5", "8:-1", "64:2560,64:4096"]
-)
-def test_resolve_unique_cap_rejects_malformed(bad):
-    cfg = small_cfg()
-    cfg.data.unique_news_cap_buckets = bad
-    with pytest.raises(ValueError):
-        resolve_unique_cap(cfg, 64)
 
 
 def test_tiled_gather_matches_untiled():
@@ -95,22 +62,6 @@ def test_tiled_gather_matches_untiled():
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(c), rtol=1e-5, atol=1e-4, err_msg=path
         )
-
-
-def test_bucketed_cap_flags_overflow():
-    """A bucketed cap resolves per the traced B and drives the overflow
-    metric: B=8 -> cap 2 (guaranteed overflow on a real batch); the metric
-    must flag it so results are never silently corrupted."""
-    cfg_c = small_cfg()
-    cfg_c.data.unique_news_cap_buckets = "8:2,128:4096"
-    mesh = client_mesh(8)
-    _, batcher, token_states, model, st0c, _ = make_setup(cfg_c, seed=0)
-    batch = _batch_dict(next(batcher.epoch_batches_sharded(8, 0)))
-    step_c = build_fed_train_step(
-        model, cfg_c, get_strategy("grad_avg"), mesh, mode="joint"
-    )
-    _, m3 = step_c(st0c, shard_batch(mesh, batch), token_states)
-    assert int(np.max(np.asarray(m3["unique_overflow"]))) > 0
 
 
 def test_donate_batch_step_runs_with_fresh_buffers():

@@ -658,40 +658,6 @@ def test_coordinator_cli_two_process(tmp_path):
         assert "done after 2 rounds" in out
 
 
-def test_trainer_raises_on_unique_cap_overflow(tmp_path):
-    """A too-small data.unique_news_cap must abort the round loudly —
-    jnp.unique(size=cap) silently drops ids past the cap, so a run that kept
-    going would train on corrupted gathers."""
-    from fedrec_tpu.train.trainer import Trainer
-
-    cfg = tiny_cfg(tmp_path)
-    cfg.model.text_encoder_mode = "head"
-    cfg.data.unique_news_cap = 4  # far below any batch's distinct ids
-    data, token_states = tiny_data(cfg)
-    trainer = Trainer(cfg, data, token_states)
-    with pytest.raises(RuntimeError, match="unique_news_cap"):
-        trainer.train_round(0)
-
-
-def test_trainer_finetune_respects_unique_cap(tmp_path):
-    """Finetune mode (full trunk per unique slot) honors the cap: exact run
-    completes at a safe cap, and a too-small cap aborts the round."""
-    from fedrec_tpu.train.trainer import Trainer
-
-    cfg = finetune_cfg(tmp_path)
-    data = finetune_data(cfg)  # 48 news, trunk-vocab-compatible tokens
-    cfg.data.unique_news_cap = 46  # below num_news, above distinct-id count
-    trainer = Trainer(cfg, data, token_states=None)
-    r = trainer.train_round(0)
-    assert np.isfinite(r.train_loss)
-
-    cfg_bad = finetune_cfg(tmp_path / "bad")
-    cfg_bad.data.unique_news_cap = 4
-    trainer_bad = Trainer(cfg_bad, data, token_states=None)
-    with pytest.raises(RuntimeError, match="unique_news_cap"):
-        trainer_bad.train_round(0)
-
-
 WEIGHTED_WORKER = textwrap.dedent(
     """
     import os, sys
