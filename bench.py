@@ -407,101 +407,6 @@ def main() -> None:
         )
         out["cohort8_samples_per_sec"] = round(8 * B / dt8, 2)
 
-        # epoch-in-jit: lax.scan 32 B=64 steps in ONE dispatch — the per-step
-        # dispatch overhead that dominates the b64 row amortizes
-        # away inside the compiled chain (train.step.build_fed_train_scan;
-        # uncapped step, so the row compares to uncapped_samples_per_sec).
-        from fedrec_tpu.train import build_fed_train_scan, shard_scan_batches
-
-        S = 32
-        scan_step = build_fed_train_scan(
-            model, cfg, get_strategy("grad_avg"), mesh, mode="joint"
-        )
-
-        def make_scan_batch(seed: int, bsz: int, n_clients: int = 1):
-            r = np.random.default_rng(seed)
-            stacked_b = {
-                "candidates": r.integers(
-                    0, num_news, (S, 1, bsz, C)
-                ).astype(np.int32),
-                "history": r.integers(
-                    0, num_news, (S, 1, bsz, H)
-                ).astype(np.int32),
-                "labels": np.zeros((S, 1, bsz), np.int32),
-            }
-            return shard_scan_batches(mesh, stacked_b, cfg)
-
-        dt_scan = measure(
-            B, iters=10, the_step=scan_step, batch_maker=make_scan_batch
-        )
-        # first-class dispatch-insensitive companion to the headline
-        # (VERDICT r3 #8): one compiled chain of S steps pays ONE
-        # dispatch, so this number is steadier than the per-step B=64 row
-        out["scan_samples_per_sec"] = round(S * B / dt_scan, 2)
-        out["scan_batch_size"] = B
-        out["scan_chain_len"] = S
-
-        # rounds-in-jit: R federated rounds — each S train steps PLUS the
-        # round-end weighted FedAvg sync — compiled into ONE dispatch
-        # (train.step.build_fed_round_scan; equality with the host-driven
-        # round loop pinned in tests/test_scan.py). The reference pays
-        # Python+gloo dispatch per batch AND per round by construction
-        # (Parameter_Averaging_main.py:137-151).
-        from fedrec_tpu.train import (
-            build_fed_round_scan,
-            shard_round_batches,
-        )
-
-        R_r, S_r = 4, 8
-        round_scan = build_fed_round_scan(
-            model, cfg, get_strategy("param_avg"), mesh, mode="joint"
-        )
-        w_rounds = jnp.ones((R_r, 1), jnp.float32)
-
-        def make_round_batch(seed: int, bsz: int, n_clients: int = 1):
-            r = np.random.default_rng(seed)
-            stacked_b = {
-                "candidates": r.integers(
-                    0, num_news, (R_r, S_r, 1, bsz, C)
-                ).astype(np.int32),
-                "history": r.integers(
-                    0, num_news, (R_r, S_r, 1, bsz, H)
-                ).astype(np.int32),
-                "labels": np.zeros((R_r, S_r, 1, bsz), np.int32),
-            }
-            return shard_round_batches(mesh, stacked_b, cfg)
-
-        dt_r = measure(
-            B, iters=5,
-            the_step=lambda st, b, t: round_scan(st, b, t, w_rounds),
-            batch_maker=make_round_batch,
-        )
-        rs_rate = round(R_r * S_r * B / dt_r, 2)
-        out["round_scan_samples_per_sec"] = rs_rate
-        out["round_scan_shape"] = {"rounds": R_r, "steps": S_r, "batch": B}
-        # HEADLINE LEG for the dispatch-bound regime: rounds-in-jit is
-        # now the production Trainer's path (train.rounds_per_scan), so
-        # every window certifies the win at HEAD against the two
-        # config-matched comparators — the uncapped per-batch B=64 row
-        # and the epoch-scan row (all three run the identical uncapped
-        # step math at the same B).
-        per_batch = out.get("uncapped_samples_per_sec")
-        if per_batch:
-            out["round_scan_vs_per_batch_uncapped"] = round(
-                rs_rate / per_batch, 3
-            )
-        if out.get("scan_samples_per_sec"):
-            out["round_scan_vs_epoch_scan"] = round(
-                rs_rate / out["scan_samples_per_sec"], 3
-            )
-        out["round_scan_note"] = (
-            "config-matched comparators: uncapped per-batch B=64 "
-            "(round_scan_vs_per_batch_uncapped) and the S=32 epoch "
-            "scan (round_scan_vs_epoch_scan); the Trainer runs this "
-            "program in production behind train.rounds_per_scan "
-            "(trajectory equality pinned in tests/test_scan.py)"
-        )
-
         # decoupled (reference-parity) mode: the text tower leaves the step —
         # news vecs come from a precomputed (N, D) table gather; this is the
         # per-batch cost the reference's epoch structure actually implies.

@@ -9,7 +9,7 @@ round:
      trained from,
   3. pushes the delta to the :mod:`~fedrec_tpu.agg.server` commit
      authority (after the scripted chaos delay, when this worker is the
-     smoke's straggler — ``chaos.straggle_ms`` is the host-driven
+     smoke's straggler — ``chaos.straggle_ms`` is the host-side
      straggle knob and sleeps here, at the push boundary).  With
      ``fed.dcn_compress`` set, the push ships ENCODED per-leaf payloads
      instead of dense leaves: linear sketches go up raw (the server

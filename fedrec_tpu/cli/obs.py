@@ -45,7 +45,7 @@ Subcommands:
 
   fedrec-obs replay <dir | flightrec dir> [--max-steps N] [--json]
       Re-execute the flight-recorder dump's recorded steps on CPU from
-      the dumped chunk-entry state — deterministically confirming (and
+      the dumped round-entry state — deterministically confirming (and
       bisecting to) the step that went non-finite.  Exit 0 when the
       dump's trigger is reproduced, 1 when it is not.
 
@@ -669,10 +669,8 @@ def _cmd_replay(args) -> int:
             "parallel steps need a multi-device mesh and cannot replay on "
             "one CPU device"
         )
-    # replay is per-batch, host-driven, file-free — neutralize every knob
-    # that would change dispatch shape or write artifacts
-    cfg.train.rounds_per_scan = 1
-    cfg.train.scan_steps = 1
+    # replay is per-batch and file-free — neutralize every knob that
+    # would donate a reused batch or write artifacts
     cfg.train.donate_batch = False
     cfg.data.prefetch_batches = 0
     cfg.obs.dir = ""
@@ -714,8 +712,9 @@ def _cmd_replay(args) -> int:
     from fedrec_tpu.train.step import compressed_sync_active
 
     # codec syncs (fed.dcn_compress != none) compress ROUND DELTAS: track
-    # each round's entry params so a chunk-spanning dump replays the exact
-    # compressed trajectory. Host copies — the step donates state buffers.
+    # each round's entry params so a dump that spans rounds (an older
+    # run's) replays the exact compressed trajectory. Host copies — the
+    # step donates state buffers.
     sync_takes_entry = sync is not None and compressed_sync_active(cfg, strategy)
 
     def _entry_copy(st):
@@ -736,7 +735,7 @@ def _cmd_replay(args) -> int:
         if rec["round"] != prev_round:
             if sync is not None and prev_round in weights:
                 # re-apply the recorded round-end participation sync so a
-                # chunk-spanning dump replays the exact trajectory
+                # dump that spans rounds replays the exact trajectory
                 if sync_takes_entry:
                     state = sync(state, np.asarray(weights[prev_round]), *entry)
                 else:

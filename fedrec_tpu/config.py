@@ -360,7 +360,7 @@ class FedConfig:
     server_momentum: float = 0.9
     # client->server UPDATE compression (fedrec_tpu.comms): applied at the
     # in-graph round-end sync (each cohort client's round delta — the
-    # simulated cross-device uplink, host-driven AND rounds-in-jit) and at
+    # simulated cross-device uplink) and at
     # the coordinator's cross-host DCN gather (real wire buffers). The
     # server->client fan-out stays full precision in every mode.
     #   "none"     — dense f32 (bit-identical to the pre-codec sync)
@@ -409,7 +409,7 @@ class FedConfig:
     dcn_error_feedback: bool = True
     # Byzantine-robust aggregation + quarantine/rollback recovery (see
     # RobustConfig). Applies wherever params aggregate: the in-graph
-    # round-end sync (param_avg, host-driven AND rounds-in-jit) and the
+    # round-end sync (param_avg) and the
     # coordinator's cross-host gather.
     robust: RobustConfig = field(default_factory=RobustConfig)
     # cross-device cohort engine: logical-client population sampled onto
@@ -750,10 +750,9 @@ class ChaosConfig:
     A seeded :class:`FaultPlan` schedules per-round, per-client faults.
     Client-side faults are applied as masks at the optimizer-update
     boundary INSIDE the jitted step (the per-client fault vector rides the
-    batch as ``chaos.code``/``chaos.scale`` arrays, so every dispatch mode
-    — per-batch, epoch scan, rounds-in-jit — and the flight-recorder
-    replay see identical faults), and two runs of the same plan are
-    bit-identical. Host-level faults (peer kill, torn snapshot) exercise
+    batch as ``chaos.code``/``chaos.scale`` arrays, so the round loop and
+    the flight-recorder replay see identical faults), and two runs of the
+    same plan are bit-identical. Host-level faults (peer kill, torn snapshot) exercise
     the coordinator deployment's recovery paths.
 
     ``faults`` is a comma list of ``kind@round:client[xscale]`` specs,
@@ -768,7 +767,7 @@ class ChaosConfig:
     seed: int = 0
     drop_rate: float = 0.0             # per-(round, client) Bernoulli dropout
     straggle_rate: float = 0.0         # ditto; weight 0 + optional host delay
-    straggle_ms: float = 0.0           # host-driven path: sleep per straggler round
+    straggle_ms: float = 0.0           # host sleep per straggler round
     faults: str = ""                   # "kind@round:client[xscale]" comma list
     # ---- population-level fault distributions (fed.population): applied
     # to LOGICAL client ids at cohort-sampling time, seeded per
@@ -889,28 +888,10 @@ class TrainConfig:
     #             behind the published MIND table, evaluation_functions.py:33-47)
     # "last4"   — deterministic last-4-pool-negatives slice (client.py:159-160)
     eval_protocol: str = "full"
-    # epoch-in-jit: dispatch the train step in lax.scan chains of this many
-    # batches (1 = per-batch dispatch). Amortizes host->device dispatch —
-    # the dominant cost of small-batch steps on remote-dispatch links
-    # (train.step.build_fed_train_scan); trajectories are identical
-    # (tests/test_scan.py). Chains compile for this one static length; a
-    # short epoch tail falls back to per-batch dispatch.
-    scan_steps: int = 1
-    # rounds-in-jit: execute whole federated ROUNDS (all local epochs + the
-    # round-end param sync) in compiled chunks of up to this many rounds via
-    # train.step.build_fed_round_scan — one XLA dispatch per chunk instead
-    # of one per batch. Chunks always break at eval/save cadence boundaries,
-    # so checkpoint and evaluation behavior is byte-identical to the
-    # host-driven loop (and so is the trajectory — tests/test_scan.py).
-    # Requires joint/finetune mode, no server optimizer (FedOpt steps are
-    # host-side by design). 1 = host-driven rounds (default).
-    rounds_per_scan: int = 1
-    # donate the batch buffers to the compiled step/scan programs: the
-    # (steps, clients, B, ...) stacks of a round chunk are hundreds of MB at
-    # large B, and donation lets XLA reclaim them as scratch once consumed.
-    # Safe in the Trainer (every dispatch device_puts fresh arrays); leave
-    # False when driving the step builders directly with reused batches
-    # (bench.py's chain timer re-dispatches the same 8 batches).
+    # donate the batch buffers to the compiled step: donation lets XLA
+    # reclaim them as scratch once consumed. Safe in the Trainer (every
+    # dispatch device_puts fresh arrays); leave False when driving the step
+    # builder directly with reused batches.
     donate_batch: bool = False
     # keep a separate best-validation-AUC snapshot under
     # <snapshot_dir>/best (full snapshot dir incl. config.json, so
@@ -984,12 +965,20 @@ class ExperimentConfig:
         return self
 
 
-# flags deleted from the schema (fedrec-lint CC202 dead-flag findings).
-# from_dict tolerates them so snapshot config.json files written by older
-# runs keep loading; everything else unknown still fails fast.
+# fields deleted from the schema. to_dict writes every field, so from_dict
+# tolerates these: snapshot config.json files and flight-recorder manifests
+# written by older runs keep loading. Everything else unknown still fails
+# fast, and so does an override that names one of these.
 _REMOVED_KEYS = {
     "train.total_epochs",   # the CLI positional writes fed.rounds directly
     "train.log_every",      # never consulted; the Trainer logs every round
+    # the host dedups each step at a size that follows the traffic
+    "data.unique_news_cap",
+    "data.unique_news_cap_buckets",
+    # the two scan dispatch forms: their trajectories were pinned identical
+    # to the per-batch round loop's, so ignoring the value is exact
+    "train.scan_steps",
+    "train.rounds_per_scan",
 }
 
 

@@ -19,13 +19,12 @@ Client-side faults come in two flavors:
   weight is forced to 0 — it trains but its contribution is excluded,
   exactly the failure mode the reference dies on
   (Final_Report.pdf VII.a). Stragglers can additionally cost a host-side
-  delay (``straggle_ms``) on the host-driven path.
+  delay (``straggle_ms``).
 * **update faults** (``nan``, ``scale``, ``flip``): applied as masks at
   the optimizer-update boundary INSIDE the jitted step. The per-client
   ``(code, scale)`` vectors ride the batch dict as ``chaos.code`` /
-  ``chaos.scale`` arrays, so every dispatch mode (per-batch, epoch scan,
-  rounds-in-jit) compiles the same fault arithmetic, and the flight
-  recorder's batch ring captures them — ``fedrec-obs replay`` re-injects
+  ``chaos.scale`` arrays, so the step compiles the fault arithmetic and
+  the flight recorder's batch ring captures them — ``fedrec-obs replay`` re-injects
   the fault for free.
 
 Host-level faults (``kill_round``/``kill_process``, guarded by an

@@ -10,8 +10,7 @@ module is the host-side client-state layer behind ``fed.population``:
 * :class:`ClientPopulation` — N logical clients, each owning
 
   - a **data-shard handle**: a static, seeded, equal-size row shard of the
-    training set (equal sizes keep the per-round step count static, the
-    contract every jitted dispatch mode relies on);
+    training set (equal sizes keep the per-round step count static);
   - a **sample count** (the ``weighted`` sampler's selection weight);
   - an **optimizer sidecar** where the strategy keeps one
     (``client_state="persist"``): the non-parameter slot leaves — optax
@@ -76,13 +75,12 @@ class QuorumFailure(Exception):
     which the run aborts with an operator-grade message.
     """
 
-    def __init__(self, anchor_round: int, round_idx: int, reporting: int,
-                 min_reports: int, attempt: int):
+    def __init__(self, round_idx: int, reporting: int, min_reports: int,
+                 attempt: int):
         super().__init__(
             f"round {round_idx}: {reporting} reporting clients < quorum "
             f"min_reports={min_reports} (draw attempt {attempt})"
         )
-        self.anchor_round = int(anchor_round)  # the chunk's draw anchor
         self.round_idx = int(round_idx)
         self.reporting = int(reporting)
         self.attempt = int(attempt)
@@ -90,9 +88,9 @@ class QuorumFailure(Exception):
 
 @dataclass
 class CohortPlan:
-    """One round's (or rounds-in-jit chunk's) resolved cohort."""
+    """One round's resolved cohort."""
 
-    round_idx: int                     # the draw anchor round
+    round_idx: int                     # the round the cohort was drawn for
     attempt: int                       # quorum re-draw counter
     sampled: np.ndarray                # (S,) drawn candidates, priority order
     start_dropped: np.ndarray          # sampled ids that never started
@@ -121,10 +119,7 @@ def build_cohort_plan(
     ``pack=False`` is the fixed-world (population == slots) mode: slots
     ARE the clients, so over-selection repacking is skipped — a dropout
     keeps its slot and loses its weight in :func:`plan_round_weights`
-    instead. This keeps the slot->client map identical no matter where
-    the plan is anchored, which is what makes host-driven rounds and
-    rounds-in-jit chunks (one plan per chunk) bit-identical under
-    population-level chaos.
+    instead.
     """
     if over_select < 1.0:
         raise ValueError(
@@ -180,10 +175,9 @@ def plan_round_weights(
     ``plan``'s packing, plus an event dict for the ledger/metrics:
     ``{"reported": ids, "dropped": ids, "deadline_cut": ids}``.
 
-    For the plan's anchor round the dropout draws REPLAY the packing
+    For the plan's own round the dropout draws REPLAY the packing
     draws (same rng keys), so an occupant can only lose weight to the
-    deadline; later rounds of a rounds-in-jit chunk re-roll per-round
-    fates for the fixed cohort.
+    deadline.
     """
     from fedrec_tpu.fed.chaos import population_report
 

@@ -24,9 +24,8 @@ sensitivity to any one client, selectable via ``fed.robust.method``:
 
 All four run INSIDE the jitted round-end sync (``shard_map`` over the
 cohort axes), so they compose with everything already in the program: DP
-noise is applied per client *before* the sync, FedOpt steps the
-post-aggregation global, and the rounds-in-jit scan carries the same
-sync body as the host-driven round (``train.step._make_local_sync``).
+noise is applied per client *before* the sync and FedOpt steps the
+post-aggregation global (``train.step._make_local_sync``).
 
 Cost note: the robust methods materialize the full cohort per device via
 ``lax.all_gather`` — n_clients × params transient memory. Fine for the

@@ -936,11 +936,10 @@ def _round_intervals(
     tr: WorkerTrace, corr: float
 ) -> list[tuple[int, float, float, dict[str, float]]]:
     """(round, aligned start, aligned end, phase durations) per round
-    covered by this incarnation's ``fed_round`` spans.  A rounds-in-jit
-    chunk (``num_rounds`` > 1) is one dispatch: its wall interval is
-    split evenly across its rounds and its phase work attributed to
-    each covered round at 1/num_rounds — the same even attribution the
-    Trainer's round-seconds histogram applies.
+    covered by this incarnation's ``fed_round`` spans.  A span of an
+    older trace that covers several rounds (``num_rounds`` > 1) has its
+    wall interval split evenly across them and its phase work attributed
+    to each covered round at 1/num_rounds.
 
     Phase events are bucketed ONCE (sorted by start, window lookups by
     bisection): a rescans-per-span loop would be quadratic in trace

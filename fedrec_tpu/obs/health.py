@@ -13,7 +13,7 @@ contract:
   tripped a trigger (any non-finite cell, or a loss spike vs the
   trailing-window mean).
 * :class:`FlightRecorder` keeps a bounded ring of the last N
-  (batch, metadata) records plus the round/chunk-entry state; on a
+  (batch, metadata) records plus the round-entry state; on a
   trigger it dumps the offending batch, a params/opt-state checkpoint
   (flax msgpack), the registry snapshot, and a replay manifest into
   ``obs.dir/flightrec/``.  ``fedrec-obs replay`` re-executes the dumped
@@ -52,7 +52,7 @@ class TrainingHealthError(RuntimeError):
 def _observe_array(hist, arr: np.ndarray) -> None:
     """Publish every cell of ``arr`` into a registry histogram in ONE
     vectorized pass + one lock acquire (a per-cell ``observe()`` loop
-    costs milliseconds per chunk on the round-critical host path).
+    costs milliseconds per round on the round-critical host path).
     ``searchsorted(side='left')`` matches ``observe``'s inclusive-upper-
     bound ``bisect_left``; +inf (and nan, which compares unordered) land
     in the overflow bucket."""
@@ -68,9 +68,8 @@ def _observe_array(hist, arr: np.ndarray) -> None:
 class HealthMonitor:
     """Round-cadence digest of the sentry's health arrays.
 
-    ``check()`` takes ``(rounds, steps, clients)``-shaped arrays (a
-    host-driven round passes rounds=1) so the host-driven loop and the
-    rounds-in-jit chunk share one code path — and one trigger policy.
+    ``check()`` takes ``(rounds, steps, clients)``-shaped arrays; the
+    round loop passes one round's, rounds=1.
     """
 
     def __init__(self, health_cfg: Any, registry: MetricsRegistry | None = None):
@@ -149,7 +148,7 @@ class HealthMonitor:
         round_losses: list[float],
         ignore_clients: set[int] | None = None,
     ) -> dict | None:
-        """Digest one round's (or chunk's) health arrays.
+        """Digest one round's health arrays.
 
         ``rows`` values are shaped ``(rounds, steps, clients)``;
         ``round_losses`` has one mean loss per round.  Publishes registry
@@ -287,10 +286,11 @@ class HealthMonitor:
 
 
 class FlightRecorder:
-    """Bounded ring of (batch, rng/step metadata) + chunk-entry state.
+    """Bounded ring of (batch, rng/step metadata) + round-entry state.
 
-    ``start_chunk`` is called at every round (host-driven) or chunk
-    (rounds-in-jit) entry with a HOST copy of the pre-chunk client state —
+    ``start_chunk`` is called at every round's entry with a HOST copy of
+    the client state at that point (the name and the manifest's
+    ``chunk_start_round`` are the format ``fedrec-obs replay`` reads) —
     replay must start from the state the offending step actually saw, and
     the device buffers may be donated away by the time a trigger fires.
     ``record`` appends one per-step batch record (numpy references, no
@@ -372,8 +372,8 @@ class FlightRecorder:
             "chunk_start_round": self._chunk_start_round,
             "weights": self._weights,
             "ring_size": self.ring_size,
-            # False when the ring dropped early-chunk steps: replay then
-            # starts mid-chunk against the chunk-entry state (approximate)
+            # False when the ring dropped the round's early steps: replay
+            # then starts mid-round against the round-entry state (approximate)
             "ring_complete": self._records_seen <= self.ring_size,
             "records": [],
         }

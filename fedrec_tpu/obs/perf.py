@@ -399,7 +399,7 @@ class PerfMonitor:
     * ``perf.samples_per_sec`` / ``perf.mfu`` / ``perf.hbm_fraction``
       (the MFU/HBM gauges only when the chip peaks are known; the HBM
       fraction additionally needs a ``cost_analysis`` bytes-accessed
-      reading for the per-batch step program),
+      reading for the step program),
     * ``perf.host_ms_per_step`` / ``perf.dispatch_ms_per_step``,
     * ``perf.roofline_rounds_total{verdict=…}`` — the per-round verdict,
       short keys; canonical strings in :data:`ROOFLINE_VERDICTS`.
@@ -446,12 +446,6 @@ class PerfMonitor:
             cfg, cfg.data.batch_size, num_news
         )
         self.samples_per_step = cfg.fed.num_clients * cfg.data.batch_size
-        # per-batch dispatch only: a scan/round-chunk dispatch amortizes
-        # many steps per executable, so its bytes-accessed reading is not
-        # a per-step figure (the gauge stays absent there)
-        self._per_batch_dispatch = (
-            cfg.train.scan_steps <= 1 and cfg.train.rounds_per_scan <= 1
-        )
         self.cost = CostAnalysisRecorder(self.registry)
 
         self._g_step_flops = self.registry.gauge(
@@ -474,7 +468,7 @@ class PerfMonitor:
         self._g_hbm_fraction = self.registry.gauge(
             "perf.hbm_fraction",
             "cost_analysis bytes accessed / wall / chip HBM peak of the "
-            "last round; needs chip peaks + a per-batch dispatch",
+            "last round; needs chip peaks",
         )
         self._g_host_ms = self.registry.gauge(
             "perf.host_ms_per_step",
@@ -550,15 +544,15 @@ class PerfMonitor:
 
     def begin_round(self) -> None:
         """Mark the tracer/step-counter positions a round's digest diffs
-        against; call at round (or chunk) entry."""
+        against; call at round entry."""
         self._mark_events = self.tracer.event_count()
         self._mark_steps = self._steps_counter.value()
         self._mark_dropped = self.tracer.dropped
 
     def observe_round(
-        self, round_idx: int, num_rounds: int, wall_s: float
+        self, round_idx: int, wall_s: float
     ) -> dict[str, Any]:
-        """Digest the round (or rounds-in-jit chunk) that just finished:
+        """Digest the round that just finished:
         publish the gauges and return the per-round log keys
         (``perf.samples_per_sec`` / ``perf.mfu`` / ``perf.verdict``)."""
         steps = self._steps_counter.value() - self._mark_steps
@@ -596,7 +590,7 @@ class PerfMonitor:
             self._g_mfu.set(mfu)
             out["perf.mfu"] = round(mfu, 6)
         hbm_fraction = None
-        if self.peak_bw is not None and self._per_batch_dispatch and steps > 0:
+        if self.peak_bw is not None and steps > 0:
             nbytes = self.cost.bytes_accessed("train_step")
             if nbytes:
                 hbm_fraction = steps * nbytes / wall_s / self.peak_bw
@@ -607,10 +601,10 @@ class PerfMonitor:
             # pipeline costs at least as much as the device step it feeds
             input_bound = disp_s > 0 and host_s >= disp_s
             key, _ = roofline_verdict(input_bound, mfu, hbm_fraction)
-            self._c_verdicts.inc(num_rounds, verdict=key)
+            self._c_verdicts.inc(verdict=key)
             out["perf.verdict"] = key
         else:
-            self._c_untraced.inc(num_rounds)
+            self._c_untraced.inc()
         self.last_round = {"round": round_idx, **out}
         # efficiency-drop trigger: a round well below the trailing mean
         # arms a capture of the NEXT round (this one is already gone).
@@ -643,23 +637,17 @@ class PerfMonitor:
         self._pending_trigger = True
         return True
 
-    def capture_before_round(
-        self, round_idx: int, num_rounds: int = 1
-    ) -> str | None:
-        """Start a capture window when the dispatch beginning at round
-        ``round_idx`` (covering ``num_rounds`` rounds — a rounds-in-jit
-        chunk dispatches several) intersects one: the configured
-        [N, N+K) window, or a pending efficiency-drop trigger.  Returns
-        the logdir when a window started."""
+    def capture_before_round(self, round_idx: int) -> str | None:
+        """Start a capture window when round ``round_idx`` lies in one:
+        the configured [N, N+K) window, or a pending efficiency-drop
+        trigger.  Returns the logdir when a window started."""
         if self._active is not None or self.obs_dir is None:
             return None
         reason = None
         end = round_idx + 1
         if self._window is not None:
             start, length = self._window
-            # intersection, not membership: under rounds-in-jit a chunk
-            # can stride over the window's start round
-            if start < round_idx + num_rounds and round_idx < start + length:
+            if start <= round_idx < start + length:
                 reason, end = "configured", start + length
         if reason is None and self._pending_trigger:
             reason = "efficiency_drop"

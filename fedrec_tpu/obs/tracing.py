@@ -8,8 +8,8 @@ tracer records those as wall-clock spans and writes them in the Chrome
 trace event format (``{"traceEvents": [...]}``), which both
 ``chrome://tracing`` and https://ui.perfetto.dev load directly.
 
-Correlating host and device: the Trainer wraps every round (or
-rounds-in-jit chunk) in BOTH a host span here and a
+Correlating host and device: the Trainer wraps every round in BOTH a
+host span here and a
 ``jax.profiler.StepTraceAnnotation("fed_round", step_num=...)``, so when
 a device trace is captured (``train.profile=true``) the XLA steps carry
 the same round numbers as the host spans.

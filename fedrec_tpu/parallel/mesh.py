@@ -152,9 +152,7 @@ def _two_axis_mesh(
 def fed_batch_spec(key: str, cfg: Any, mesh: Mesh) -> P:
     """The ONE per-key batch layout rule: dim 0 over the clients axis;
     ``history``'s last dim additionally over the seq axis when sequence
-    parallelism is on. Used by ``shard_fed_batch`` and (under a prepended
-    steps dim) by ``train.step.shard_scan_batches`` — change it here and
-    both input paths follow."""
+    parallelism is on. Used by ``shard_fed_batch``."""
     if (
         cfg.fed.seq_shards > 1
         and cfg.fed.seq_axis in mesh.axis_names

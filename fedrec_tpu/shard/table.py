@@ -27,8 +27,8 @@ train step's downstream math — dedup inverse scatter, text-head encode,
 Capacity scales linearly with devices: ``rows_per_device = ceil(N / S)``.
 
 Why fixed shapes: a "send only what each shard needs" exchange would put
-a data-dependent dimension inside the compiled step (retrace per batch,
-illegal under ``lax.scan`` rounds-in-jit). The ``(S, U)`` worst-case
+a data-dependent dimension inside the compiled step (retrace per
+batch). The ``(S, U)`` worst-case
 bucket wastes wire on padding slots, which is exactly what the round
 loop's host-side dedup bounds: ``U`` is the encode size it chose from the
 traffic (``train/step.py: host_news_dedup``), not the slot count.

@@ -1,8 +1,6 @@
 from fedrec_tpu.train.state import ClientState, init_client_state, stack_states
 from fedrec_tpu.train.step import (
     build_eval_step,
-    build_fed_round_scan,
-    build_fed_train_scan,
     build_fed_train_step,
     build_full_eval_step,
     build_full_eval_step_sharded,
@@ -11,10 +9,6 @@ from fedrec_tpu.train.step import (
     compressed_sync_active,
     encode_all_news,
     encode_all_news_sharded,
-    shard_round_batches,
-    shard_scan_batches,
-    stack_batches,
-    stack_rounds,
 )
 
 __all__ = [
@@ -22,8 +16,6 @@ __all__ = [
     "build_eval_step",
     "build_full_eval_step",
     "build_full_eval_step_sharded",
-    "build_fed_round_scan",
-    "build_fed_train_scan",
     "build_fed_train_step",
     "build_news_update_step",
     "build_param_sync",
@@ -31,9 +23,5 @@ __all__ = [
     "encode_all_news",
     "encode_all_news_sharded",
     "init_client_state",
-    "shard_round_batches",
-    "shard_scan_batches",
-    "stack_batches",
-    "stack_rounds",
     "stack_states",
 ]

@@ -107,8 +107,8 @@ def _apply_update_fault(tree: Any, code: jnp.ndarray, scale: jnp.ndarray) -> Any
 
     ``code`` is this client's scalar fault code (``fed.chaos.FAULT_CODES``:
     0 none, 1 nan, 2 scale, 3 sign-flip) and ``scale`` the multiplier for
-    code 2 — both ride the batch dict so every dispatch mode (and the
-    flight-recorder replay) compiles identical fault arithmetic. Code 0
+    code 2 — both ride the batch dict so the round loop and the
+    flight-recorder replay compile identical fault arithmetic. Code 0
     selects the original update untouched (exact, not ``u * 1``).
     """
 
@@ -738,9 +738,7 @@ def _build_local_step(
     """The ONE construction of the per-client step math.
 
     Returns ``(local_step, cohort_k, batch_spec, mesh_axis)`` — wrapped into
-    a per-batch program by ``build_fed_train_step`` and into an
-    epoch-in-jit ``lax.scan`` by ``build_fed_train_scan``; both wrappers
-    share this body so a fix to the step math can never diverge them.
+    the per-batch program by ``build_fed_train_step``.
 
     ``noise_fn(grads, rng) -> grads`` is the LDP hook: applied per client,
     device-side, *before* any cross-client collective (the honest version of
@@ -1177,8 +1175,8 @@ def _build_local_step(
             metrics["health.grad_norm"] = grad_norm
             metrics["health.update_norm"] = update_norm
             metrics["health.param_norm"] = param_norm
-            # int32 sentinel, not bool: scan stacks it over steps and the
-            # host sums it — "how many step×client cells went non-finite"
+            # int32 sentinel, not bool: the host stacks it over steps and
+            # sums it — "how many step×client cells went non-finite"
             metrics["health.nonfinite"] = 1 - finite.astype(jnp.int32)
             if dp_stats is not None:
                 metrics["health.clip_rate"] = dp_stats["clip_rate"]
@@ -1222,7 +1220,7 @@ def build_fed_train_step(
     ``donate_batch`` additionally donates the batch buffers (the Trainer
     device_puts fresh arrays every dispatch, so XLA may reclaim them as
     scratch once consumed); leave False when re-dispatching the same batch
-    arrays (bench.py's chain timer does).
+    arrays.
 
     ``sharded_table`` (a ``shard.table.TableSpec``): the feature table is
     row-sharded over the clients axis instead of replicated, gathered
@@ -1251,192 +1249,6 @@ def build_fed_train_step(
         in_shardings=_table_in_shardings(3, cfg, mode, mesh, table_spec),
         donate_argnums=(0, 1) if donate_batch else (0,),
     )
-
-
-def _prepend_none(spec: Any) -> Any:
-    """P(axis, ...) -> P(None, axis, ...): same layout under a leading
-    (unsharded) steps dimension."""
-    if isinstance(spec, dict):
-        return {kk: _prepend_none(v) for kk, v in spec.items()}
-    return P(None, *spec)
-
-
-def build_fed_train_scan(
-    model: NewsRecommender,
-    cfg: ExperimentConfig,
-    strategy: FedStrategy,
-    mesh: Mesh,
-    mode: str | None = None,
-    noise_fn: Callable[[Any, jax.Array], Any] | None = None,
-    donate_batch: bool = False,
-    sharded_table: Any | None = None,
-    state_shardings: Any | None = None,
-) -> Callable:
-    """Epoch-in-jit: ``lax.scan`` the train step over a STACK of batches.
-
-    ``scan_fn(stacked_state, stacked_batches, table) -> (state, metrics)``
-    where every batch array carries a leading ``(steps,)`` dimension
-    (``stack_batches`` + ``shard_scan_batches``) and the returned metrics
-    do too. One XLA dispatch executes the whole chain — the TPU-first
-    answer to per-step dispatch overhead, which dominates small-batch
-    throughput (the reference pays per-batch Python+DDP dispatch by
-    construction, ``main.py:55-91``). Identical math to the per-step form:
-    the body IS the same ``_build_local_step`` closure, so a fix to the
-    step math lands in both.
-    """
-    local_step, k, batch_spec, axis = _build_local_step(
-        model, cfg, strategy, mesh, mode, noise_fn, sharded_table
-    )
-    table_spec = P(axis) if sharded_table is not None else P()
-
-    @partial(
-        shard_map,
-        mesh=mesh,
-        in_specs=(P(axis), _prepend_none(batch_spec), table_spec),
-        out_specs=(P(axis), _prepend_none(P(axis))),
-        check_vma=False,
-    )
-    def sharded_scan(stacked_state, batches, table):
-        def one(carry, batch):
-            new_state, metrics = _cohort_call(local_step, k, 2, carry, batch, table)
-            return new_state, metrics
-
-        return lax.scan(one, stacked_state, batches)
-
-    return jax.jit(
-        _reshard_state_out(sharded_scan, state_shardings),
-        in_shardings=_table_in_shardings(3, cfg, mode, mesh, table_spec),
-        donate_argnums=(0, 1) if donate_batch else (0,),
-    )
-
-
-def stack_batches(batches: list) -> dict:
-    """Stack per-step batch dicts into (steps, ...) arrays for
-    ``build_fed_train_scan``."""
-    return {
-        kk: np.stack([b[kk] for b in batches]) for kk in batches[0]
-    }
-
-
-def shard_scan_batches(mesh: Mesh, stacked: dict, cfg: ExperimentConfig) -> dict:
-    """Device-put stacked (steps, num_clients, ...) batch arrays: the
-    per-key ``parallel.mesh.fed_batch_spec`` layout under a leading
-    (unsharded) steps dimension."""
-    return _shard_stacked_batches(mesh, stacked, cfg, depth=1)
-
-
-def _shard_stacked_batches(
-    mesh: Mesh, stacked: dict, cfg: ExperimentConfig, depth: int
-) -> dict:
-    """THE device-put for batch stacks: the per-key fed layout under
-    ``depth`` leading unsharded dims (1 = epoch scan, 2 = round scan)."""
-    from jax.sharding import NamedSharding
-
-    from fedrec_tpu.parallel.mesh import fed_batch_spec
-
-    def spec_of(kk):
-        s = fed_batch_spec(kk, cfg, mesh)
-        for _ in range(depth):
-            s = _prepend_none(s)
-        return s
-
-    return {
-        kk: jax.device_put(np.asarray(v), NamedSharding(mesh, spec_of(kk)))
-        for kk, v in stacked.items()
-    }
-
-
-def build_fed_round_scan(
-    model: NewsRecommender,
-    cfg: ExperimentConfig,
-    strategy: FedStrategy,
-    mesh: Mesh,
-    mode: str | None = None,
-    noise_fn: Callable[[Any, jax.Array], Any] | None = None,
-    donate_batch: bool = False,
-    sharded_table: Any | None = None,
-    state_shardings: Any | None = None,
-) -> Callable:
-    """Rounds-in-jit: whole federated ROUNDS in one XLA dispatch.
-
-    ``round_scan(stacked_state, batches, table, weights) ->
-    (state, metrics)`` where every batch array carries a leading
-    ``(rounds, steps)`` pair (``stack_rounds`` + ``shard_round_batches``)
-    and ``weights`` is a ``(rounds, num_clients)`` participation matrix
-    applied at each round's end through ``strategy.sync_params``. This
-    compiles the round loop the reference drives from Python over gloo —
-    per-epoch ``all_reduce(param)/world_size``
-    (``Parameter_Averaging_main.py:137-151``) and the server's
-    broadcast/gather round loop (``server.py:72-105``) — into a single
-    program: one dispatch per R rounds instead of R·S per-batch dispatches,
-    the next rung above ``build_fed_train_scan``.
-
-    The step body IS the same ``_build_local_step`` closure and the sync
-    uses the ONE ``cohort_axes`` policy, so the math is identical to the
-    Trainer's host-driven rounds (pinned in ``tests/test_scan.py``).
-    ``Local``/``GradAvg`` strategies make the round-end sync a no-op,
-    turning this into a plain multi-epoch-in-jit.
-    """
-    local_step, k, batch_spec, axis = _build_local_step(
-        model, cfg, strategy, mesh, mode, noise_fn, sharded_table
-    )
-    table_spec = P(axis) if sharded_table is not None else P()
-    _, sync_axes = cohort_axes(cfg, mesh)
-    local_round_sync = _make_local_sync(strategy, sync_axes, cfg.fed.robust, cfg.fed)
-    codec_sync = compressed_sync_active(cfg, strategy)
-
-    @partial(
-        shard_map,
-        mesh=mesh,
-        in_specs=(
-            P(axis),
-            _prepend_none(_prepend_none(batch_spec)),
-            table_spec,
-            _prepend_none(P(axis)),
-        ),
-        out_specs=(P(axis), _prepend_none(_prepend_none(P(axis)))),
-        check_vma=False,
-    )
-    def sharded_rounds(stacked_state, batches, table, weights):
-        def one_step(carry, batch):
-            return _cohort_call(local_step, k, 2, carry, batch, table)
-
-        def one_round(carry, xs):
-            r_batches, w = xs
-            # the codec sync compresses each client's ROUND DELTA, so it
-            # needs the round-entry params — captured from the carry here,
-            # exactly the trees the Trainer captures host-side for the
-            # host-driven path
-            entry_u, entry_n = carry.user_params, carry.news_params
-            st, ms = lax.scan(one_step, carry, r_batches)
-            if codec_sync:
-                st = _cohort_call(
-                    local_round_sync, k, 4, st, w, entry_u, entry_n
-                )
-            else:
-                st = _cohort_call(local_round_sync, k, 2, st, w)
-            return st, ms
-
-        return lax.scan(one_round, stacked_state, (batches, weights))
-
-    return jax.jit(
-        _reshard_state_out(sharded_rounds, state_shardings),
-        in_shardings=_table_in_shardings(4, cfg, mode, mesh, table_spec),
-        donate_argnums=(0, 1) if donate_batch else (0,),
-    )
-
-
-def stack_rounds(round_batches: list) -> dict:
-    """Stack a list of per-round batch lists into (rounds, steps, ...)
-    arrays for ``build_fed_round_scan`` — literally two layers of
-    ``stack_batches``."""
-    return stack_batches([stack_batches(r) for r in round_batches])
-
-
-def shard_round_batches(mesh: Mesh, stacked: dict, cfg: ExperimentConfig) -> dict:
-    """Device-put (rounds, steps, num_clients, ...) batch arrays with the
-    per-key fed layout under two leading unsharded dims."""
-    return _shard_stacked_batches(mesh, stacked, cfg, depth=2)
 
 
 def build_news_update_step(
@@ -1520,9 +1332,7 @@ def _make_local_sync(
     strategy: FedStrategy, sync_axes: Any, robust: Any = None,
     fed_cfg: Any = None, leaf_codecs: list | None = None,
 ) -> Callable:
-    """THE round-end parameter-sync body — shared by ``build_param_sync``
-    (host-driven rounds) and ``build_fed_round_scan`` (rounds-in-jit) so
-    the two programs can never diverge on what a round-end sync means.
+    """THE round-end parameter-sync body of ``build_param_sync``.
     Optimizer states stay local (the reference likewise only averages
     parameters).
 

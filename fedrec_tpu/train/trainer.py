@@ -45,12 +45,10 @@ from fedrec_tpu.train.state import init_client_state, replicate_state
 from fedrec_tpu.train.step import (
     batch_host_dedup,
     build_eval_step,
-    build_fed_round_scan,
     build_fed_train_step,
     build_full_eval_step,
     build_full_eval_step_sharded,
     build_news_update_step,
-    build_fed_train_scan,
     build_param_sync,
     compressed_sync_active,
     build_corpus_encode,
@@ -58,10 +56,6 @@ from fedrec_tpu.train.step import (
     encode_rows_for,
     host_news_dedup,
     most_distinct_news,
-    shard_round_batches,
-    shard_scan_batches,
-    stack_batches,
-    stack_rounds,
 )
 from fedrec_tpu.obs import (
     CompileWatchdog,
@@ -188,13 +182,6 @@ class Trainer:
                 "fed.robust.method='mean'"
             )
         if cfg.fed.dcn_compress == "auto":
-            if cfg.train.rounds_per_scan > 1:
-                raise ValueError(
-                    "fed.dcn_compress='auto' is incompatible with "
-                    "train.rounds_per_scan > 1: pinning the per-leaf codec "
-                    "map after warmup rebuilds the compiled sync, which "
-                    "cannot happen inside a compiled round chain"
-                )
             if rb.method != "mean":
                 raise ValueError(
                     "fed.dcn_compress='auto' requires "
@@ -247,13 +234,6 @@ class Trainer:
                 "the aggregation topology would silently never apply"
             )
         if cfg.agg.mode == "async":
-            if cfg.train.rounds_per_scan > 1:
-                raise ValueError(
-                    "agg.mode='async' is incompatible with "
-                    "train.rounds_per_scan > 1: the buffered quorum commit "
-                    "is a host-side round-boundary operation and cannot run "
-                    "inside a compiled round chain"
-                )
             if cfg.fed.dcn_compress == "auto":
                 raise ValueError(
                     "agg.mode='async' is incompatible with "
@@ -529,58 +509,19 @@ class Trainer:
             sharded_table=self.table_spec,
             state_shardings=self._state_shardings,
         )
-        # The joint step's dedup runs on the host wherever the round loop
-        # feeds one batch a dispatch (train/step.py: host_news_dedup): only
-        # the host holds a step's ids before it dispatches. The encode size
+        # The joint step's dedup runs on the host (train/step.py:
+        # host_news_dedup): only the host holds a step's ids before it
+        # dispatches. The encode size
         # R is chosen from the first round's own batches before the first
         # dispatch (_choose_encode_rows): None until then, and for good on
         # the paths whose step dedups on the device at the slot count.
         self._host_dedup = (
             self.mode == "joint"
             and cfg.fed.seq_shards <= 1
-            and cfg.train.scan_steps <= 1
             and not (cfg.privacy.enabled and cfg.privacy.mechanism == "dpsgd")
         )
         self._encode_rows: int | None = None
         self._encode_full = 0  # min(B*(C+H), N), which every count fits
-        # epoch-in-jit chains (train.scan_steps > 1): one dispatch per
-        # scan_steps batches; the tail of an epoch uses train_step
-        self.train_scan = (
-            build_fed_train_scan(
-                self.model, cfg, self.strategy, self.mesh, mode=self.mode,
-                donate_batch=cfg.train.donate_batch,
-                sharded_table=self.table_spec,
-                state_shardings=self._state_shardings,
-            )
-            if cfg.train.scan_steps > 1
-            else None
-        )
-        # rounds-in-jit (train.rounds_per_scan > 1): whole rounds — every
-        # local epoch plus the round-end sync — in one compiled dispatch.
-        # run() chunks rounds so chunk boundaries always land on eval/save
-        # cadence rounds; trajectory equality is pinned in tests/test_scan.py.
-        self.round_scan = None
-        if cfg.train.rounds_per_scan > 1:
-            if self.mode == "decoupled":
-                raise ValueError(
-                    "train.rounds_per_scan > 1 is not supported with "
-                    "model.text_encoder_mode='table' (decoupled mode): the "
-                    "epoch-end news_update/table refresh is a host-driven "
-                    "program between epochs. Use mode 'head' or 'finetune', "
-                    "or train.scan_steps for epoch-in-jit."
-                )
-            if self.server_opt is not None:
-                raise ValueError(
-                    "train.rounds_per_scan > 1 is incompatible with "
-                    "fed.server_opt: FedOpt steps round deltas host-side at "
-                    "every round boundary. Disable one of the two."
-                )
-            self.round_scan = build_fed_round_scan(
-                self.model, cfg, self.strategy, self.mesh, mode=self.mode,
-                donate_batch=cfg.train.donate_batch,
-                sharded_table=self.table_spec,
-                state_shardings=self._state_shardings,
-            )
         self.news_update = build_news_update_step(
             self.model, cfg, self.mesh, self.strategy,
             state_shardings=self._state_shardings,
@@ -1236,9 +1177,9 @@ class Trainer:
         self.perf = None
         self._perf_last_batch = None
         # retain the last sharded batch ONLY when the HBM-attribution
-        # pass will actually read it — a pinned (steps, clients, B, ...)
-        # stack with no consumer would hold a chunk of device memory
-        # across rounds for nothing
+        # pass will actually read it — a pinned (clients, B, ...) batch
+        # with no consumer would hold device memory across rounds for
+        # nothing
         self._perf_keep_batch = False
         if cfg.obs.perf.enabled:
             from fedrec_tpu.obs.perf import PerfMonitor
@@ -1287,10 +1228,6 @@ class Trainer:
         # compile carries (callable, arg shapes) provenance — the steady-
         # shape paths must show exactly one compile per signature
         self.train_step = self.watchdog.watch(self.train_step, "train_step")
-        if self.train_scan is not None:
-            self.train_scan = self.watchdog.watch(self.train_scan, "train_scan")
-        if self.round_scan is not None:
-            self.round_scan = self.watchdog.watch(self.round_scan, "round_scan")
         self.eval_step = self.watchdog.watch(self.eval_step, "eval_step")
         self.full_eval_step = self.watchdog.watch(
             self.full_eval_step, "full_eval_step"
@@ -1897,12 +1834,12 @@ class Trainer:
     # ------------------------------------------------- health / forensics
     def _host_state(self) -> Any:
         """Host (numpy) copy of the full stacked client state — the flight
-        recorder's chunk-entry checkpoint. Device buffers may be donated
+        recorder's round-entry checkpoint. Device buffers may be donated
         away by the time a trigger fires, so the copy is eager."""
         return jax.tree_util.tree_map(np.asarray, self.state)
 
     def _entry_state(self) -> Any:
-        """The round/chunk-entry state the flight recorder keeps — None
+        """The round-entry state the flight recorder keeps — None
         when obs.health.snapshot_state is off (the per-round D2H copy is
         the recorder's dominant cost at large model x cohort scale; dumps
         then carry the batch ring but cannot replay)."""
@@ -1920,35 +1857,22 @@ class Trainer:
         self,
         start_round: int,
         health_rows: list[dict] | None = None,
-        metrics3d: dict | None = None,
         round_losses: tuple | list = (),
     ) -> None:
-        """Digest one round's (or chunk's) fetched sentry arrays through the
+        """Digest one round's fetched sentry arrays through the
         HealthMonitor; on a trigger, dump the flight recorder and (for a
         non-finite sentinel under abort_on_nonfinite) raise
         TrainingHealthError. One sync point per round — the arrays were
         produced asynchronously alongside the loss readback."""
         if not self.cfg.obs.health.sentry:
             return
-        if metrics3d is not None:
-            arrays = {
-                k: np.asarray(v)
-                for k, v in metrics3d.items()
-                if k.startswith("health.")
-            }
-        elif health_rows:
-            c = self.cfg.fed.num_clients
-            keys = health_rows[0].keys()
-            arrays = {
-                k: np.concatenate(
-                    [np.asarray(r[k]).reshape(-1, c) for r in health_rows]
-                )[None]
-                for k in keys
-            }
-        else:
+        if not health_rows:
             return
-        if not arrays:
-            return
+        # one (clients,) row a step -> the monitor's (rounds, steps, clients)
+        arrays = {
+            k: np.stack([np.asarray(r[k]) for r in health_rows])[None]
+            for k in health_rows[0]
+        }
         trigger = self.health.check(
             start_round, arrays, list(round_losses),
             ignore_clients=set(self._quarantine),
@@ -2022,9 +1946,9 @@ class Trainer:
 
     # ------------------------------------------- quarantine & rollback
     def train_round_recovering(self, round_idx: int) -> RoundResult:
-        """One host-driven round under the quarantine/rollback policy —
+        """One round under the quarantine/rollback policy —
         the coordinator driver's per-round entry point (``run`` applies
-        the same policy around whole chunks). Without
+        the same policy). Without
         ``fed.robust.recover`` this is exactly :meth:`train_round`."""
         from fedrec_tpu.fed.population import QuorumFailure
 
@@ -2044,7 +1968,7 @@ class Trainer:
             return result
 
     def _capture_recovery_state(self) -> None:
-        """Snapshot the rollback target at round/chunk entry: the full
+        """Snapshot the rollback target at round entry: the full
         client state (host copy), plus the FedOpt buffers — the server
         optimizer steps at round end, so replaying a rolled-back round
         without restoring them would double-apply momentum."""
@@ -2236,7 +2160,7 @@ class Trainer:
     def _flightrec_on_exception(self, e: BaseException) -> None:
         """Last-chance forensics: a run dying to an exception that never
         reached a round-end health check (a dispatch error) still dumps its
-        batch ring + chunk-entry state."""
+        batch ring + round-entry state."""
         if self.flightrec is None or self.flightrec.dump_count > 0:
             return
         if not isinstance(e, Exception):
@@ -2250,17 +2174,13 @@ class Trainer:
         })
 
     def _mask_rng(self, round_idx: int) -> jax.Array:
-        """THE per-round participation-mask key — host-driven rounds and
-        rounds-in-jit chunks both derive masks from this one expression, so
-        the chunked path's identical-trajectory contract cannot be broken
-        by editing one copy."""
+        """THE per-round participation-mask key."""
         return jax.random.PRNGKey(
             hash((self.cfg.train.seed, round_idx)) & 0x7FFFFFFF
         )
 
     def _round_weights(self, round_idx: int) -> np.ndarray:
-        """THE per-round aggregation weights — host-driven rounds and
-        rounds-in-jit chunks share this one composition:
+        """THE per-round aggregation weights, one composition:
 
         * fixed-world (no ``fed.population``): participation mask × chaos
           slot drop/straggle mask × quarantine exclusion — without chaos
@@ -2285,7 +2205,7 @@ class Trainer:
                 plan, round_idx, cfg.fed.population.round_deadline_ms,
                 chaos=self.chaos,
             )
-            if round_idx == plan.round_idx and plan.start_dropped.size:
+            if plan.start_dropped.size:
                 # start-drops never reached a slot; the ledger still owes
                 # them a dropped round (over-selection's raison d'etre)
                 events["dropped"] = np.unique(
@@ -2357,16 +2277,12 @@ class Trainer:
             self._g_cohort_reporting.set(float(reporting))
             mr = cfg.fed.population.min_reports
             if 0 < mr and reporting < mr:
-                raise QuorumFailure(
-                    plan.round_idx, round_idx, reporting, mr, plan.attempt
-                )
+                raise QuorumFailure(round_idx, reporting, mr, plan.attempt)
         return w
 
     # ------------------------------------------------- cohort engine
     def _ensure_cohort(self, round_idx: int) -> None:
-        """Sample and install the cohort for ``round_idx`` (the draw
-        anchor — a rounds-in-jit chunk keeps one cohort for its whole
-        span, re-rolling only the per-round report weights). Re-entrant:
+        """Sample and install the cohort for ``round_idx``. Re-entrant:
         a rollback or quorum replay re-derives the plan — same
         ``(seed, round, attempt)`` minus newly-quarantined clients —
         and the install no-ops when the occupancy is unchanged."""
@@ -2527,7 +2443,7 @@ class Trainer:
 
     def _handle_quorum_failure(self, e, round_idx: int) -> None:
         """One quorum-replay cycle: discard the round's pending ledger
-        events, bump the draw attempt for the anchor round (fresh cohort
+        events, bump the draw attempt for the round (fresh cohort
         + fresh fault dice next pass), abort once retries are exhausted.
         The failure is raised before any dispatch, so 'replay from the
         round-entry state' needs no state restore — the entry state was
@@ -2536,7 +2452,7 @@ class Trainer:
         self._pop_pending = {
             k: v for k, v in self._pop_pending.items() if k < round_idx
         }
-        attempts = self._pop_attempts.get(e.anchor_round, 0) + 1
+        attempts = self._pop_attempts.get(e.round_idx, 0) + 1
         self._m_quorum_replays.inc()
         self.tracer.add_span(
             "quorum_replay", dur_s=0.0, round=e.round_idx,
@@ -2572,7 +2488,7 @@ class Trainer:
                 "raise over_select, or relax the deadline "
                 "(docs/OPERATIONS.md, 'sizing a cohort')."
             ) from e
-        self._pop_attempts[e.anchor_round] = attempts
+        self._pop_attempts[e.round_idx] = attempts
         print(
             f"[trainer] WARNING: quorum failure at round {e.round_idx} "
             f"({e.reporting} < {pcfg.min_reports}); discarding the round "
@@ -2581,24 +2497,20 @@ class Trainer:
         )
 
     def _count_uplink(self, weights_np: np.ndarray) -> None:
-        """Bank one synced round's (or one chunk row's) modeled wire
-        traffic: each REPORTING client ships one encoded update up, every
-        client receives one dense fan-out down. Bytes come from a real
+        """Bank one synced round's modeled wire traffic: each REPORTING
+        client ships one encoded update up, every client receives one
+        dense fan-out down. Bytes come from a real
         wire-codec encode of the param trees (init-time; payload sizes are
         static per codec × shapes). No-op without an active codec."""
         if self._codec_bytes_per_client is None:
             return
-        w = np.asarray(weights_np).reshape(-1, self.cfg.fed.num_clients)
-        reporting = int((w > 0).sum())
-        rounds = int(w.shape[0])
+        reporting = int((np.asarray(weights_np) > 0).sum())
         self._m_bytes_up.inc(
             float(self._codec_bytes_per_client * reporting), path="cohort"
         )
         self._m_bytes_down.inc(
             float(
-                self._dense_bytes_per_client
-                * self.cfg.fed.num_clients
-                * rounds
+                self._dense_bytes_per_client * self.cfg.fed.num_clients
             ),
             path="cohort",
         )
@@ -2661,7 +2573,7 @@ class Trainer:
         )
 
     def train_round(self, round_idx: int) -> RoundResult:
-        """One host-driven federated round, wrapped in a ``fed_round`` host
+        """One federated round, wrapped in a ``fed_round`` host
         span AND a ``jax.profiler.StepTraceAnnotation`` carrying the same
         round number — so the obs trace and a captured device trace
         (train.profile) are correlatable round-for-round."""
@@ -2687,7 +2599,7 @@ class Trainer:
         wall = _time.perf_counter() - t0
         self._m_round_secs.observe(wall)
         if self.perf is not None:
-            self.perf.observe_round(round_idx, 1, wall)
+            self.perf.observe_round(round_idx, wall)
         return result
 
     def _train_round_inner(self, round_idx: int) -> RoundResult:
@@ -2735,7 +2647,6 @@ class Trainer:
         # host fetch at the round-end health check
         health_rows: list[dict] = []
         routing_rows: list[dict] = []  # sparse-expert trunk counters, same deal
-        scan_s = cfg.train.scan_steps if self.train_scan is not None else 1
 
         tracer = self.tracer
 
@@ -2750,47 +2661,12 @@ class Trainer:
                     {k: v for k, v in metrics.items() if k.startswith("moe.")}
                 )
 
-        def dispatch(group: list, table) -> None:
-            self._count_steps(len(group))
-            if len(group) == scan_s and scan_s > 1:
-                with tracer.span("h2d", n=len(group)):
-                    stacked = shard_scan_batches(
-                        self.mesh, stack_batches(group), cfg
-                    )
-                if self._perf_keep_batch:
-                    self._perf_last_batch = stacked
-                with tracer.span("dispatch", kind="scan_chain", n=len(group)):
-                    self.state, metrics = self.train_scan(
-                        self.state, stacked, table
-                    )
-            else:  # per-batch path; also the short epoch tail under scan
-                for g in group:
-                    with tracer.span("h2d", n=1):
-                        sharded = shard_fed_batch(self.mesh, g, cfg)
-                    if self._perf_keep_batch:
-                        self._perf_last_batch = sharded
-                    # what the step will encode, by the step's own rule
-                    entries = batch_host_dedup(sharded)
-                    encode = (
-                        {"rows": entries[0].shape[-1],
-                         "slots": entries[1].shape[-1]}
-                        if entries else {}
-                    )
-                    with tracer.span("dispatch", kind="step", n=1, **encode):
-                        self.state, metrics = self.train_step(
-                            self.state, sharded, table
-                        )
-                    keep_metrics(metrics)
-                return
-            keep_metrics(metrics)  # scan chain: (scan_s, clients) entries
-
         if self._host_dedup and self._encode_rows is None:
             self._choose_encode_rows(round_idx * cfg.fed.local_epochs)
         step_in_round = 0
         for local_epoch in range(cfg.fed.local_epochs):
             epoch_idx = round_idx * cfg.fed.local_epochs + local_epoch
             table = self._feature_table()
-            group: list = []
             it = self._epoch_batch_iter(epoch_idx, chaos_extra, distinct)
             src = iter(it)
             try:
@@ -2812,10 +2688,23 @@ class Trainer:
                             batch, round_idx, epoch_idx, step_in_round
                         )
                     step_in_round += 1
-                    group.append(batch)
-                    if len(group) == scan_s:
-                        dispatch(group, table)
-                        group = []
+                    self._count_steps(1)
+                    with tracer.span("h2d", n=1):
+                        sharded = shard_fed_batch(self.mesh, batch, cfg)
+                    if self._perf_keep_batch:
+                        self._perf_last_batch = sharded
+                    # what the step will encode, by the step's own rule
+                    entries = batch_host_dedup(sharded)
+                    encode = (
+                        {"rows": entries[0].shape[-1],
+                         "slots": entries[1].shape[-1]}
+                        if entries else {}
+                    )
+                    with tracer.span("dispatch", kind="step", n=1, **encode):
+                        self.state, metrics = self.train_step(
+                            self.state, sharded, table
+                        )
+                    keep_metrics(metrics)
             finally:
                 # a dispatch error mid-epoch must not leak the producer
                 # thread (Prefetcher.close is idempotent; bare generators
@@ -2823,8 +2712,6 @@ class Trainer:
                 close = getattr(it, "close", None)
                 if close is not None:
                     close()
-            if group:
-                dispatch(group, table)
             if self.mode == "decoupled":
                 self.state, tables = self.news_update(self.state, self.token_states)
                 self._table = self._replicate_table(
@@ -2901,12 +2788,10 @@ class Trainer:
             elif self.mode == "decoupled":
                 self._refresh_table()
 
-        # flat mean over every (step, client) cell: scan chains contribute one
-        # (scan_steps, clients) entry and per-batch steps one (clients,) entry,
-        # so a mean-of-entry-means would overweight the epoch tail
+        # the round's loss: the flat mean over every (step, client) cell
         train_loss = self._round_loss_mean(
-            np.concatenate([np.asarray(l).reshape(-1) for l in losses]),
-            np.concatenate([np.asarray(l).reshape(-1) for l in raw_losses]),
+            np.stack([np.asarray(l) for l in losses]),
+            np.stack([np.asarray(l) for l in raw_losses]),
         )
         # sentry digest FIRST: a non-finite sentinel is the root cause the
         # operator needs (and dumps the flight recorder) before any other
@@ -3132,8 +3017,7 @@ class Trainer:
         return float(finite.mean()) if finite.size else float("nan")
 
     def _eval_if_due(self, result: RoundResult) -> None:
-        """Round-cadence evaluation (train.eval_every), shared by the
-        host-driven round and the rounds-in-jit chunk tail."""
+        """Round-cadence evaluation (train.eval_every)."""
         if self.valid_ix is None:
             return
         if (result.round_idx + 1) % self.cfg.train.eval_every != 0:
@@ -3159,187 +3043,6 @@ class Trainer:
                 self._finish_quality_eval(
                     result.round_idx, q, result.val_metrics
                 )
-
-    # ----------------------------------------------------- rounds-in-jit
-    def _round_is_boundary(self, round_idx: int) -> bool:
-        """True when host-side work is due AFTER this round — evaluation
-        (eval_every), a snapshot (save_every / final round), or the end of
-        training — so a compiled round chunk must not run past it."""
-        cfg = self.cfg
-        if round_idx >= cfg.fed.rounds - 1:
-            return True
-        if self.valid_ix is not None and (round_idx + 1) % cfg.train.eval_every == 0:
-            return True
-        if self.snapshots is not None and (round_idx + 1) % cfg.train.save_every == 0:
-            return True
-        return False
-
-    def _round_chunk(self, round_idx: int) -> int:
-        """How many rounds starting at ``round_idx`` may run in one
-        compiled chunk: up to ``train.rounds_per_scan``, never crossing a
-        cadence boundary (so checkpoint/eval behavior is byte-identical to
-        the host-driven loop) — nor a quarantine expiry: the chunk's
-        weights stack is built at entry, so a chunk outliving a quarantine
-        would exclude the client past its configured
-        ``fed.robust.quarantine_rounds`` and delay its healed rejoin."""
-        if self.round_scan is None:
-            return 1
-        cap = self.cfg.train.rounds_per_scan
-        if self._quarantine:
-            cap = min(cap, min(self._quarantine.values()))
-        n = 1
-        while (
-            n < cap
-            and round_idx + n < self.cfg.fed.rounds
-            and not self._round_is_boundary(round_idx + n - 1)
-        ):
-            n += 1
-        return n
-
-    def _train_rounds_scan(self, round_idx: int, num_rounds: int) -> list[RoundResult]:
-        """Execute ``num_rounds`` whole federated rounds in ONE compiled
-        dispatch via ``build_fed_round_scan`` — every local epoch's steps
-        plus each round-end participation-weighted sync. The host builds
-        the (rounds, steps, clients, ...) batch stack up front — straight
-        off the batcher, no prefetcher: with a single dispatch at the end
-        there is no device work to overlap the build with — so the device
-        sees zero host round-trips until the chunk's final readback.
-
-        Identical trajectory to ``train_round`` driven ``num_rounds``
-        times: same step body, same sync policy, same per-round
-        participation masks (same rng derivation) — pinned in
-        ``tests/test_scan.py``.
-        """
-        import time as _time
-
-        t0 = _time.perf_counter()
-        # one cohort per CHUNK (the chunk's batch stack and state are fixed
-        # at entry; per-round report weights still re-roll inside) — cohort
-        # rotation under rounds-in-jit happens at chunk cadence, a
-        # documented divergence from the host-driven per-round rotation
-        self._ensure_cohort(round_idx)
-        if self.perf is not None:
-            self.perf.begin_round()
-        chunk_span = self.tracer.span(
-            "fed_round", step_num=round_idx, num_rounds=num_rounds,
-            **self._round_span_args(),
-        )
-        chunk_annotation = jax.profiler.StepTraceAnnotation(
-            "fed_round", step_num=round_idx
-        )
-        with chunk_span, chunk_annotation:
-            results = self._train_rounds_scan_inner(round_idx, num_rounds)
-            sample_device_memory(
-                self.registry, self.tracer, fed_round=round_idx
-            )
-            self._perf_sample_components(round_idx)
-        # the chunk is one dispatch; attribute its wall time evenly so the
-        # per-round histogram stays comparable across dispatch modes
-        wall = _time.perf_counter() - t0
-        per_round = wall / num_rounds
-        for _ in range(num_rounds):
-            self._m_round_secs.observe(per_round)
-        if self.perf is not None:
-            # one digest per chunk (the chunk IS one dispatch); the log
-            # keys ride every round of the chunk via _after_round
-            self.perf.observe_round(round_idx, num_rounds, wall)
-        return results
-
-    def _train_rounds_scan_inner(
-        self, round_idx: int, num_rounds: int
-    ) -> list[RoundResult]:
-        cfg = self.cfg
-        tracer = self.tracer
-        weights = np.stack([
-            self._round_weights(r)
-            for r in range(round_idx, round_idx + num_rounds)
-        ])
-        table = self._feature_table()
-        if self.flightrec is not None:
-            self.flightrec.start_chunk(
-                round_idx, self._entry_state(),
-                {round_idx + i: weights[i] for i in range(num_rounds)},
-            )
-
-        with tracer.span(
-            "batch_build", kind="round_stack", rounds=num_rounds
-        ):
-            round_lists: list[list[dict]] = []
-            steps: int | None = None
-            for r in range(round_idx, round_idx + num_rounds):
-                batches: list[dict] = []
-                chaos_extra = self._chaos_batch_keys(r) or {}
-                for local_epoch in range(cfg.fed.local_epochs):
-                    epoch_idx = r * cfg.fed.local_epochs + local_epoch
-                    # sampled world: slot j iterates the CHUNK cohort's
-                    # client j's own shard (same source as the host-driven
-                    # path — _ensure_cohort above fixed the occupancy)
-                    for b in self._epoch_batches_source(epoch_idx):
-                        batch = {
-                            "candidates": b.candidates,
-                            "history": b.history,
-                            "labels": b.labels,
-                            **chaos_extra,
-                        }
-                        if self.flightrec is not None:
-                            self.flightrec.record(
-                                batch, r, epoch_idx, len(batches)
-                            )
-                        batches.append(batch)
-                if steps is None:
-                    steps = len(batches)
-                elif len(batches) != steps:
-                    # static (rounds, steps) shapes are the contract; a
-                    # varying per-epoch step count cannot stack
-                    raise RuntimeError(
-                        f"rounds-in-jit needs a constant steps-per-round, got "
-                        f"{steps} then {len(batches)}"
-                    )
-                round_lists.append(batches)
-            if not steps:
-                raise ValueError(
-                    "no batches: dataset smaller than num_clients*batch_size"
-                )
-
-        with tracer.span("h2d", n=num_rounds * steps):
-            stacked = shard_round_batches(
-                self.mesh, stack_rounds(round_lists), cfg
-            )
-        if self._perf_keep_batch:
-            self._perf_last_batch = stacked
-        self._count_steps(num_rounds * steps)
-        with tracer.span(
-            "dispatch", kind="round_chunk", rounds=num_rounds, steps=steps
-        ):
-            self.state, metrics = self.round_scan(
-                self.state, stacked, table, jnp.asarray(weights)
-            )
-        if self.strategy.sync_params_every_round:
-            self._m_robust_rounds.inc(num_rounds, method=cfg.fed.robust.method)
-            self._count_uplink(weights)
-
-        mean_loss = np.asarray(metrics["mean_loss"])  # (rounds, steps, clients)
-        raw_loss = np.asarray(metrics["loss"])
-        results = []
-        for i in range(num_rounds):
-            # same reduction as the host-driven round's loss bookkeeping
-            results.append(
-                RoundResult(
-                    round_idx + i,
-                    self._round_loss_mean(mean_loss[i], raw_loss[i]),
-                )
-            )
-        # sentry digest first (see _train_round_inner): the health arrays
-        # are already (rounds, steps, clients) in the chunk's metrics
-        self._check_health(
-            round_idx, metrics3d=metrics,
-            round_losses=[r.train_loss for r in results],
-        )
-        # only the chunk's last round can sit on an eval boundary
-        # (_round_chunk guarantees it); earlier rounds get no metrics, same
-        # as host-driven rounds off the eval cadence
-        self._eval_if_due(results[-1])
-        return results
 
     def evaluate(self, client: int | None = None) -> dict[str, float]:
         """Mean validation metrics over all impressions (fixes the reference's
@@ -3585,57 +3288,46 @@ class Trainer:
                     })
                 round_idx = self.start_round
                 while round_idx < cfg.fed.rounds:
-                    # rounds-in-jit: chunks of up to train.rounds_per_scan
-                    # rounds in one dispatch, always breaking at eval/save
-                    # cadence boundaries so the host-side bookkeeping below
-                    # sees exactly the rounds it would host-driven
-                    chunk = self._round_chunk(round_idx)
                     if self.perf is not None:
-                        # capture windows open at the dispatch boundary —
-                        # a window intersecting this round/chunk starts a
+                        # capture windows open at the round boundary — a
+                        # window that holds this round starts a
                         # jax.profiler trace under obs.dir
-                        self.perf.capture_before_round(round_idx, chunk)
+                        self.perf.capture_before_round(round_idx)
                     # rollback target: the state every client held at
-                    # round/chunk entry — one blocking host copy per round
+                    # round entry — one blocking host copy per round
                     # is the price of replayability (same cost profile as
                     # obs.health.snapshot_state); no-op unless recover
                     self._capture_recovery_state()
                     try:
-                        if chunk > 1:
-                            results = self._train_rounds_scan(round_idx, chunk)
-                        else:
-                            results = [self.train_round(round_idx)]
+                        result = self.train_round(round_idx)
                     except RoundRecovery as e:
                         self._rollback_and_quarantine(e.trigger, round_idx)
-                        continue  # replay the same round/chunk
+                        continue  # replay the same round
                     except QuorumFailure as e:
                         # raised BEFORE any dispatch (weights are built at
-                        # round/chunk entry), so the round's entry state
+                        # round entry), so the round's entry state
                         # was never left — replay is a fresh cohort draw
                         self._handle_quorum_failure(e, round_idx)
                         continue
                     self._round_retries = 0
-                    for result in results:
-                        history.append(result)
-                        # commit BEFORE _after_round: a save-cadence
-                        # snapshot's population sidecar must describe the
-                        # schedule INCLUDING this round
-                        self._commit_population(result.round_idx)
-                        self._after_round(result)
-                        self._tick_quarantine()
+                    history.append(result)
+                    # commit BEFORE _after_round: a save-cadence
+                    # snapshot's population sidecar must describe the
+                    # schedule INCLUDING this round
+                    self._commit_population(round_idx)
+                    self._after_round(result)
+                    self._tick_quarantine()
                     if self.perf is not None:
                         # the window closes AFTER the round's host-side
                         # bookkeeping so checkpoint/eval cost is captured
-                        self.perf.capture_after_round(
-                            round_idx + len(results) - 1
-                        )
-                    round_idx += len(results)
+                        self.perf.capture_after_round(round_idx)
+                    round_idx += 1
             if self.snapshots is not None:
                 self.snapshots.wait()  # settle async saves before handing back
         except BaseException as e:
             # forensics on EVERY failing exit path: an exception that never
             # reached a round-end health check (a dispatch error) still
-            # dumps the batch ring + chunk-entry state
+            # dumps the batch ring + round-entry state
             self._flightrec_on_exception(e)
             raise
         finally:
@@ -3691,9 +3383,8 @@ class Trainer:
             self._m_eps.set(eps)
             log["privacy.epsilon_spent"] = round(eps, 6)
         if self.perf is not None and self.perf.last_round is not None:
-            # the latest round/chunk digest rides the per-round record —
-            # the MFU trend fedrec-obs perf renders (a chunk's rounds all
-            # carry the chunk digest; num_rounds disambiguates in-trace)
+            # the latest round digest rides the per-round record — the
+            # MFU trend fedrec-obs perf renders
             log.update({
                 k: v for k, v in self.perf.last_round.items() if k != "round"
             })

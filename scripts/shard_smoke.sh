@@ -18,8 +18,7 @@
 #      now turns that flake into a retried bring-up.)
 #   2. SHARDED-TABLE STEP EQUALITY: the federated train step through
 #      the sharded catalog on the 8-device mesh must be BIT-IDENTICAL
-#      to the replicated-table step (the degenerate-config equality),
-#      per-batch AND rounds-in-jit.
+#      to the replicated-table step (the degenerate-config equality).
 #   3. FSDP STEP EQUALITY: a (clients=4, fsdp=2) mesh with the at-rest
 #      state sharded per the size-aware policy — step + round-end sync
 #      bit-identical to the 1-D replicated baseline, and the at-rest
@@ -248,10 +247,7 @@ from fedrec_tpu.parallel import client_mesh, fed_mesh, shard_batch
 from fedrec_tpu.shard import (
     ShardedNewsTable, fsdp_state_shardings,
 )
-from fedrec_tpu.train import (
-    build_fed_round_scan, build_fed_train_step, build_param_sync,
-    shard_round_batches, stack_rounds,
-)
+from fedrec_tpu.train import build_fed_train_step, build_param_sync
 from fedrec_tpu.train.state import init_client_state, replicate_state
 
 
@@ -302,7 +298,7 @@ def leaves(tree):
     return [np.asarray(x) for x in jax.tree_util.tree_leaves(tree)]
 
 
-# ---- leg 2: sharded catalog == dense, per-batch AND rounds-in-jit
+# ---- leg 2: sharded catalog == dense
 cfg = tiny_cfg(fed__num_clients=8)
 model, ts, st0, batch = setup(cfg)
 mesh = client_mesh(8)
@@ -322,24 +318,6 @@ np.testing.assert_array_equal(np.asarray(md["loss"]), np.asarray(ms["loss"]))
 for a, b in zip(leaves(sd.user_params), leaves(ss.user_params)):
     np.testing.assert_array_equal(a, b)
 print("STEP_EQUALITY_OK per-batch")
-
-rs_d = build_fed_round_scan(
-    model, cfg, get_strategy("param_avg"), mesh, mode="joint"
-)
-rs_s = build_fed_round_scan(
-    model, cfg, get_strategy("param_avg"), mesh, mode="joint",
-    sharded_table=tab.spec,
-)
-stacked = shard_round_batches(mesh, stack_rounds([[batch], [batch]]), cfg)
-w = jnp.ones((2, 8), jnp.float32)
-_, _, r0a, _ = setup(cfg)
-_, _, r0b, _ = setup(cfg)
-ra, ma = rs_d(r0a, stacked, jnp.asarray(ts), w)
-rb, mb = rs_s(r0b, stacked, tab.rows, w)
-np.testing.assert_array_equal(np.asarray(ma["loss"]), np.asarray(mb["loss"]))
-for a, b in zip(leaves(ra.user_params), leaves(rb.user_params)):
-    np.testing.assert_array_equal(a, b)
-print("STEP_EQUALITY_OK rounds-in-jit")
 
 # ---- leg 3: fsdp at-rest sharding == 1-D replicated baseline
 cfg_f = tiny_cfg(fed__num_clients=4)

@@ -492,10 +492,6 @@ def test_trainer_rejects_bad_agg_config(agg_data, tmp_path):
         "requires a strategy that syncs",
         **{"agg.mode": "async", "fed.strategy": "grad_avg"},
     )
-    expect(
-        "rounds_per_scan",
-        **{"agg.mode": "async", "train.rounds_per_scan": 2},
-    )
     # every CONCRETE codec composes with async now (entries are encoded
     # into the buffer); only the warmup-dependent "auto" stays rejected
     expect(

@@ -7,7 +7,7 @@ Pins the codec contracts end to end:
 * the numpy wire codec and the in-graph jnp twin implement the same
   arithmetic (same scales, same rounding, same top-k tie-break);
 * ``fed.dcn_compress='none'`` is bit-identical to the pre-codec round-end
-  sync, host-driven AND rounds-in-jit, and the coordinator's numpy
+  sync, and the coordinator's numpy
   aggregate path reconstructs exactly;
 * error feedback converges on a hand-checkable quadratic where plain
   sign-SGD/top-k stall;
@@ -413,16 +413,6 @@ def test_none_codec_bit_identical_host_driven():
     assert t0.registry.counter(
         "fed.dcn_bytes_up_total", labels=("path",)
     ).value(path="cohort") == 0.0
-
-
-@pytest.mark.slow  # jit-heavy; the host-driven variant pins the contract
-def test_none_codec_bit_identical_rounds_in_jit():
-    t0 = _codec_trainer("none", **{"train.rounds_per_scan": 2})
-    h0 = t0.run()
-    t1 = _codec_trainer("none", **{"train.rounds_per_scan": 2})
-    h1 = t1.run()
-    assert [r.train_loss for r in h0] == [r.train_loss for r in h1]
-    assert _params_equal(t0.state, t1.state)
 
 
 def test_sign1bit_trainer_banks_bytes_and_residual(tmp_path):

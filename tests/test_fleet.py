@@ -194,7 +194,7 @@ def test_critical_path_known_straggler():
 
 
 def test_critical_path_chunked_rounds_split_evenly():
-    # one rounds-in-jit chunk covering rounds 0-2 on worker 0 vs
+    # an older trace's one span covering rounds 0-2 on worker 0 vs
     # per-round spans on worker 1: every round still gets attributed
     chunk = _mk_trace(1000.0, [0], [0.03], num_rounds=3)
     per = _mk_trace(1000.0, [0, 1, 2], [0.002, 0.002, 0.002])

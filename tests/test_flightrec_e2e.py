@@ -1,7 +1,6 @@
 """Flight-recorder end-to-end: a forced-NaN Trainer run aborts via the
 numeric sentry, leaves a complete ``flightrec/`` dump (batch + state +
-manifest + registry snapshot) on the host-driven AND rounds-in-jit exit
-paths, an exception abort dumps too, and ``fedrec-obs replay``
+manifest + registry snapshot), an exception abort dumps too, and ``fedrec-obs replay``
 deterministically reproduces the non-finite step from the dump on CPU."""
 
 from __future__ import annotations
@@ -37,13 +36,12 @@ def fresh_obs():
         set_tracer(old_tr)
 
 
-def _nan_cfg(tmp_path, tag, rounds_per_scan=1):
+def _nan_cfg(tmp_path, tag):
     cfg = small_cfg()
-    cfg.model.text_encoder_mode = "head"  # joint mode (round-scan capable)
+    cfg.model.text_encoder_mode = "head"  # joint mode
     cfg.fed.strategy = "param_avg"
     cfg.fed.rounds = 2
     cfg.optim.user_lr = float("inf")  # first update goes non-finite
-    cfg.train.rounds_per_scan = rounds_per_scan
     cfg.train.snapshot_dir = str(tmp_path / f"snap_{tag}")
     cfg.train.save_every = 1000
     cfg.train.eval_every = 1000
@@ -71,12 +69,16 @@ def _assert_dump_complete(obs_dir):
     return man
 
 
-def test_host_driven_nan_dumps_and_replays(tmp_path, fresh_obs):
+def test_host_driven_nan_dumps_and_replays(tmp_path, fresh_obs, capsys):
     reg, _ = fresh_obs
     cfg = _nan_cfg(tmp_path, "host")
     _run_expect_abort(cfg)
     man = _assert_dump_complete(tmp_path / "obs_host")
     assert man["trigger"]["round"] == 0 and man["trigger"]["step"] == 0
+    # the round recorded its weights for replay's round-end sync, under
+    # the keys fedrec-obs replay reads
+    assert man["chunk_start_round"] == 0
+    assert man["weights"] == {"0": [1.0] * cfg.fed.num_clients}
     assert reg.counter("health.nonfinite_steps_total").value() > 0
     # the obs artifact trio was also written by the failing exit path
     for f in ("metrics.jsonl", "trace.json", "prometheus.txt"):
@@ -86,22 +88,10 @@ def test_host_driven_nan_dumps_and_replays(tmp_path, fresh_obs):
     from fedrec_tpu.cli.obs import main as obs_main
 
     assert obs_main(["replay", str(tmp_path / "obs_host")]) == 0
+    capsys.readouterr()  # drain earlier output before capturing the verdict
     assert obs_main(
         ["replay", str(tmp_path / "obs_host" / "flightrec"), "--json"]
     ) == 0
-
-
-def test_rounds_in_jit_nan_dumps_and_replays(tmp_path, fresh_obs, capsys):
-    cfg = _nan_cfg(tmp_path, "scan", rounds_per_scan=2)
-    _run_expect_abort(cfg)
-    man = _assert_dump_complete(tmp_path / "obs_scan")
-    # the chunk recorded per-round weights for replay's round-end syncs
-    assert set(man["weights"]) == {"0", "1"}
-
-    from fedrec_tpu.cli.obs import main as obs_main
-
-    capsys.readouterr()  # drain trainer output before capturing the verdict
-    assert obs_main(["replay", str(tmp_path / "obs_scan"), "--json"]) == 0
     verdict = json.loads(capsys.readouterr().out)
     assert verdict["reproduced_nonfinite"] is True
     assert verdict["first_nonfinite"]["round"] == man["trigger"]["round"]
@@ -114,7 +104,7 @@ def _device_lost(state, batch, table):
 
 def test_exception_abort_still_dumps(tmp_path, fresh_obs):
     """A mid-round abort that never reaches the health check (a dispatch
-    error) dumps the ring + chunk-entry state with kind=exception."""
+    error) dumps the ring + round-entry state with kind=exception."""
     cfg = small_cfg()
     cfg.model.text_encoder_mode = "head"
     cfg.fed.strategy = "param_avg"

@@ -432,56 +432,6 @@ def test_fused_step_trajectory_matches_dense():
     _assert_trees_match(st_d.news_params, st_f.news_params, 2e-4, 1e-4)
 
 
-def test_fused_round_scan_matches_host_loop():
-    """rounds_per_scan leg WITH fusion on: the rounds-in-jit program and
-    the host-driven per-batch loop run the identical fused step body, so
-    their trajectories must match step for step."""
-    from fedrec_tpu.train import (
-        build_fed_round_scan,
-        build_param_sync,
-        shard_round_batches,
-        stack_rounds,
-    )
-
-    cfg = small_cfg(
-        optim__user_lr=3e-3, optim__news_lr=3e-3, model__fuse_hot_path=True
-    )
-    _, batcher, toks, model, st0, mesh = make_setup(cfg, seed=0)
-    R, S = 2, 2
-    rounds = []
-    it = batcher.epoch_batches_sharded(8, 0)
-    for _ in range(R):
-        rounds.append([_batch_dict(next(it)) for _ in range(S)])
-
-    step = build_fed_train_step(
-        model, cfg, get_strategy("param_avg"), mesh, mode="joint"
-    )
-    sync = build_param_sync(cfg, mesh, get_strategy("param_avg"))
-    w = jnp.ones((8,), jnp.float32)
-    st_loop = st0
-    for r in rounds:
-        for b in r:
-            st_loop, _ = step(st_loop, shard_batch(mesh, b), toks)
-        st_loop = sync(st_loop, w)
-
-    _, _, _, _, st0b, _ = make_setup(cfg, seed=0)
-    round_scan = build_fed_round_scan(
-        model, cfg, get_strategy("param_avg"), mesh, mode="joint"
-    )
-    stacked = shard_round_batches(mesh, stack_rounds(rounds), cfg)
-    st_scan, _ = round_scan(
-        st0b, stacked, toks, jnp.ones((R, 8), jnp.float32)
-    )
-    _assert_trees_match(
-        st_loop.user_params, st_scan.user_params, 1e-5, 1e-6,
-        noise_bound=2e-4,
-    )
-    _assert_trees_match(
-        st_loop.news_params, st_scan.news_params, 1e-5, 1e-6,
-        noise_bound=2e-4,
-    )
-
-
 # ------------------------------------------------------------- VMEM model
 def test_fused_gather_vmem_model_fits_and_is_independent_of_unique():
     """The acceptance pin: the fused gather kernel's traced VMEM working

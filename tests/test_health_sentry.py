@@ -1,6 +1,7 @@
 """In-graph numeric sentry: the jitted step's health aux vector is present
-(and finite) on healthy runs across all three step builders, flags a
-forced non-finite update, carries the DP clip-rate, and vanishes when
+(and finite) on healthy runs, reaches the round's health check stacked
+over its steps, flags a forced non-finite update, carries the DP
+clip-rate, and vanishes when
 ``obs.health.sentry`` is off — with trajectories UNCHANGED by the aux."""
 
 from __future__ import annotations
@@ -12,12 +13,7 @@ import jax
 
 from fedrec_tpu.fed import get_strategy
 from fedrec_tpu.parallel import client_mesh, shard_batch
-from fedrec_tpu.train import (
-    build_fed_train_scan,
-    build_fed_train_step,
-    shard_scan_batches,
-    stack_batches,
-)
+from fedrec_tpu.train import build_fed_train_step
 
 from test_train import make_setup, small_cfg, _batch_dict
 
@@ -97,21 +93,40 @@ def test_forced_nonfinite_flags_every_client():
     assert np.all(np.isfinite(np.asarray(m["loss"])))
 
 
-def test_scan_builder_carries_health_stack():
+def test_round_health_rows_reach_the_check_as_steps_by_clients(tmp_path):
+    """A round's per-step health vectors reach ``_check_health`` one row a
+    step and the monitor as one round's (steps, clients) stack."""
+    from fedrec_tpu.train.trainer import Trainer
+
     cfg = small_cfg()
-    _, batcher, token_states, model, stacked, mesh = make_setup(cfg)
-    batches = []
-    for b in batcher.epoch_batches_sharded(8, 0):
-        batches.append(_batch_dict(b))
-        if len(batches) == 3:
-            break
-    scan = build_fed_train_scan(model, cfg, get_strategy("grad_avg"), mesh,
-                                mode="joint")
-    _, ms = scan(stacked, shard_scan_batches(mesh, stack_batches(batches), cfg),
-                 token_states)
+    cfg.model.text_encoder_mode = "head"
+    cfg.fed.strategy = "param_avg"
+    cfg.train.snapshot_dir = str(tmp_path / "snap")
+    cfg.train.eval_every = 1000
+    data, _, token_states, _, _, _ = make_setup(cfg, num_train=3 * 64, seed=0)
+    t = Trainer(cfg, data, np.asarray(token_states))
+    rows_seen, stacks_seen = [], []
+    check_health, monitor_check = t._check_health, t.health.check
+
+    def spy_rows(round_idx, health_rows=None, round_losses=()):
+        rows_seen.append(health_rows)
+        return check_health(round_idx, health_rows, round_losses)
+
+    def spy_stack(start_round, arrays, round_losses, **kw):
+        stacks_seen.append(arrays)
+        return monitor_check(start_round, arrays, round_losses, **kw)
+
+    t._check_health, t.health.check = spy_rows, spy_stack
+    t.train_round(0)
+    (rows,), (arrays,) = rows_seen, stacks_seen
+    assert len(rows) == 3 and all(HEALTH_KEYS <= set(r) for r in rows)
+    assert all(np.asarray(r["health.grad_norm"]).shape == (8,) for r in rows)
     for k in HEALTH_KEYS:
-        assert np.asarray(ms[k]).shape == (3, 8)  # (steps, clients)
-    assert np.asarray(ms["health.nonfinite"]).sum() == 0
+        assert arrays[k].shape == (1, 3, 8)  # one round of (steps, clients)
+    assert arrays["health.nonfinite"].sum() == 0
+    np.testing.assert_array_equal(
+        arrays["health.grad_norm"][0, 1], np.asarray(rows[1]["health.grad_norm"])
+    )
 
 
 def test_dpsgd_step_emits_clip_rate():

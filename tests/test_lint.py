@@ -92,7 +92,7 @@ def test_step_builders_are_traced_scopes():
     traced = trace_safety._collect_traced_functions(pf.tree, pf.lines)
     names = {getattr(f, "name", "") for f in traced}
     for expected in ("local_step", "sharded_step", "local_sync",
-                     "sharded_scan", "sharded_rounds"):
+                     "sharded_sync", "local_update", "sharded_update"):
         assert expected in names, (expected, sorted(names))
     rb = project.file("fedrec_tpu/fed/robust.py")
     rb_traced = trace_safety._collect_traced_functions(rb.tree, rb.lines)

@@ -1,6 +1,5 @@
-"""Trainer x observability: round spans nest correctly under
-``rounds_per_scan`` chunking AND in the host-driven loop, the DP
-accountant's ``privacy.epsilon_spent`` gauge tracks rounds, and the
+"""Trainer x observability: round spans nest correctly in the round
+loop, the DP accountant's ``privacy.epsilon_spent`` gauge tracks rounds, and the
 ``fedrec-obs`` report renders a real run's artifacts."""
 
 from __future__ import annotations
@@ -30,13 +29,11 @@ def fresh_obs():
         set_tracer(old_tr)
 
 
-def _run_trainer(tmp_path, tag, rounds_per_scan, rounds=2, privacy=False,
-                 prefetch=0):
+def _run_trainer(tmp_path, tag, rounds=2, privacy=False, prefetch=0):
     cfg = small_cfg(optim__user_lr=3e-3)
-    cfg.model.text_encoder_mode = "head"  # joint mode (round-scan capable)
+    cfg.model.text_encoder_mode = "head"  # joint mode
     cfg.fed.strategy = "param_avg"
     cfg.fed.rounds = rounds
-    cfg.train.rounds_per_scan = rounds_per_scan
     cfg.train.snapshot_dir = str(tmp_path / f"snap_{tag}")
     cfg.train.save_every = 1000
     cfg.train.eval_every = rounds  # one eval, on the final round
@@ -82,38 +79,32 @@ def _assert_children_nest(evs, expect_chunks):
 
 
 def test_round_spans_nest_host_driven(tmp_path, fresh_obs):
-    cfg = _run_trainer(tmp_path, "host", rounds_per_scan=1)
+    reg, _ = fresh_obs
+    cfg = _run_trainer(tmp_path, "host")
     evs = _trace_events(cfg)
     # one fed_round per round, each wrapping its own children
     _assert_children_nest(evs, expect_chunks=[(0, 1), (1, 1)])
     # the param_avg sync span shows up inside a round
     assert any(e["name"] == "aggregate" for e in evs)
-
-
-def test_round_spans_nest_under_rounds_per_scan(tmp_path, fresh_obs):
-    """The satellite pin: under rounds-in-jit chunking the chunk is ONE
-    fed_round span covering both rounds (step_num = first round,
-    num_rounds = chunk size), with batch_build/h2d/dispatch/eval nested
-    inside it — not round spans dangling outside the chunk."""
-    reg, _ = fresh_obs
-    cfg = _run_trainer(tmp_path, "scan", rounds_per_scan=2)
-    evs = _trace_events(cfg)
-    _assert_children_nest(evs, expect_chunks=[(0, 2)])
-    # the chunk dispatch span carries its shape
-    (chunk_dispatch,) = [
-        e for e in evs
-        if e["name"] == "dispatch" and e["args"].get("kind") == "round_chunk"
-    ]
-    assert chunk_dispatch["args"]["rounds"] == 2
-    # registry round accounting matches either dispatch mode
+    # one h2d and one dispatch span a step, with the attributes the
+    # benchmark's per-step metrics read: the step's kind and count, and the
+    # rows it encodes for the slots its batch gathers
+    dispatches = [e for e in evs if e["name"] == "dispatch"]
+    h2ds = [e for e in evs if e["name"] == "h2d"]
+    steps = reg.counter("train.steps_total").value()
+    assert len(dispatches) == len(h2ds) == steps > 0
+    assert all(e["args"]["n"] == 1 for e in h2ds)
+    slots = cfg.data.batch_size * (1 + cfg.data.npratio + cfg.data.max_his_len)
+    for e in dispatches:
+        assert e["args"]["kind"] == "step" and e["args"]["n"] == 1
+        assert 0 < e["args"]["rows"] <= e["args"]["slots"] == slots
     assert reg.counter("train.rounds_total").value() == 2
     assert reg.get("train.round_seconds").cell()["count"] == 2
 
 
 def test_epsilon_spent_gauge_tracks_rounds(tmp_path, fresh_obs):
     reg, _ = fresh_obs
-    cfg = _run_trainer(tmp_path, "dp", rounds_per_scan=1, privacy=True,
-                       prefetch=2)
+    cfg = _run_trainer(tmp_path, "dp", privacy=True, prefetch=2)
     # the gauge holds the final round's spend
     eps_final = reg.gauge("privacy.epsilon_spent").value()
     assert eps_final is not None and eps_final > 0

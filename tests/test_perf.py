@@ -322,7 +322,7 @@ def test_monitor_round_digest_no_peaks(fresh_obs):
     tr.add_span("batch_build", dur_s=0.30)
     tr.add_span("h2d", dur_s=0.10)
     tr.add_span("dispatch", dur_s=0.20)
-    out = mon.observe_round(0, 1, wall_s=1.0)
+    out = mon.observe_round(0, wall_s=1.0)
     assert out["perf.samples_per_sec"] == pytest.approx(
         4 * cfg.fed.num_clients * cfg.data.batch_size, rel=1e-6
     )
@@ -340,7 +340,7 @@ def test_monitor_round_digest_no_peaks(fresh_obs):
     mon.begin_round()
     steps.inc(4)
     tr.add_span("dispatch", dur_s=0.5)
-    assert mon.observe_round(1, 1, wall_s=0.6)["perf.verdict"] == "device"
+    assert mon.observe_round(1, wall_s=0.6)["perf.verdict"] == "device"
 
 
 def test_monitor_untraced_round_publishes_no_verdict(fresh_obs):
@@ -355,7 +355,7 @@ def test_monitor_untraced_round_publishes_no_verdict(fresh_obs):
     mon.begin_round()
     steps.inc(4)
     tr.add_span("batch_build", dur_s=0.4)  # dropped
-    out = mon.observe_round(0, 1, wall_s=1.0)
+    out = mon.observe_round(0, wall_s=1.0)
     assert "perf.verdict" not in out
     assert out["perf.samples_per_sec"] > 0  # wall-based gauges still land
     from fedrec_tpu.obs.report import snapshot_value
@@ -378,7 +378,7 @@ def test_monitor_mfu_with_chip_peaks_and_eval_exclusion(fresh_obs):
     steps.inc(8)
     tr.add_span("dispatch", dur_s=1.0)
     tr.add_span("eval", dur_s=1.0)
-    out = mon.observe_round(0, 1, wall_s=3.0)
+    out = mon.observe_round(0, wall_s=3.0)
     flops = 8 * cfg.fed.num_clients * flops_per_train_step(cfg, cfg.data.batch_size, 64)
     peak = peak_flops("TPU v4", cfg.model.dtype)
     # denominator is wall MINUS the eval span (2.0 s, not 3.0); the
@@ -442,16 +442,25 @@ def test_monitor_capture_window_and_pointer(fresh_obs, tmp_path):
     ) == 1.0
 
 
-def test_monitor_capture_intersects_chunk(fresh_obs, tmp_path):
-    """Under rounds-in-jit a chunk can stride over the window's start
-    round — intersection (not membership) must still open the window."""
+def test_monitor_capture_window_spans_its_rounds(fresh_obs, tmp_path):
+    """A window of several rounds opens at its first round, stays the one
+    open window through its last, and closes after it."""
     reg, tr = fresh_obs
-    _, mon = _mk_monitor(reg, tr, "cpu", tmp_path, capture_rounds="3:1")
-    assert mon.capture_before_round(0, num_rounds=2) is None  # [0,2) misses
-    logdir = mon.capture_before_round(2, num_rounds=3)  # [2,5) covers 3
-    assert logdir is not None
+    _, mon = _mk_monitor(reg, tr, "cpu", tmp_path, capture_rounds="3:2")
+    assert mon.capture_before_round(2) is None  # [3,5) does not hold 2
+    logdir = mon.capture_before_round(3)
+    assert logdir is not None and "perf_capture_r0003" in logdir
+    mon.capture_after_round(3)  # round 4 is still to come
+    assert mon.capture_before_round(4) is None  # no second window inside
     mon.capture_after_round(4)
     assert Path(logdir).exists()
+    (ptr,) = [
+        r for r in map(
+            json.loads, (tmp_path / "metrics.jsonl").read_text().splitlines()
+        ) if r.get("kind") == "perf_capture"
+    ]
+    assert ptr["round"] == 3 and ptr["last_round"] == 4
+    assert mon.capture_before_round(5) is None
 
 
 def test_monitor_efficiency_drop_trigger(fresh_obs, tmp_path):
@@ -463,11 +472,11 @@ def test_monitor_efficiency_drop_trigger(fresh_obs, tmp_path):
     for r in range(3):  # healthy rounds build the trailing mean
         mon.begin_round()
         steps.inc(4)
-        mon.observe_round(r, 1, wall_s=1.0)
+        mon.observe_round(r, wall_s=1.0)
         assert mon.capture_before_round(r + 1) is None or r < 2
     mon.begin_round()
     steps.inc(1)  # 4x slower round -> > 50% below trailing mean
-    mon.observe_round(3, 1, wall_s=1.0)
+    mon.observe_round(3, wall_s=1.0)
     logdir = mon.capture_before_round(4)
     assert logdir is not None
     mon.capture_after_round(4)
@@ -502,7 +511,7 @@ def test_perf_detail_report_and_cli(fresh_obs, tmp_path, capsys):
     mon.begin_round()
     steps.inc(4)
     tr.add_span("dispatch", dur_s=0.4)
-    out = mon.observe_round(0, 1, wall_s=0.5)
+    out = mon.observe_round(0, wall_s=0.5)
     mon.cost(_FakeJitted({"flops": 1e9, "bytes accessed": 5e8}), (), {},
              "train_step")
     live_array_components({"params": {}}, registry=reg)
@@ -539,7 +548,7 @@ def test_fleet_report_carries_perf(fresh_obs, tmp_path):
     mon.begin_round()
     steps.inc(4)
     tr.add_span("dispatch", dur_s=0.4)
-    mon.observe_round(0, 1, wall_s=0.5)
+    mon.observe_round(0, wall_s=0.5)
     obs = _write_obs_dir(tmp_path, reg)
     (obs / "trace.json").write_text(json.dumps(tr.to_chrome()))
     workers = load_fleet_dir(obs)

@@ -399,23 +399,18 @@ def test_dpsgd_rejected_in_decoupled_mode():
         build_fed_train_step(model, cfg, get_strategy("param_avg"), mesh, mode="decoupled")
 
 
-def test_dpsgd_user_scope_under_cohorts_and_scan():
-    """The round-5 combinations nobody pinned: per-example DP-SGD with
-    dp_scope='user' must produce IDENTICAL results (a) packed as in-device
-    cohorts (8 clients on 4 devices, k=2) vs one-client-per-device, and
-    (b) dispatched per-batch vs inside the epoch-in-jit lax.scan. All four
+def test_dpsgd_user_scope_under_cohorts():
+    """The round-5 combination nobody pinned: per-example DP-SGD with
+    dp_scope='user' must produce IDENTICAL results packed as in-device
+    cohorts (8 clients on 4 devices, k=2) vs one-client-per-device. Both
     programs share _build_local_step, so divergence = a wiring bug in the
-    cohort vmap or scan carry, not the mechanism."""
-    from tests.test_scan import _collect_batches
-    from tests.test_train import make_setup, small_cfg
+    cohort vmap, not the mechanism."""
+    from itertools import islice
+
+    from tests.test_train import _batch_dict, make_setup, small_cfg
     from fedrec_tpu.fed import get_strategy
     from fedrec_tpu.parallel import client_mesh, shard_batch
-    from fedrec_tpu.train import (
-        build_fed_train_scan,
-        build_fed_train_step,
-        shard_scan_batches,
-        stack_batches,
-    )
+    from fedrec_tpu.train import build_fed_train_step
 
     cfg = small_cfg(model__dropout_rate=0.0)
     cfg.data.batch_size = 8
@@ -426,7 +421,9 @@ def test_dpsgd_user_scope_under_cohorts_and_scan():
     cfg.privacy.clip_norm = 0.5   # active clipping: exercises the bound
     cfg.privacy.sigma = 1e-12     # deterministic comparison across packings
     _, batcher, token_states, model, stacked0, _ = make_setup(cfg, seed=0)
-    batches = _collect_batches(batcher, 8, 3)
+    batches = [
+        _batch_dict(b) for b in islice(batcher.epoch_batches_sharded(8, 0), 3)
+    ]
 
     results = {}
     for tag, max_dev in (("flat", 8), ("cohort", 4)):
@@ -450,24 +447,3 @@ def test_dpsgd_user_scope_under_cohorts_and_scan():
         jax.tree_util.tree_leaves(results["cohort"]),
     ):
         np.testing.assert_allclose(a, bp, rtol=2e-4, atol=1e-6)
-
-    # (b) epoch-in-jit: the scan program equals the per-batch loop
-    mesh = client_mesh(8)
-    scan = build_fed_train_scan(
-        model, cfg, get_strategy("grad_avg"), mesh, mode="joint"
-    )
-    _, _, _, _, st_scan, _ = make_setup(cfg, seed=0)
-    st_scan, _ms = scan(
-        st_scan, shard_scan_batches(mesh, stack_batches(batches), cfg),
-        token_states,
-    )
-    for a, bp in zip(
-        jax.tree_util.tree_leaves(results["flat"]),
-        jax.tree_util.tree_leaves(st_scan.user_params),
-    ):
-        np.testing.assert_allclose(a, np.asarray(bp), rtol=2e-4, atol=1e-6)
-    for a, bp in zip(
-        jax.tree_util.tree_leaves(stacked0.news_params),
-        jax.tree_util.tree_leaves(st_scan.news_params),
-    ):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(bp))

@@ -2,7 +2,7 @@
 
 Pins the degenerate contract (fsdp=1 builds the exact 1-D mesh and
 programs), the 3-round trajectory equality of fsdp>1 against the
-replicated baseline in host-driven AND rounds-in-jit dispatch, the
+replicated baseline, the
 at-rest residency actually shrinking, and the sharded-checkpoint
 round-trip (save gathers, restore re-commits, resume is bit-identical).
 """
@@ -165,16 +165,12 @@ def _run(cfg, data, ts):
     )
 
 
-@pytest.mark.parametrize("dispatch", ["host", "rounds_in_jit"])
-def test_trainer_fsdp_trajectory_matches_replicated(dispatch):
+def test_trainer_fsdp_trajectory_matches_replicated():
     """The acceptance pin: 3-round fsdp=2 trajectory bit-identical to the
-    replicated baseline, host-driven AND rounds-in-jit."""
-    extra = {} if dispatch == "host" else {"train__rounds_per_scan": 3}
-    cfg_b, data, ts = _tiny_trainer(**extra)
+    replicated baseline."""
+    cfg_b, data, ts = _tiny_trainer()
     base = _run(cfg_b, data, ts)
-    cfg_f, _, _ = _tiny_trainer(
-        shard__fsdp=2, shard__fsdp_min_size_mb=0.0, **extra
-    )
+    cfg_f, _, _ = _tiny_trainer(shard__fsdp=2, shard__fsdp_min_size_mb=0.0)
     fsdp = _run(cfg_f, data, ts)
     assert base[0] == fsdp[0], (base[0], fsdp[0])
     for a, b in zip(base[1], fsdp[1]):

@@ -3,8 +3,8 @@
 The acceptance pins: the owner-bucketed all_to_all gather is BIT-IDENTICAL
 to the dense ``full_table[ids]``, per-device rows equal
 ``total_rows / shards``, and the sharded-table train step matches the
-replicated-table step bitwise in all three dispatch modes (per-batch,
-epoch-scan, rounds-in-jit) — plus the build-time guards, the serving
+replicated-table step bitwise, round-end sync included — plus the
+build-time guards, the serving
 store's sharded mode, and the report's Sharding section.
 """
 
@@ -27,15 +27,7 @@ from fedrec_tpu.shard.table import (
     a2a_bytes_per_gather,
     owner_bucketed_gather,
 )
-from fedrec_tpu.train import (
-    build_fed_round_scan,
-    build_fed_train_scan,
-    build_fed_train_step,
-    shard_round_batches,
-    shard_scan_batches,
-    stack_batches,
-    stack_rounds,
-)
+from fedrec_tpu.train import build_fed_train_step, build_param_sync
 
 from fedrec_tpu.train.step import NEWS_ROWS, host_news_dedup
 
@@ -105,8 +97,8 @@ def test_a2a_bytes_model():
     )
 
 
-# ----------------------------------- step equality, all three dispatch modes
-def test_sharded_step_bitwise_equals_dense_all_dispatch_modes():
+# ------------------------------------------------------------ step equality
+def test_sharded_step_bitwise_equals_dense():
     cfg = small_cfg(
         model__text_encoder_mode="head", optim__user_lr=3e-3,
         optim__news_lr=3e-3,
@@ -119,7 +111,6 @@ def test_sharded_step_bitwise_equals_dense_all_dispatch_modes():
         if len(batches) >= 2:
             break
 
-    # per-batch
     step_d = build_fed_train_step(
         model, cfg, get_strategy("param_avg"), mesh, mode="joint"
     )
@@ -138,41 +129,12 @@ def test_sharded_step_bitwise_equals_dense_all_dispatch_modes():
     _assert_trees_equal(st_d.user_params, st_s.user_params)
     _assert_trees_equal(st_d.news_params, st_s.news_params)
 
-    # epoch-scan
-    scan_d = build_fed_train_scan(
-        model, cfg, get_strategy("param_avg"), mesh, mode="joint"
+    # the round-end weighted sync on top of either table
+    sync = build_param_sync(cfg, mesh, get_strategy("param_avg"))
+    w = jnp.asarray([1, 1, 0, 1, 1, 1, 0, 1], jnp.float32)
+    _assert_trees_equal(
+        sync(st_d, w).user_params, sync(st_s, w).user_params
     )
-    scan_s = build_fed_train_scan(
-        model, cfg, get_strategy("param_avg"), mesh, mode="joint",
-        sharded_table=tab.spec,
-    )
-    stacked = shard_scan_batches(mesh, stack_batches(batches), cfg)
-    sd, mdd = scan_d(make_setup(cfg, seed=0)[4], stacked, token_states)
-    ss, mss = scan_s(make_setup(cfg, seed=0)[4], stacked, tab.rows)
-    np.testing.assert_array_equal(
-        np.asarray(mdd["loss"]), np.asarray(mss["loss"])
-    )
-    _assert_trees_equal(sd.user_params, ss.user_params)
-
-    # rounds-in-jit (incl. the round-end weighted sync)
-    rs_d = build_fed_round_scan(
-        model, cfg, get_strategy("param_avg"), mesh, mode="joint"
-    )
-    rs_s = build_fed_round_scan(
-        model, cfg, get_strategy("param_avg"), mesh, mode="joint",
-        sharded_table=tab.spec,
-    )
-    rounds = shard_round_batches(
-        mesh, stack_rounds([batches[:1], batches[1:2]]), cfg
-    )
-    w = jnp.ones((2, 8), jnp.float32)
-    rd, mrd = rs_d(make_setup(cfg, seed=0)[4], rounds, token_states, w)
-    rs, mrs = rs_s(make_setup(cfg, seed=0)[4], rounds, tab.rows, w)
-    np.testing.assert_array_equal(
-        np.asarray(mrd["loss"]), np.asarray(mrs["loss"])
-    )
-    _assert_trees_equal(rd.user_params, rs.user_params)
-    _assert_trees_equal(rd.news_params, rs.news_params)
 
 
 def test_sharded_step_composes_with_chunk_and_host_dedup():

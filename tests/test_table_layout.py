@@ -262,23 +262,16 @@ def test_the_stated_format_changes_no_bit_of_a_joint_step():
     assert tuple(stated.layout.major_to_minor) == ROW_MAJOR
 
 
-@pytest.mark.parametrize("form,over", [
-    ("train_step", {}),
-    ("train_scan", {"train__scan_steps": 2}),
-    ("round_scan", {"train__rounds_per_scan": 2, "fed__rounds": 4}),
-])
-def test_each_dispatch_form_compiles_once_over_two_calls(form, over):
-    cfg = tiny_cfg("head", **over)
+@pytest.mark.parametrize("form", ["train_step", "param_sync"])
+def test_each_program_of_a_round_compiles_once_over_two_rounds(form):
+    cfg = tiny_cfg("head")
     data, states = tiny_inputs(cfg)
     trainer = Trainer(cfg, data, states)
     program = getattr(trainer, form)
     calls = []
     setattr(trainer, form, lambda *a: calls.append(1) or program(*a))
-    if form == "round_scan":
-        trainer.run()                       # two chunks of two rounds
-    else:
-        trainer.train_round(0)
-        trainer.train_round(1)
+    trainer.train_round(0)
+    trainer.train_round(1)
     assert len(calls) >= 2
     assert program.__wrapped__._cache_size() == 1
 
