@@ -839,6 +839,14 @@ class Trainer:
             buckets=(0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 25.0, 50.0,
                      100.0, 250.0, 500.0, 1000.0),
         )
+        self._m_round_end_secs = self.registry.histogram(
+            "train.round_end_seconds",
+            "host seconds a round's end takes once its last device program "
+            "is done (the round_end span): the one read of the steps' "
+            "metrics, the loss, the health digest, the routing counters",
+            buckets=(0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25,
+                     0.5, 1.0),
+        )
         self._g_encode_rows = self.registry.gauge(
             "train.encode_rows",
             "news rows a client-step gathers and encodes: the size R the "
@@ -1859,18 +1867,18 @@ class Trainer:
         health_rows: list[dict] | None = None,
         round_losses: tuple | list = (),
     ) -> None:
-        """Digest one round's fetched sentry arrays through the
-        HealthMonitor; on a trigger, dump the flight recorder and (for a
-        non-finite sentinel under abort_on_nonfinite) raise
-        TrainingHealthError. One sync point per round — the arrays were
-        produced asynchronously alongside the loss readback."""
+        """Digest one round's sentry arrays through the HealthMonitor; on
+        a trigger, dump the flight recorder and (for a non-finite sentinel
+        under abort_on_nonfinite) raise TrainingHealthError.
+        ``health_rows`` are HOST arrays, one dict a step: the round's end
+        gathered them with the losses in its one read."""
         if not self.cfg.obs.health.sentry:
             return
         if not health_rows:
             return
         # one (clients,) row a step -> the monitor's (rounds, steps, clients)
         arrays = {
-            k: np.stack([np.asarray(r[k]) for r in health_rows])[None]
+            k: np.stack([r[k] for r in health_rows])[None]
             for k in health_rows[0]
         }
         trigger = self.health.check(
@@ -2639,27 +2647,38 @@ class Trainer:
                 np.asarray, self._client0_params()
             )
 
-        losses = []
-        raw_losses = []  # per-client loss cells: the NaN-robust fallback
+        # what the round's end reads of every step's metrics, as device
+        # arrays on their way to the host: the in-graph mean loss, the
+        # per-client loss cells (the NaN-robust fallback), the sentry aux
+        # vectors and a sparse-expert trunk's routing counters
+        losses: list = []
+        kept = {
+            "losses": losses, "raw_losses": [],
+            "health_rows": [], "routing_rows": [],
+        }
         # each host-deduped step's largest distinct count over its clients
         distinct: list[int] = []
-        # sentry aux vectors, same deal: appended as device arrays, one
-        # host fetch at the round-end health check
-        health_rows: list[dict] = []
-        routing_rows: list[dict] = []  # sparse-expert trunk counters, same deal
 
         tracer = self.tracer
 
         def keep_metrics(metrics) -> None:
             losses.append(metrics["mean_loss"])
-            raw_losses.append(metrics["loss"])
+            kept["raw_losses"].append(metrics["loss"])
+            started = [metrics["mean_loss"], metrics["loss"]]
             row = {k: v for k, v in metrics.items() if k.startswith("health.")}
             if row:
-                health_rows.append(row)
+                kept["health_rows"].append(row)
+                started.extend(row.values())
             if self._m_expert_tokens is not None:
-                routing_rows.append(
-                    {k: v for k, v in metrics.items() if k.startswith("moe.")}
-                )
+                row = {k: v for k, v in metrics.items() if k.startswith("moe.")}
+                kept["routing_rows"].append(row)
+                started.extend(row.values())
+            # a step's few hundred bytes leave the chip behind the step,
+            # while the next steps run: nothing waits here, nothing is
+            # enqueued on the device, and the round's end finds them on
+            # the host
+            for leaf in started:
+                leaf.copy_to_host_async()
 
         if self._host_dedup and self._encode_rows is None:
             self._choose_encode_rows(round_idx * cfg.fed.local_epochs)
@@ -2788,35 +2807,47 @@ class Trainer:
             elif self.mode == "decoupled":
                 self._refresh_table()
 
-        # the round's loss: the flat mean over every (step, client) cell
-        train_loss = self._round_loss_mean(
-            np.stack([np.asarray(l) for l in losses]),
-            np.stack([np.asarray(l) for l in raw_losses]),
-        )
-        # sentry digest FIRST: a non-finite sentinel is the root cause the
-        # operator needs (and dumps the flight recorder) before any other
-        # abort gets to describe the same broken round differently
-        self._check_health(
-            round_idx, health_rows=health_rows, round_losses=[train_loss]
-        )
-        full_size = sum(most > self._encode_rows for most in distinct)
-        if full_size:
-            # those steps were served exactly, at the full size; the traffic
-            # has outgrown R, so the next round's is chosen from this one's
-            self._m_full_size_steps.inc(full_size)
-            self._set_encode_rows(max(distinct))
-        if routing_rows:
-            self._publish_routing(routing_rows)
+        # the round's last device programs (the sync or the last step, and
+        # a decoupled round's table refresh): that wait is the device's
+        jax.block_until_ready((self.state, self._table))
+        # the round's end, the host's alone: the chip waits for it
+        t_end = tracer.now()
+        with tracer.span(
+            "round_end", arrays=len(jax.tree_util.tree_leaves(kept)), reads=1
+        ):
+            # ONE read: every step but the last sent its arrays long ago
+            host = jax.device_get(kept)
+            # the round's loss: the flat mean over every (step, client) cell
+            train_loss = self._round_loss_mean(
+                np.stack(host["losses"]), np.stack(host["raw_losses"])
+            )
+            # sentry digest FIRST: a non-finite sentinel is the root cause
+            # the operator needs (and dumps the flight recorder) before any
+            # other abort gets to describe the same broken round differently
+            self._check_health(
+                round_idx, health_rows=host["health_rows"],
+                round_losses=[train_loss],
+            )
+            full_size = sum(most > self._encode_rows for most in distinct)
+            if full_size:
+                # those steps were served exactly, at the full size; the
+                # traffic has outgrown R, so the next round's is chosen from
+                # this one's
+                self._m_full_size_steps.inc(full_size)
+                self._set_encode_rows(max(distinct))
+            if host["routing_rows"]:
+                self._publish_routing(host["routing_rows"])
+        self._m_round_end_secs.observe(tracer.now() - t_end)
         result = RoundResult(round_idx, train_loss)
         self._eval_if_due(result)
         return result
 
     def _publish_routing(self, rows: list[dict]) -> None:
-        """The round's routing counters to the registry: one host fetch of
-        what the steps returned. ``moe.expert_tokens`` is (..., layers,
-        held) per step entry, leading axes steps and clients."""
+        """The round's routing counters to the registry, from the host
+        arrays the round's end gathered. ``moe.expert_tokens`` is (...,
+        layers, held) per step entry, leading axes steps and clients."""
         tokens = np.concatenate([
-            np.asarray(r["moe.expert_tokens"]).reshape(
+            r["moe.expert_tokens"].reshape(
                 (-1,) + r["moe.expert_tokens"].shape[-2:]
             )
             for r in rows
@@ -2825,7 +2856,7 @@ class Trainer:
         for (layer, expert), n in np.ndenumerate(tokens.sum(axis=0)):
             self._m_expert_tokens.inc(float(n), layer=layer, expert=first + expert)
         self._g_absent_share.set(float(np.mean(
-            [np.mean(np.asarray(r["moe.absent_share"])) for r in rows]
+            [np.mean(r["moe.absent_share"]) for r in rows]
         )))
         self._g_expert_load.set(float(np.mean(
             tokens.max(axis=-1) / np.maximum(tokens.mean(axis=-1), 1e-9)

@@ -17,7 +17,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from fedrec_tpu.obs import MetricsRegistry, Tracer, set_registry, set_tracer
+from fedrec_tpu.obs import (
+    MetricsRegistry,
+    Tracer,
+    TrainingHealthError,
+    set_registry,
+    set_tracer,
+)
+from fedrec_tpu.obs.health import HealthMonitor
 from fedrec_tpu.parallel import client_mesh, shard_fed_batch
 from fedrec_tpu.train import (
     build_fed_train_step,
@@ -25,7 +32,7 @@ from fedrec_tpu.train import (
     build_param_sync,
     encode_all_news,
 )
-from fedrec_tpu.train.trainer import Trainer
+from fedrec_tpu.train.trainer import RoundRecovery, Trainer
 
 from test_train import _batch_dict, make_setup, small_cfg
 
@@ -46,8 +53,11 @@ def _trainer(tmp_path, tag="t", num_train=128, mesh=None, **over):
         train__snapshot_dir=str(tmp_path / tag),
     )
     for k, v in over.items():
-        section, key = k.split("__")
-        setattr(getattr(cfg, section), key, v)
+        *sections, key = k.split("__")
+        node = cfg
+        for section in sections:
+            node = getattr(node, section)
+        setattr(node, key, v)
     data, _, token_states, _, _, _ = make_setup(cfg, num_train=num_train, seed=0)
     return Trainer(cfg, data, np.asarray(token_states), mesh=mesh)
 
@@ -110,14 +120,17 @@ def _assert_round_losses(ref_losses, results):
     )
 
 
-def _step_losses(t):
-    """Capture every train step's ``mean_loss`` row as ``t`` dispatches it."""
+def _step_metrics(t, plant=None):
+    """Capture every train step's whole ``metrics`` (device arrays) as ``t``
+    dispatches it; ``plant(call_index, metrics)`` may replace them first."""
     rows = []
     inner = t.train_step
 
     def recording(state, batch, table):
         state, metrics = inner(state, batch, table)
-        rows.append(metrics["mean_loss"])
+        if plant is not None:
+            metrics = plant(len(rows), metrics)
+        rows.append(metrics)
         return state, metrics
 
     t.train_step = recording
@@ -145,9 +158,9 @@ def test_train_round_matches_hand_written_loop(tmp_path, strategy, max_dev, user
         fed__strategy=strategy, **over,
     )
     ref_losses, ref_state = _reference(t, rounds=2)
-    rows = _step_losses(t)
+    rows = _step_metrics(t)
     results = [t.train_round(r) for r in range(2)]
-    got = np.stack([np.asarray(x) for x in rows])
+    got = np.stack([np.asarray(m["mean_loss"]) for m in rows])
     np.testing.assert_allclose(
         np.concatenate(ref_losses), got, rtol=1e-5, atol=1e-6
     )
@@ -236,10 +249,10 @@ def test_seq_parallel_round(tmp_path):
     )
     assert not t._host_dedup
     ref_losses, ref_state = _reference(t, rounds=1)
-    rows = _step_losses(t)
+    rows = _step_metrics(t)
     t.train_round(0)
     np.testing.assert_allclose(
-        ref_losses[0], np.stack([np.asarray(x) for x in rows]),
+        ref_losses[0], np.stack([np.asarray(m["mean_loss"]) for m in rows]),
         rtol=1e-6, atol=1e-7,
     )
     _assert_state_matches(ref_state, t)
@@ -295,3 +308,186 @@ def test_run_same_with_and_without_prefetch(tmp_path):
     listing = sorted(p.name for p in (tmp_path / "inline").iterdir())
     assert "1" in listing and "3" in listing
     assert listing == sorted(p.name for p in (tmp_path / "prefetch").iterdir())
+
+
+# ------------------------------------------------------------ the round's end
+def _per_array_round_end(rows):
+    """The round's end as the round loop read it before PR 32, written out:
+    one ``np.asarray`` a device array, stacked over the steps. Returns the
+    round's loss and the monitor's ``(1, steps, clients)`` health arrays."""
+    mean_cells = np.stack([np.asarray(m["mean_loss"]) for m in rows]).reshape(-1)
+    loss_cells = np.stack([np.asarray(m["loss"]) for m in rows]).reshape(-1)
+    if np.isfinite(mean_cells).all():
+        loss = float(mean_cells.mean())
+    else:
+        finite = loss_cells[np.isfinite(loss_cells)]
+        loss = float(finite.mean()) if finite.size else float("nan")
+    health = [{k: v for k, v in m.items() if k.startswith("health.")} for m in rows]
+    arrays = {
+        k: np.stack([np.asarray(r[k]) for r in health])[None] for k in health[0]
+    }
+    return loss, arrays
+
+
+def _health_instruments(registry):
+    return {
+        name: m["values"]
+        for name, m in registry.snapshot()["metrics"].items()
+        if name.startswith("health.")
+    }
+
+
+def _round_end_spans(t):
+    return [e for e in t.tracer.events() if e.get("name") == "round_end"]
+
+
+@pytest.mark.parametrize("strategy,max_dev,user_tower", [
+    ("param_avg", 8, "attn"), ("param_avg", 4, "attn"), ("grad_avg", 8, "attn"),
+    ("grad_avg", 4, "attn"), ("local", 8, "attn"), ("param_avg", 4, "gru"),
+])
+def test_round_end_in_one_read_equals_the_per_array_reads(
+    tmp_path, strategy, max_dev, user_tower
+):
+    """Two rounds: ``RoundResult.train_loss`` and every ``health.*``
+    instrument equal, bit for bit, what one ``np.asarray`` a device array
+    gives on the same step outputs, digested by a monitor of its own."""
+    over = {} if user_tower == "attn" else {"model__user_tower": user_tower}
+    t = _trainer(
+        tmp_path, mesh=client_mesh(8, max_devices=max_dev),
+        fed__strategy=strategy, **over,
+    )
+    rows = _step_metrics(t)
+    ref = HealthMonitor(t.cfg.obs.health, MetricsRegistry())
+    for r in range(2):
+        del rows[:]
+        result = t.train_round(r)
+        loss, arrays = _per_array_round_end(rows)
+        assert result.train_loss == loss            # the same float
+        assert ref.check(r, arrays, [loss]) is None
+        got, want = _health_instruments(t.registry), _health_instruments(ref.registry)
+        # the histograms hold every (step, client) cell, the gauge the last
+        assert want["health.grad_norm"][0]["count"] == (r + 1) * len(rows) * 8
+        assert want["health.param_norm"][0]["value"] > 0
+        assert got == want
+
+
+def _spy_host_rows(t, monkeypatch):
+    """Record the leaf types ``_check_health`` and ``_publish_routing`` are
+    handed: what they read must already be on the host."""
+    seen = {"health": [], "routing": []}
+    check, publish = t._check_health, t._publish_routing
+
+    def check_health(round_idx, health_rows=None, round_losses=()):
+        seen["health"].append([type(v) for row in health_rows for v in row.values()])
+        return check(round_idx, health_rows=health_rows, round_losses=round_losses)
+
+    def publish_routing(rows):
+        seen["routing"].append([type(v) for row in rows for v in row.values()])
+        return publish(rows)
+
+    monkeypatch.setattr(t, "_check_health", check_health)
+    monkeypatch.setattr(t, "_publish_routing", publish_routing)
+    return seen
+
+
+def _sparse_trunk_trainer(tmp_path):
+    from test_sparse_trunk import trunk_cfg, trunk_data
+
+    set_registry(MetricsRegistry())
+    set_tracer(Tracer())
+    cfg = trunk_cfg(1)
+    return Trainer(cfg, trunk_data(cfg), None, mesh=client_mesh(1, max_devices=1))
+
+
+@pytest.mark.parametrize("make,kept_keys", [
+    (lambda tmp: _trainer(tmp), 6),                   # two losses + four health.*
+    (lambda tmp: _trainer(tmp, fed__strategy="grad_avg",
+                          mesh=client_mesh(8, max_devices=4)), 6),
+    (_sparse_trunk_trainer, 8),                       # and the two moe.*
+], ids=["param_avg", "grad_avg-cohorts", "sparse-expert-trunk"])
+def test_every_round_ends_in_one_read_of_host_arrays(tmp_path, monkeypatch, make, kept_keys):
+    """Every round emits exactly one ``round_end`` span, inside its
+    ``fed_round``, with ``reads == 1`` and ``arrays == steps x kept keys``;
+    the health digest and the routing counters are handed ``numpy.ndarray``
+    leaves only (no read of a device array is left to them); the histogram
+    ``train.round_end_seconds`` is observed once a round."""
+    t = make(tmp_path)
+    seen = _spy_host_rows(t, monkeypatch)
+    steps = _step_metrics(t)
+    rounds = 2
+    for r in range(rounds):
+        t.train_round(r)
+    per_round = len(steps) // rounds
+    spans = _round_end_spans(t)
+    assert [e["args"] for e in spans] == [
+        {"arrays": per_round * kept_keys, "reads": 1}
+    ] * rounds
+    fed_rounds = [e for e in t.tracer.events() if e.get("name") == "fed_round"]
+    for end, whole in zip(spans, fed_rounds):
+        assert whole["ts"] <= end["ts"]
+        assert end["ts"] + end["dur"] <= whole["ts"] + whole["dur"]
+    assert len(seen["health"]) == rounds
+    for kinds in seen["health"] + seen["routing"]:
+        assert kinds and set(kinds) == {np.ndarray}
+    assert len(seen["routing"]) == (rounds if kept_keys == 8 else 0)
+    hist = t.registry.snapshot()["metrics"]["train.round_end_seconds"]["values"]
+    assert [cell["count"] for cell in hist] == [rounds]
+
+
+def _nonfinite(metrics, client):
+    """``metrics`` with a non-finite loss for ``client``: the cell, the
+    in-graph mean it poisons, and the sentry's flag."""
+    return {
+        **metrics,
+        "loss": metrics["loss"].at[client].set(jnp.nan),
+        "mean_loss": jnp.full_like(metrics["mean_loss"], jnp.nan),
+        "health.nonfinite": metrics["health.nonfinite"].at[client].set(1),
+    }
+
+
+@pytest.mark.parametrize("recover,raised", [
+    (False, TrainingHealthError), (True, RoundRecovery),
+], ids=["abort", "recover"])
+def test_planted_nonfinite_loss_raises_in_its_own_round(tmp_path, recover, raised):
+    """A non-finite loss planted at step 2 of round 1 is digested in round
+    1: ``train_round(1)`` itself raises (``TrainingHealthError``, or
+    ``RoundRecovery`` under ``fed.robust.recover``), naming the step and
+    the client, not a round later; round 0 ends clean."""
+    t = _trainer(tmp_path, num_train=256, fed__rounds=3, fed__robust__recover=recover)
+    at = {}
+    steps = _step_metrics(
+        t, lambda i, m: _nonfinite(m, client=3) if i == at.get("call") else m
+    )
+    t.train_round(0)
+    per_round = len(steps)
+    assert per_round > 3                            # step 2 is not the round's last
+    at["call"] = per_round + 2
+    with pytest.raises(raised) as err:
+        t.train_round(1)
+    assert len(steps) == 2 * per_round              # the whole round ran first
+    if recover:
+        trigger = err.value.trigger
+        assert (trigger["kind"], trigger["round"], trigger["step"], trigger["client"]) \
+            == ("nonfinite", 1, 2, 3)
+    else:
+        assert "[nonfinite] at round 1 step 2 client 3" in str(err.value)
+    assert t.registry.counter("health.nonfinite_steps_total").value() == 1
+    # the raising round closed its span with the error's name on it
+    assert _round_end_spans(t)[-1]["args"]["error"] == raised.__name__
+
+
+def test_step_metrics_are_what_the_round_end_reads(tmp_path):
+    """The step's program is not this loop's to change: under the paper's
+    options (8 clients, ``param_avg``, joint mode, bfloat16, sentry on) the
+    lowered step returns the six arrays the round's end gathers, one float32
+    (clients,) vector each and the int32 sentinel, and nothing else."""
+    t = _trainer(tmp_path, model__dtype="bfloat16")
+    rows = _step_metrics(t)
+    t.train_round(0)
+    avals = {k: (v.shape, str(v.dtype)) for k, v in rows[0].items()}
+    vec = ((8,), "float32")
+    assert avals == {
+        "loss": vec, "mean_loss": vec, "health.grad_norm": vec,
+        "health.update_norm": vec, "health.param_norm": vec,
+        "health.nonfinite": ((8,), "int32"),
+    }
