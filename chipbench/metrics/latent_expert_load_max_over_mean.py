@@ -1,0 +1,16 @@
+"""How uneven the latent trunk's routing is: the tokens on the fullest held
+expert of a routed layer over the mean of the held experts, averaged over
+the last round's steps, clients and layers, in per cent (100 = even). The
+program counts the (token, choice) pairs on each held expert inside the step
+and publishes the ratio as the gauge ``moe.expert_load_max_over_mean``
+(``obs/registry.py``), as for the sparse-expert trunk. A property of the
+first weights and the traffic (the router's selection bias moves it: 240 to
+370 with biases of normal(0, 0.1), 140 to 175 at 0.02), not of the chip: the
+grouped products' tiles and the worst-case gathers follow the fullest
+expert. Source: program counter. Layer: latent trunk. Moves
+``train_samples_per_s``."""
+
+
+def read(run: dict):
+    routing = run.get("routing")
+    return None if not routing else 100.0 * routing["load_max_over_mean"]

@@ -97,12 +97,23 @@ class ModelConfig:
     #                     trunk_heads, trunk_ffn (one expert's width) and
     #                     trunk_vocab (vocabulary rows held) are read from
     #                     here, as for distilbert)
+    #   "latent_moe"    — causal decoder with latent (low-rank) attention,
+    #                     a four-stream residual mixed by doubly stochastic
+    #                     matrices, leading dense layers, then a sigmoid
+    #                     router over SwiGLU experts beside a shared expert
+    #                     (models/latent_trunk.py: LatentTrunkConfig holds
+    #                     the published widths; reads the same fields, with
+    #                     trunk_ffn one routed expert's width, and
+    #                     trunk_dense_layers)
     text_trunk: str = "distilbert"
-    # sparse_expert only: the share of every layer's experts held here (one
+    # routed trunks only: the share of every layer's experts held here (one
     # chip of an expert-parallel group): ids first..first+held-1 of the
     # family's experts; the router still scores all of them. 0 held = all.
     trunk_first_expert: int = 0
     trunk_experts_held: int = 0
+    # latent_moe only: the leading layers whose feed-forward is dense
+    # (published: first_k_dense_replace = 2); the others are routed
+    trunk_dense_layers: int = 2
     # trunk architecture for "finetune" mode (defaults = distilbert-base;
     # shrink for tests). dim is bert_hidden above.
     trunk_layers: int = 6

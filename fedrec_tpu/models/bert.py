@@ -265,6 +265,35 @@ def trunk_config_from(model_cfg) -> DistilBertConfig:
     )
 
 
+# what a routed trunk may return beside its states, and the name each
+# counter takes among the step's metrics (``train/step.py`` reads the sown
+# collection under these names; the trainer publishes them at a round's end)
+TRUNK_COUNTERS = {
+    "expert_tokens": "moe.expert_tokens",
+    "absent_share": "moe.absent_share",
+    "residual_mix_err": "trunk.residual_mix_err",
+}
+
+
+def trunk_families() -> dict[str, tuple]:
+    """``model.text_trunk`` -> (ModelConfig -> trunk config, the config's
+    class, the trunk's module). A routed trunk's module maps ``(ids, mask)``
+    to ``(states, counters)``, the counters by their ``TRUNK_COUNTERS``
+    keys; DistilBERT's ``(ids, mask, train)`` to the states alone."""
+    from fedrec_tpu.models.latent_trunk import (
+        LatentMoETrunk, LatentTrunkConfig, latent_trunk_config_from,
+    )
+    from fedrec_tpu.models.sparse_trunk import (
+        SparseExpertTrunk, SparseTrunkConfig, sparse_trunk_config_from,
+    )
+
+    return {
+        "distilbert": (trunk_config_from, DistilBertConfig, DistilBert),
+        "sparse_expert": (sparse_trunk_config_from, SparseTrunkConfig, SparseExpertTrunk),
+        "latent_moe": (latent_trunk_config_from, LatentTrunkConfig, LatentMoETrunk),
+    }
+
+
 def make_text_encoder(model_cfg) -> "TextEncoder":
     """Full trainable text tower for ``text_encoder_mode='finetune'``; the
     trunk's family is ``model.text_trunk``."""
@@ -273,19 +302,14 @@ def make_text_encoder(model_cfg) -> "TextEncoder":
             "text_encoder_mode='finetune' supports only the additive head; "
             "use text_head_arch='cnn' with mode 'head' or 'table'"
         )
-    if model_cfg.text_trunk == "distilbert":
-        trunk_cfg = trunk_config_from(model_cfg)
-    elif model_cfg.text_trunk == "sparse_expert":
-        from fedrec_tpu.models.sparse_trunk import sparse_trunk_config_from
-
-        trunk_cfg = sparse_trunk_config_from(model_cfg)
-    else:
+    families = trunk_families()
+    if model_cfg.text_trunk not in families:
         raise ValueError(
             f"unknown model.text_trunk {model_cfg.text_trunk!r} "
-            "(distilbert|sparse_expert)"
+            f"({'|'.join(families)})"
         )
     return TextEncoder(
-        trunk_cfg=trunk_cfg,
+        trunk_cfg=families[model_cfg.text_trunk][0](model_cfg),
         news_dim=model_cfg.news_dim,
         stable_softmax=model_cfg.stable_softmax,
         dtype=jnp.dtype(model_cfg.dtype),
@@ -294,9 +318,10 @@ def make_text_encoder(model_cfg) -> "TextEncoder":
 
 
 class TextEncoder(nn.Module):
-    """Full text tower: trunk + additive-attention head. The trunk is
-    DistilBERT, or for a ``SparseTrunkConfig`` the sparse-expert decoder
-    of ``models.sparse_trunk``, whose routing counters are sown into the
+    """Full text tower: trunk + additive-attention head. The trunk is the
+    module ``trunk_families`` names for ``trunk_cfg``'s class: DistilBERT,
+    or a routed decoder (``models.sparse_trunk``, ``models.latent_trunk``)
+    that returns ``(states, counters)``; the counters are sown into the
     ``routing`` collection (``apply(..., mutable=["routing"])`` reads them).
 
     The in-loop fine-tuning path (``text_encoder_mode='finetune'``,
@@ -318,7 +343,6 @@ class TextEncoder(nn.Module):
     ) -> jnp.ndarray:
         """(..., 2, L) stacked [ids; mask] -> (..., news_dim)."""
         from fedrec_tpu.models.encoders import TextHead
-        from fedrec_tpu.models.sparse_trunk import SparseExpertTrunk, SparseTrunkConfig
 
         batch_shape = tokens.shape[:-2]
         flat = tokens.reshape(-1, 2, tokens.shape[-1])
@@ -330,17 +354,19 @@ class TextEncoder(nn.Module):
             dtype=self.dtype,
             name="head",
         )  # reference passes no token mask to the pooler (encoder.py:28)
-        if isinstance(self.trunk_cfg, SparseTrunkConfig):
-            states, routing = SparseExpertTrunk(
-                self.trunk_cfg, dtype=self.dtype, remat=self.remat, name="trunk"
-            )(ids, mask)
-            for name, value in routing.items():
+        trunk_cls = next(
+            module for _, cfg_cls, module in trunk_families().values()
+            if isinstance(self.trunk_cfg, cfg_cls)
+        )
+        trunk = trunk_cls(
+            self.trunk_cfg, dtype=self.dtype, remat=self.remat, name="trunk"
+        )
+        if trunk_cls is DistilBert:
+            vecs = head(trunk(ids, mask, train))
+        else:
+            states, counters = trunk(ids, mask)
+            for name, value in counters.items():
                 self.sow("routing", name, value)
             with jax.named_scope("text_head"):
                 vecs = head(states)
-        else:
-            states = DistilBert(
-                self.trunk_cfg, dtype=self.dtype, remat=self.remat, name="trunk"
-            )(ids, mask, train)
-            vecs = head(states)
         return vecs.reshape(*batch_shape, self.news_dim)

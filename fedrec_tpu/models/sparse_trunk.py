@@ -42,6 +42,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 from functools import partial
+from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
@@ -58,7 +59,8 @@ ROW_TILE = 512
 class SparseTrunkConfig:
     """Architecture knobs; defaults = ``SmallThinker-21BA3B-Instruct``
     (huggingface.co/PowerInfer/SmallThinker-21BA3B-Instruct, config.json),
-    whole: every expert and every vocabulary row held here."""
+    whole: every expert and every vocabulary row held here. Its experts are
+    ReGLU: this trunk hands ``jax.nn.relu`` to ``HeldExperts``."""
 
     vocab_size: int = 151936
     dim: int = 2560                    # hidden_size
@@ -283,14 +285,17 @@ grouped_matmul.defvjp(
 def held_experts_output(
     u: jnp.ndarray, idx: jnp.ndarray, p: jnp.ndarray,
     w_gate: jnp.ndarray, w_up: jnp.ndarray, w_down: jnp.ndarray,
-    first_expert: int,
+    first_expert: int, activation: Callable[[jnp.ndarray], jnp.ndarray],
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """The held experts' part of the layer's output for one chunk of tokens.
 
     ``u`` (T, d) normed inputs, ``idx`` / ``p`` (T, k) every token's chosen
     experts and their weights, ``w_*`` the held experts' weights
-    ``(held, ...)`` in the dtype to compute in. Returns ``y`` (T, d) and the
-    tokens routed to each held expert, (held,) int32.
+    ``(held, ...)`` in the dtype to compute in. An expert is
+    ``down(activation(gate u) * up u)``: ``jax.nn.relu`` from the
+    sparse-expert trunk (ReGLU, SmallThinker), ``jax.nn.silu`` from the
+    latent trunk (SwiGLU, ``models/latent_trunk.py``). Returns ``y`` (T, d)
+    and the tokens routed to each held expert, (held,) int32.
 
     The (token, choice) pairs are sorted by held expert, pairs on absent
     experts last; the three grouped products run over the held groups. Rows
@@ -316,7 +321,7 @@ def held_experts_output(
     with jax.named_scope("moe_experts"):
         gate = jnp.where(here, grouped_matmul(xs, w_gate, sizes), 0)
         up = jnp.where(here, grouped_matmul(xs, w_up, sizes), 0)
-        down = grouped_matmul(jax.nn.relu(gate) * up, w_down, sizes)
+        down = grouped_matmul(activation(gate) * up, w_down, sizes)
         down = jnp.where(here, down, 0)
     with jax.named_scope("moe_combine"):
         per_choice = to_pair_order(down, order, back).reshape(t, k, -1)
@@ -327,10 +332,13 @@ def held_experts_output(
     return y, sizes
 
 
-class _Experts(nn.Module):
-    """The experts held here: ReGLU, no bias."""
+class HeldExperts(nn.Module):
+    """The experts held here, gated and without bias, for any trunk whose
+    configuration names ``dim``, ``expert_dim``, ``first_expert`` and
+    ``experts_held``."""
 
-    cfg: SparseTrunkConfig
+    cfg: Any
+    activation: Callable[[jnp.ndarray], jnp.ndarray]
     dtype: jnp.dtype = jnp.float32
 
     @nn.compact
@@ -351,7 +359,9 @@ class _Experts(nn.Module):
         )
 
         def chunk(args):
-            return held_experts_output(*args, *weights, c.first_expert)
+            return held_experts_output(
+                *args, *weights, c.first_expert, self.activation
+            )
 
         n = _chunks(u.shape[0])
         if n == 1:
@@ -384,7 +394,7 @@ class _DecoderLayer(nn.Module):
             x = x + _Attention(c, self.is_global, self.dtype, name="attn")(h, mask)
         with jax.named_scope("moe_route"):
             u = norm("ffn_norm")(x)
-        y, counts = _Experts(c, self.dtype, name="experts")(
+        y, counts = HeldExperts(c, jax.nn.relu, self.dtype, name="experts")(
             u.reshape(n * L, d), idx, p
         )
         return x + y.reshape(n, L, d), counts

@@ -47,6 +47,7 @@ from fedrec_tpu.config import ExperimentConfig
 from fedrec_tpu.eval.metrics import ranking_metrics_batch
 from fedrec_tpu.fed.strategies import FedStrategy, ParamAvg
 from fedrec_tpu.models import NewsRecommender, score_loss
+from fedrec_tpu.models.bert import TRUNK_COUNTERS
 from fedrec_tpu.models.recommender import score_candidates
 from fedrec_tpu.privacy.dpsgd import make_noise_fn, per_example_clipped_grads
 from fedrec_tpu.train.state import ClientState, make_optimizers
@@ -532,8 +533,8 @@ def _encode_unique_tokens(
 
     Gathers the unique token rows from the (N, 2, L) table, runs trunk +
     head once per distinct news, and scatters back to (len(ids), D). Also
-    returns the trunk's routing counters (``models.sparse_trunk``; empty
-    for a dense trunk).
+    returns the counters a routed trunk sowed (``models.bert.TextEncoder``;
+    empty for a dense trunk), under their ``TRUNK_COUNTERS`` metric names.
     """
     size = min(ids.shape[0], tokens_table.shape[0])
     uniq, inv = jnp.unique(ids, size=size, fill_value=0, return_inverse=True)
@@ -546,7 +547,7 @@ def _encode_unique_tokens(
         rngs={"dropout": dropout_rng} if train else None,
         mutable=["routing"],
     )  # (size, D)
-    routing = {k: v[0] for k, v in sown.get("routing", {}).items()}
+    routing = {TRUNK_COUNTERS[k]: v[0] for k, v in sown.get("routing", {}).items()}
     return vecs[inv], routing
 
 
@@ -1157,9 +1158,9 @@ def _build_local_step(
 
         mean_loss = lax.pmean(loss, axis_name=sync_axes)
         metrics = {"loss": loss, "mean_loss": mean_loss}
-        # a sparse-expert trunk's routing counters (models.sparse_trunk):
-        # tokens on each held expert a layer, share of pairs on absent ones
-        metrics.update({f"moe.{name}": v for name, v in routing.items()})
+        # a routed trunk's counters, under the names ``models.bert``'s
+        # ``TRUNK_COUNTERS`` gives them (``moe.*``, ``trunk.*``)
+        metrics.update(routing)
         if sentry:
             grad_norm = _tree_global_norm(*sentry_grads)
             update_norm = _tree_global_norm(*sentry_updates)

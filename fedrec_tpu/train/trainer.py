@@ -41,7 +41,7 @@ from fedrec_tpu.parallel.mesh import (
     shard_fed_batch,
 )
 from fedrec_tpu.train.checkpoint import SnapshotManager
-from fedrec_tpu.train.state import init_client_state, replicate_state
+from fedrec_tpu.train.state import init_stacked_state
 from fedrec_tpu.train.step import (
     batch_host_dedup,
     build_eval_step,
@@ -361,13 +361,10 @@ class Trainer:
             from fedrec_tpu.shard.policy import fsdp_state_shardings
 
             abstract_state = jax.eval_shape(
-                lambda: replicate_state(
-                    init_client_state(
-                        self.model, cfg, jax.random.PRNGKey(cfg.train.seed),
-                        data.num_news, data.title_len,
-                    ),
-                    cfg.fed.num_clients,
+                lambda: init_stacked_state(
+                    self.model, cfg, jax.random.PRNGKey(cfg.train.seed),
                     jax.random.PRNGKey(cfg.train.seed + 1),
+                    data.num_news, data.title_len,
                 )
             )
             self._state_shardings = fsdp_state_shardings(
@@ -572,19 +569,14 @@ class Trainer:
             )
 
         # state (pre-sharded so the first step doesn't retrace)
-        state0 = init_client_state(
+        stacked = init_stacked_state(
             self.model,
             cfg,
             jax.random.PRNGKey(cfg.train.seed),
+            jax.random.PRNGKey(cfg.train.seed + 1),
             data.num_news,
             data.title_len,
         )
-        stacked = replicate_state(
-            state0, cfg.fed.num_clients, jax.random.PRNGKey(cfg.train.seed + 1)
-        )
-        # the stack is a copy: let go of the first before the placement makes
-        # a third (a trunk's state is gigabytes)
-        del state0
         self.state = self._place_state(stacked)
         del stacked
         if self._pop_engine:
@@ -859,25 +851,6 @@ class Trainer:
             "steps whose distinct news exceeded train.encode_rows and were "
             "served, exactly, at the full size min(B*(C+H), N)",
         )
-        # a sparse-expert trunk's routing counters (models.sparse_trunk),
-        # returned with the step's metrics and published at the round's end
-        self._m_expert_tokens = self._g_absent_share = self._g_expert_load = None
-        if self.mode == "finetune" and cfg.model.text_trunk == "sparse_expert":
-            self._m_expert_tokens = self.registry.counter(
-                "moe.expert_tokens_total",
-                "(token, choice) pairs routed to each held expert",
-                labels=("layer", "expert"),
-            )
-            self._g_absent_share = self.registry.gauge(
-                "moe.absent_share",
-                "share of the last round's (token, choice) pairs that fell "
-                "on experts not held here",
-            )
-            self._g_expert_load = self.registry.gauge(
-                "moe.expert_load_max_over_mean",
-                "last round's mean over steps, clients and layers of the "
-                "fullest held expert's tokens over the held experts' mean",
-            )
         # per-step fusion gauge: how many fused Pallas hot-path kernels the
         # compiled step launches (model.fuse_hot_path; 2 = gather+encode
         # AND attention+pool+score, 1 = scoring kernel only — cnn text
@@ -1283,7 +1256,8 @@ class Trainer:
             tree_knobs += ["bert_hidden", "text_head_arch", "cnn_kernel"]
         if saved_mode == "finetune":
             tree_knobs += [
-                "trunk_layers", "trunk_heads", "trunk_ffn", "trunk_vocab",
+                "text_trunk", "trunk_layers", "trunk_dense_layers",
+                "trunk_heads", "trunk_ffn", "trunk_vocab",
             ]
         diffs = [
             (k, saved[k], getattr(cfg.model, k))
@@ -2650,7 +2624,7 @@ class Trainer:
         # what the round's end reads of every step's metrics, as device
         # arrays on their way to the host: the in-graph mean loss, the
         # per-client loss cells (the NaN-robust fallback), the sentry aux
-        # vectors and a sparse-expert trunk's routing counters
+        # vectors and a routed trunk's counters (``moe.*``, ``trunk.*``)
         losses: list = []
         kept = {
             "losses": losses, "raw_losses": [],
@@ -2669,8 +2643,11 @@ class Trainer:
             if row:
                 kept["health_rows"].append(row)
                 started.extend(row.values())
-            if self._m_expert_tokens is not None:
-                row = {k: v for k, v in metrics.items() if k.startswith("moe.")}
+            row = {
+                k: v for k, v in metrics.items()
+                if k.startswith(("moe.", "trunk."))
+            }
+            if row:
                 kept["routing_rows"].append(row)
                 started.extend(row.values())
             # a step's few hundred bytes leave the chip behind the step,
@@ -2843,24 +2820,51 @@ class Trainer:
         return result
 
     def _publish_routing(self, rows: list[dict]) -> None:
-        """The round's routing counters to the registry, from the host
-        arrays the round's end gathered. ``moe.expert_tokens`` is (...,
-        layers, held) per step entry, leading axes steps and clients."""
-        tokens = np.concatenate([
-            r["moe.expert_tokens"].reshape(
-                (-1,) + r["moe.expert_tokens"].shape[-2:]
+        """The round's counters of a routed trunk to the registry, from the
+        host arrays the round's end gathered: whatever the trunk returned
+        with the step's metrics (``models.bert.TextEncoder``), so a trunk
+        registers its gauges by returning them. ``moe.expert_tokens`` is
+        (..., routed layers, held) per step entry, leading axes steps and
+        clients; ``layer`` counts the routed layers from 0."""
+        reg = self.registry
+        if "moe.expert_tokens" in rows[0]:
+            tokens = np.concatenate([
+                r["moe.expert_tokens"].reshape(
+                    (-1,) + r["moe.expert_tokens"].shape[-2:]
+                )
+                for r in rows
+            ])  # (steps x clients, layers, held)
+            m_tokens = reg.counter(
+                "moe.expert_tokens_total",
+                "(token, choice) pairs routed to each held expert",
+                labels=("layer", "expert"),
             )
-            for r in rows
-        ])  # (steps x clients, layers, held)
-        first = self.cfg.model.trunk_first_expert
-        for (layer, expert), n in np.ndenumerate(tokens.sum(axis=0)):
-            self._m_expert_tokens.inc(float(n), layer=layer, expert=first + expert)
-        self._g_absent_share.set(float(np.mean(
-            [np.mean(r["moe.absent_share"]) for r in rows]
-        )))
-        self._g_expert_load.set(float(np.mean(
-            tokens.max(axis=-1) / np.maximum(tokens.mean(axis=-1), 1e-9)
-        )))
+            first = self.cfg.model.trunk_first_expert
+            for (layer, expert), n in np.ndenumerate(tokens.sum(axis=0)):
+                m_tokens.inc(float(n), layer=layer, expert=first + expert)
+            reg.gauge(
+                "moe.absent_share",
+                "share of the last round's (token, choice) pairs that fell "
+                "on experts not held here",
+            ).set(float(np.mean(
+                [np.mean(r["moe.absent_share"]) for r in rows]
+            )))
+            reg.gauge(
+                "moe.expert_load_max_over_mean",
+                "last round's mean over steps, clients and layers of the "
+                "fullest held expert's tokens over the held experts' mean",
+            ).set(float(np.mean(
+                tokens.max(axis=-1) / np.maximum(tokens.mean(axis=-1), 1e-9)
+            )))
+        if "trunk.residual_mix_err" in rows[0]:
+            reg.gauge(
+                "trunk.residual_mix_err_max",
+                "last round's largest distance from 1 of a row or column "
+                "sum of a residual mixing matrix after its last Sinkhorn "
+                "iteration, over tokens, sublayers, steps and clients",
+            ).set(float(max(
+                np.max(r["trunk.residual_mix_err"]) for r in rows
+            )))
 
     # ------------------------------------------- aggregation topologies
     def _agg_param_stacks(self) -> tuple[Any, Any]:

@@ -390,7 +390,8 @@ def test_held_experts_compile_at_published_widths(topo, one_chip, clients):
     )
 
     def loss(u, idx, p, w_gate, w_up, w_down):
-        y, sizes = sparse_trunk.held_experts_output(u, idx, p, w_gate, w_up, w_down, 0)
+        y, sizes = sparse_trunk.held_experts_output(
+            u, idx, p, w_gate, w_up, w_down, 0, jax.nn.relu)
         return jnp.sum(y.astype(jnp.float32)), sizes
 
     def both(*a):
@@ -403,3 +404,57 @@ def test_held_experts_compile_at_published_widths(topo, one_chip, clients):
     assert "lhs_batch_dims={0}" not in "".join(
         line for line in text.splitlines() if "ragged-dot(" in line
     )
+
+
+# ------------------------------ the latent trunk's whole step, one chip's share
+# what the runtime leaves a program on one v5e chip (``bytes_limit`` of
+# ``device.memory_stats()``: PERF.md section 4)
+V5E_BYTES_LIMIT = 16.909e9
+
+
+@pytest.mark.time_limit(900)
+def test_latent_trunk_step_fits_one_chip_at_published_widths(topo):
+    """``xing29b-ep8.b2``'s train step (Xing4.0-29B-A4B's widths, one dense
+    and four routed layers, 8 of 64 experts and an eighth of the vocabulary
+    held, B=2: 5,500 tokens, bfloat16 compute) compiles for one described
+    v5e chip with its arguments (709M parameters and Adam's two moments) and
+    its temporaries (the gradient, the bfloat16 weight copies, one
+    sublayer's rematerialised forward) under the chip's limit; the grouped
+    products reach the grouped-matmul kernel. The TPU compiler takes two
+    minutes over it here."""
+    cfg = ExperimentConfig().apply_overrides([
+        "fed.num_clients=1", "fed.strategy=grad_avg", "data.batch_size=2",
+        "model.text_encoder_mode=finetune", "model.text_trunk=latent_moe",
+        "model.bert_hidden=3584", "model.trunk_layers=5", "model.trunk_dense_layers=1",
+        "model.trunk_heads=32", "model.trunk_ffn=1024", "model.trunk_vocab=16384",
+        "model.trunk_first_expert=0", "model.trunk_experts_held=8",
+        "model.dtype=bfloat16", "model.dropout_rate=0.0",
+    ])
+    model = NewsRecommender(cfg.model)
+    mesh = Mesh(np.array(topo.devices[:1]), (cfg.fed.mesh_axis,))
+    per_client = NamedSharding(mesh, P(cfg.fed.mesh_axis))
+    state = jax.eval_shape(
+        lambda: replicate_state(
+            init_client_state(model, cfg, jax.random.PRNGKey(0), TABLE_ROWS, TITLE),
+            1, jax.random.PRNGKey(1),
+        )
+    )
+    params = sum(
+        x.size for x in jax.tree_util.tree_leaves((state.user_params, state.news_params))
+    )
+    assert params == 709_191_264                 # x 16 B = 11.35 GB with the gradient
+    state = jax.tree_util.tree_map(lambda x: _spec(x.shape, x.dtype, per_client), state)
+    batch = {
+        "candidates": _spec((1, 2, CANDS), "int32", per_client),
+        "history": _spec((1, 2, HIS), "int32", per_client),
+        "labels": _spec((1, 2), "int32", per_client),
+    }
+    tokens = _spec((TABLE_ROWS, 2, TITLE), "int32", NamedSharding(mesh, P()))
+    step = build_fed_train_step(model, cfg, get_strategy("grad_avg"), mesh, mode="finetune")
+    compiled = step.lower(state, batch, tokens).compile()
+    mem = compiled.memory_analysis()
+    assert 8.5e9 < mem.argument_size_in_bytes < 8.6e9          # 12 B a parameter at rest
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < V5E_BYTES_LIMIT
+    text = compiled.as_text()
+    # 4 routed layers x 3 products x (forward, rematerialised, two transposes)
+    assert text.count('custom_call_target="tpu_custom_call"') >= 48
