@@ -271,6 +271,7 @@ def trunk_config_from(model_cfg) -> DistilBertConfig:
 TRUNK_COUNTERS = {
     "expert_tokens": "moe.expert_tokens",
     "absent_share": "moe.absent_share",
+    "full_size_chunks": "moe.full_size_chunks",
     "residual_mix_err": "trunk.residual_mix_err",
 }
 

@@ -47,14 +47,20 @@ is ``yarn_mscale``. Feed-forward, with ``u = RMSNorm(x_in; g2)``: the first
 
 The held experts' part is ``sparse_trunk.HeldExperts`` (sort by expert,
 grouped products over the true group sizes, no token dropped), told which
-experts it holds as there; what absent experts would add is left out. The
-shared expert is computed whole. The embedding holds ``vocab_held`` rows
-from ``vocab_first``; an id outside the slice embeds to zero.
+experts it holds as there; what absent experts would add is left out. Its
+sorted buffer has ``sparse_trunk.buffer_rows``' two sizes: with 8 of 64
+experts held a layer runs through a quarter of the worst case's rows
+whenever the router sent here at most twice the even share of the pairs, and
+at the worst case's size, exactly, when it sent more (``full_size_chunks``
+counts those layers). The shared expert is computed whole. The embedding
+holds ``vocab_held`` rows from ``vocab_first``; an id outside the slice
+embeds to zero.
 
 Rematerialisation (``remat=True``) is per sublayer, mixer included: the
 backward pass keeps each sublayer's input ``X`` and runs its forward once
-more. Nothing else is rematerialised or chunked beyond ``HeldExperts``' own
-chunks.
+more, except the held experts', whose output is kept beside ``X``
+(``HeldExperts`` runs its own forward again beside its transposes). Nothing
+else is rematerialised or chunked beyond ``HeldExperts``' own chunks.
 """
 
 from __future__ import annotations
@@ -67,7 +73,9 @@ import jax.numpy as jnp
 from flax import linen as nn
 from jax import lax
 
-from fedrec_tpu.models.sparse_trunk import HeldExperts, RMSNorm, attention_allowed
+from fedrec_tpu.models.sparse_trunk import (
+    HELD_EXPERTS_OUTPUT, HeldExperts, RMSNorm, attention_allowed,
+)
 
 HI = lax.Precision.HIGHEST
 
@@ -290,7 +298,7 @@ class _RoutedFFN(nn.Module):
     dtype: jnp.dtype = jnp.float32
 
     @nn.compact
-    def __call__(self, u: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
+    def __call__(self, u: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
         c = self.cfg
         tokens = u.reshape(-1, c.dim)
         with jax.named_scope("moe_route"):
@@ -301,14 +309,14 @@ class _RoutedFFN(nn.Module):
             idx, weights = route_sigmoid(
                 tokens, w_router, bias, c.experts_per_token, c.routed_scale
             )
-        y, counts = HeldExperts(c, jax.nn.silu, self.dtype, name="experts")(
+        y, counts, full_size = HeldExperts(c, jax.nn.silu, self.dtype, name="experts")(
             tokens, idx, weights
         )
         with jax.named_scope("shared_expert"):
             shared = _GatedFFN(
                 c.dim, c.n_shared_experts * c.expert_dim, self.dtype, name="shared_expert"
             )(u)
-        return y.reshape(u.shape) + shared, counts
+        return y.reshape(u.shape) + shared, counts, full_size
 
 
 # ---------------------------------------------------------------- the mixer
@@ -370,7 +378,7 @@ class _Sublayer(nn.Module):
     first, so that no array has a 4-long axis among its last two (a TPU
     tiles those by 8 x 128). Returns the new state, the mixer's distance
     from doubly stochastic and, from a routed sublayer, the tokens on each
-    held expert."""
+    held expert and the chunks that ran at the sorted buffer's full size."""
 
     cfg: LatentTrunkConfig
     kind: str
@@ -401,8 +409,7 @@ class _Sublayer(nn.Module):
         else:
             with jax.named_scope("moe_route"):
                 u = norm("norm")(x_in)
-            out, counts = _RoutedFFN(c, self.dtype, name="ffn")(u)
-            extra = (counts,)
+            out, *extra = _RoutedFFN(c, self.dtype, name="ffn")(u)
         with jax.named_scope("residual_mix"):
             out32 = out.astype(jnp.float32)
             mixed = jnp.concatenate([
@@ -410,7 +417,7 @@ class _Sublayer(nn.Module):
                 + per_token(post[i]) * out32
                 for i in range(n)
             ], axis=-1).astype(self.dtype)
-        return (mixed, err) + extra
+        return (mixed, err, *extra)
 
 
 class LatentMoETrunk(nn.Module):
@@ -418,6 +425,8 @@ class LatentMoETrunk(nn.Module):
     trunk's counters: ``expert_tokens`` (routed layers, experts_held) int32,
     the (token, choice) pairs that fell on each held expert;
     ``absent_share``, the share of all pairs that fell on absent experts;
+    ``full_size_chunks``, how many (layer, chunk)s ran at the sorted buffer's
+    full size (``sparse_trunk.buffer_rows``), int32;
     ``residual_mix_err``, the largest distance from 1 of a row or
     column sum of a mixing matrix after its last Sinkhorn iteration, over
     tokens and sublayers."""
@@ -441,18 +450,23 @@ class LatentMoETrunk(nn.Module):
             x = jnp.where(held[:, None], rows, 0).astype(self.dtype)
         with jax.named_scope("residual_mix"):
             x = jnp.tile(x, (1, c.n_streams))
-        sublayer = nn.remat(_Sublayer) if self.remat else _Sublayer
-        counts, errs = [], []
+        # the held experts' output is kept: their forward, which the choice
+        # between the buffer's sizes runs again beside its transposes, is
+        # then not run a third time for the sake of ``post``'s gradient
+        keep = jax.checkpoint_policies.save_only_these_names(HELD_EXPERTS_OUTPUT)
+        sublayer = nn.remat(_Sublayer, policy=keep) if self.remat else _Sublayer
+        routed, errs = [], []
         for i in range(c.n_layers):
             ffn = "dense" if i < c.n_dense_layers else "routed"
             x, err = sublayer(c, "attention", self.dtype, name=f"layer_{i}_attn")(
                 x, attention_mask
             )
-            x, err_ffn, *n = sublayer(c, ffn, self.dtype, name=f"layer_{i}_ffn")(
+            x, err_ffn, *of_layer = sublayer(c, ffn, self.dtype, name=f"layer_{i}_ffn")(
                 x, attention_mask
             )
             errs += [err, err_ffn]
-            counts += n
+            if of_layer:
+                routed.append(of_layer)
         with jax.named_scope("residual_mix"):
             x = jnp.sum(
                 x.reshape(-1, c.n_streams, c.dim), axis=1, dtype=jnp.float32
@@ -460,9 +474,10 @@ class LatentMoETrunk(nn.Module):
         x = RMSNorm(c.rms_norm_eps, self.dtype, name="final_norm")(x)
         x = x.reshape(input_ids.shape + (c.dim,))
         counters = {"residual_mix_err": jnp.max(jnp.stack(errs))}
-        if counts:
-            expert_tokens = jnp.stack(counts)
+        if routed:
+            expert_tokens, full_size = map(jnp.stack, zip(*routed))
             pairs = c.n_routed_layers * input_ids.size * c.experts_per_token
             counters["expert_tokens"] = expert_tokens
             counters["absent_share"] = 1.0 - jnp.sum(expert_tokens) / jnp.float32(pairs)
+            counters["full_size_chunks"] = jnp.sum(full_size)
         return x, counters

@@ -31,11 +31,28 @@ for the absent chips. The embedding likewise holds ``vocab_held`` rows from
 
 No token is dropped: the assignments are sorted by expert and the grouped
 products (``grouped_matmul``: ``jax.lax.ragged_dot``, XLA:TPU's grouped
-matmul kernel) run over the true group sizes. Static shapes make the sorted
-buffer as long as the worst case (every choice of every token on a held
-expert), so the layer works through the tokens in chunks of at most
-``MAX_CHUNK_TOKENS``, each chunk rematerialised in the backward pass: memory
-is bounded by one chunk's worst case, compute follows the rows really there.
+matmul kernel) run over the true group sizes. Shapes are static, so the
+sorted buffer has one of two sizes (``buffer_rows``). The full size is the
+worst case, every choice of every token on a held expert. The small size
+follows the share of the experts held: ``EVEN_SHARE_ROOM`` (twice) what an
+even router sends here, a half of the full size with 16 of 64 experts held,
+a quarter with 8 of 64. Every pass over the buffer (the row gathers, the
+selects that zero its unwritten rows, the products' outputs and all their
+transposes) costs by its rows, whatever they hold. A chunk whose router sent
+more pairs here than the small size holds runs at the full size, one
+``lax.cond`` between the two sizes of the same body on the count the sort
+gives (``_either_size``), so a router that drifts is served exactly, at the
+worst case's cost; ``full_size_chunks`` counts those chunks. Twice, because
+the share on the held experts read within 0.7 to 1.5 of the even share over
+the benchmark's seeds (PERF.md section 6, PR 33 and 34). Where half the
+experts or more are held the two sizes are one and there is no conditional.
+Under an in-device cohort's ``vmap`` the count is per client, the
+conditional becomes a select and both sizes run for every client: exact, and
+slower than one size by the small one (test widths only so far).
+
+The layer works through the tokens in chunks of at most ``MAX_CHUNK_TOKENS``,
+each chunk rematerialised in the backward pass: memory is bounded by one
+chunk's worst case, compute follows the rows really there.
 """
 
 from __future__ import annotations
@@ -48,11 +65,16 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
-# the expert layer's sorted buffer holds chunk x experts_per_token rows,
-# padded to whole tiles of ROW_TILE rows
+# the expert layer's sorted buffer is whole tiles of ROW_TILE rows: at its
+# full size chunk x experts_per_token rows, at its small size EVEN_SHARE_ROOM
+# times the rows an even router fills
 MAX_CHUNK_TOKENS = 16384
 ROW_TILE = 512
+EVEN_SHARE_ROOM = 2
+# the name ``HeldExperts`` gives its output for a caller's remat policy
+HELD_EXPERTS_OUTPUT = "held_experts_output"
 
 
 @dataclass(frozen=True)
@@ -212,7 +234,10 @@ def route(
 # expert order (pairs sorted by held expert, then the absent ones, then
 # padding) by gathers only: the sort gives the permutation and its inverse,
 # so each gather's cotangent goes back by a gather too, where the transpose
-# of a gather is a scatter (which a TPU runs row by row).
+# of a gather is a scatter (which a TPU runs row by row). The buffer may be
+# cut short of the absent pairs (``buffer_rows``): ``order`` is cut to its
+# rows, and a ``back`` that points past them is clipped and counted for
+# nothing.
 @partial(jax.custom_vjp, nondiff_argnums=(3,))
 def to_expert_order(u, order, back, k):
     """(T, d) token rows -> (rows, d): row i is the token of pair order[i]."""
@@ -220,7 +245,9 @@ def to_expert_order(u, order, back, k):
 
 
 def _to_expert_order_bwd(k, back, ct):
-    per_pair = ct[back].reshape(back.shape[0] // k, k, ct.shape[-1])
+    rows, d = ct.shape
+    per_pair = ct[jnp.minimum(back, rows - 1)].reshape(-1, k, d)
+    per_pair = jnp.where((back < rows).reshape(-1, k, 1), per_pair, 0)
     return jnp.sum(per_pair, axis=1, dtype=jnp.float32).astype(ct.dtype), None, None
 
 
@@ -232,18 +259,31 @@ to_expert_order.defvjp(
 
 @jax.custom_vjp
 def to_pair_order(y, order, back):
-    """(rows, d) in expert order -> (T*k, d) in pair order."""
-    return y[back]
+    """(rows, d) in expert order -> (T*k, d) in pair order; a pair past the
+    buffer reads its last row (the caller gives that pair no weight)."""
+    return y[jnp.minimum(back, y.shape[0] - 1)]
 
 
 def _to_pair_order_bwd(order, ct):
-    padded = jnp.pad(ct, ((0, order.shape[0] - ct.shape[0]), (0, 0)))
-    return padded[order], None, None
+    # the padding entries sort last: a buffer cut short holds none of them
+    padding = max(order.shape[0] - ct.shape[0], 0)
+    return jnp.pad(ct, ((0, padding), (0, 0)))[order], None, None
 
 
 to_pair_order.defvjp(
-    lambda y, order, back: (y[back], order), _to_pair_order_bwd
+    lambda y, order, back: (to_pair_order(y, order, back), order),
+    _to_pair_order_bwd,
 )
+
+
+def buffer_rows(pairs: int, held: int, n_experts: int) -> tuple[int, int]:
+    """The sorted buffer's two sizes for ``pairs`` (token, choice) pairs with
+    ``held`` of ``n_experts`` experts here, in whole row tiles: (small, full).
+    Full holds every pair; small holds ``EVEN_SHARE_ROOM`` times the pairs an
+    even router sends to the held experts, and never more than full."""
+    tiles = lambda rows: -(-rows // ROW_TILE) * ROW_TILE  # noqa: E731
+    full = tiles(pairs)
+    return min(tiles(-(-EVEN_SHARE_ROOM * pairs * held // n_experts)), full), full
 
 
 def _chunks(tokens: int) -> int:
@@ -282,41 +322,22 @@ grouped_matmul.defvjp(
 )
 
 
-def held_experts_output(
-    u: jnp.ndarray, idx: jnp.ndarray, p: jnp.ndarray,
-    w_gate: jnp.ndarray, w_up: jnp.ndarray, w_down: jnp.ndarray,
-    first_expert: int, activation: Callable[[jnp.ndarray], jnp.ndarray],
-) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """The held experts' part of the layer's output for one chunk of tokens.
-
-    ``u`` (T, d) normed inputs, ``idx`` / ``p`` (T, k) every token's chosen
-    experts and their weights, ``w_*`` the held experts' weights
-    ``(held, ...)`` in the dtype to compute in. An expert is
-    ``down(activation(gate u) * up u)``: ``jax.nn.relu`` from the
-    sparse-expert trunk (ReGLU, SmallThinker), ``jax.nn.silu`` from the
-    latent trunk (SwiGLU, ``models/latent_trunk.py``). Returns ``y`` (T, d)
-    and the tokens routed to each held expert, (held,) int32.
-
-    The (token, choice) pairs are sorted by held expert, pairs on absent
-    experts last; the three grouped products run over the held groups. Rows
-    past the held groups are pairs on absent experts (and padding): a
-    grouped product leaves them unwritten, so they are zeroed on the way in
-    and out (and, by the same selects transposed, in the backward pass).
-    """
-    t, k = idx.shape
-    held = w_gate.shape[0]
+def _at_rows(rows, k, activation, ints, u, p, w_gate, w_up, w_down):
+    """One chunk's output through a sorted buffer of ``rows`` rows: the body
+    of ``held_experts_output`` at one of its sizes. Rows past the held groups
+    are pairs on absent experts (and padding): a grouped product leaves them
+    unwritten, so they are zeroed on the way in and out (and, by the same
+    selects transposed, in the backward pass)."""
+    order, back, sizes = ints
+    t = u.shape[0]
     with jax.named_scope("moe_route"):
-        local = idx.reshape(-1) - first_expert
-        group = jnp.where((local >= 0) & (local < held), local, held)
-        # whole row tiles: the grouped-matmul kernel is several times slower
-        # on a row count that is not a multiple of its tile
-        group = jnp.pad(group, (0, -(t * k) % ROW_TILE), constant_values=held)
-        order = jnp.argsort(group, stable=True)
-        back = jnp.argsort(order)[: t * k]
-        sizes = jnp.sum(
-            group[:, None] == jnp.arange(held)[None, :], axis=0, dtype=jnp.int32
-        )
-        here = (jnp.arange(group.shape[0]) < jnp.sum(sizes))[:, None]
+        order = order[:rows]
+        # a no-op wherever this size's result is used; under a cohort's vmap
+        # both sizes run, and the groups of a client whose pairs overflow
+        # this one must not reach past the buffer
+        ends = jnp.minimum(jnp.cumsum(sizes), rows)
+        sizes = jnp.diff(ends, prepend=0)
+        here = (jnp.arange(rows) < ends[-1])[:, None]
         xs = jnp.where(here, to_expert_order(u, order, back, k), 0)
     with jax.named_scope("moe_experts"):
         gate = jnp.where(here, grouped_matmul(xs, w_gate, sizes), 0)
@@ -325,17 +346,105 @@ def held_experts_output(
         down = jnp.where(here, down, 0)
     with jax.named_scope("moe_combine"):
         per_choice = to_pair_order(down, order, back).reshape(t, k, -1)
-        y = jnp.einsum(
-            "tkd,tk->td", per_choice, p.astype(down.dtype),
+        # a pair past the buffer is on an absent expert: it adds nothing
+        weight = jnp.where((back < rows).reshape(t, k), p, 0)
+        return jnp.einsum(
+            "tkd,tk->td", per_choice, weight.astype(down.dtype),
             preferred_element_type=jnp.float32,
         ).astype(down.dtype)
-    return y, sizes
+
+
+# The choice between the buffer's two sizes, differentiated by hand: left to
+# JAX, the forward conditional would hand the backward one every residual of
+# BOTH branches, the branch not taken filling the other's with zeros at their
+# size. Here the forward keeps the chunk's inputs and the backward
+# conditional runs the taken size's forward again beside its transposes,
+# which is what the chunk's (and the layer's) rematerialisation does anyway.
+@partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _either_size(sizes, k, activation, overflow, ints, *floats):
+    small, full = sizes
+    return lax.cond(
+        overflow,
+        partial(_at_rows, full, k, activation),
+        partial(_at_rows, small, k, activation),
+        ints, *floats,
+    )
+
+
+def _either_size_bwd(sizes, k, activation, res, ct):
+    overflow, ints, floats = res
+
+    def transposes(rows):
+        def run(ints, floats, ct):
+            return jax.vjp(partial(_at_rows, rows, k, activation, ints), *floats)[1](ct)
+        return run
+
+    small, full = sizes
+    grads = lax.cond(overflow, transposes(full), transposes(small), ints, floats, ct)
+    return (None, None, *grads)
+
+
+_either_size.defvjp(
+    lambda sizes, k, activation, overflow, ints, *floats: (
+        _either_size(sizes, k, activation, overflow, ints, *floats),
+        (overflow, ints, floats),
+    ),
+    _either_size_bwd,
+)
+
+
+def held_experts_output(
+    u: jnp.ndarray, idx: jnp.ndarray, p: jnp.ndarray,
+    w_gate: jnp.ndarray, w_up: jnp.ndarray, w_down: jnp.ndarray,
+    first_expert: int, n_experts: int,
+    activation: Callable[[jnp.ndarray], jnp.ndarray],
+) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """The held experts' part of the layer's output for one chunk of tokens.
+
+    ``u`` (T, d) normed inputs, ``idx`` / ``p`` (T, k) every token's chosen
+    experts and their weights, ``w_*`` the held experts' weights
+    ``(held, ...)`` in the dtype to compute in, ``n_experts`` the layer's
+    experts, held and absent. An expert is
+    ``down(activation(gate u) * up u)``: ``jax.nn.relu`` from the
+    sparse-expert trunk (ReGLU, SmallThinker), ``jax.nn.silu`` from the
+    latent trunk (SwiGLU, ``models/latent_trunk.py``). Returns ``y`` (T, d),
+    the tokens routed to each held expert, (held,) int32, and whether the
+    chunk's pairs overflowed the buffer's small size and ran at the full
+    one, int32 (0 where the two sizes are one).
+
+    The (token, choice) pairs are sorted by held expert, pairs on absent
+    experts last; the three grouped products run over the held groups, in a
+    buffer of ``buffer_rows``' small size where the held pairs fit, else of
+    its full size (``_at_rows`` at either).
+    """
+    t, k = idx.shape
+    held = w_gate.shape[0]
+    small, full = buffer_rows(t * k, held, n_experts)
+    with jax.named_scope("moe_route"):
+        local = idx.reshape(-1) - first_expert
+        group = jnp.where((local >= 0) & (local < held), local, held)
+        # whole row tiles: the grouped-matmul kernel is several times slower
+        # on a row count that is not a multiple of its tile
+        group = jnp.pad(group, (0, full - t * k), constant_values=held)
+        order = jnp.argsort(group, stable=True)
+        back = jnp.argsort(order)[: t * k]
+        sizes = jnp.sum(
+            group[:, None] == jnp.arange(held)[None, :], axis=0, dtype=jnp.int32
+        )
+    ints, floats = (order, back, sizes), (u, p, w_gate, w_up, w_down)
+    if small == full:
+        return _at_rows(full, k, activation, ints, *floats), sizes, jnp.int32(0)
+    overflow = jnp.sum(sizes) > small
+    y = _either_size((small, full), k, activation, overflow, ints, *floats)
+    return y, sizes, overflow.astype(jnp.int32)
 
 
 class HeldExperts(nn.Module):
     """The experts held here, gated and without bias, for any trunk whose
-    configuration names ``dim``, ``expert_dim``, ``first_expert`` and
-    ``experts_held``."""
+    configuration names ``dim``, ``expert_dim``, ``n_experts``,
+    ``first_expert`` and ``experts_held``: tokens ``u`` (T, d) with their
+    chosen experts and weights -> their part of the output, the tokens on
+    each held expert and the chunks that ran at the buffer's full size."""
 
     cfg: Any
     activation: Callable[[jnp.ndarray], jnp.ndarray]
@@ -344,7 +453,7 @@ class HeldExperts(nn.Module):
     @nn.compact
     def __call__(
         self, u: jnp.ndarray, idx: jnp.ndarray, p: jnp.ndarray
-    ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
         c = self.cfg
         init = nn.initializers.variance_scaling(
             1.0, "fan_in", "normal", in_axis=-2, out_axis=-1, batch_axis=(0,)
@@ -360,15 +469,19 @@ class HeldExperts(nn.Module):
 
         def chunk(args):
             return held_experts_output(
-                *args, *weights, c.first_expert, self.activation
+                *args, *weights, c.first_expert, c.n_experts, self.activation
             )
 
         n = _chunks(u.shape[0])
         if n == 1:
-            return chunk((u, idx, p))
-        split = lambda x: x.reshape(n, -1, x.shape[-1])  # noqa: E731
-        y, sizes = lax.map(jax.checkpoint(chunk), (split(u), split(idx), split(p)))
-        return y.reshape(u.shape), jnp.sum(sizes, axis=0)
+            y, sizes, full_size = chunk((u, idx, p))
+        else:
+            split = lambda x: x.reshape(n, -1, x.shape[-1])  # noqa: E731
+            y, sizes, full_size = lax.map(
+                jax.checkpoint(chunk), (split(u), split(idx), split(p))
+            )
+            y, sizes, full_size = y.reshape(u.shape), jnp.sum(sizes, axis=0), jnp.sum(full_size)
+        return checkpoint_name(y, HELD_EXPERTS_OUTPUT), sizes, full_size
 
 
 class _DecoderLayer(nn.Module):
@@ -379,7 +492,7 @@ class _DecoderLayer(nn.Module):
     @nn.compact
     def __call__(
         self, x: jnp.ndarray, mask: jnp.ndarray
-    ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
         c = self.cfg
         n, L, d = x.shape
         norm = lambda name: RMSNorm(c.rms_norm_eps, self.dtype, name=name)  # noqa: E731
@@ -394,17 +507,18 @@ class _DecoderLayer(nn.Module):
             x = x + _Attention(c, self.is_global, self.dtype, name="attn")(h, mask)
         with jax.named_scope("moe_route"):
             u = norm("ffn_norm")(x)
-        y, counts = HeldExperts(c, jax.nn.relu, self.dtype, name="experts")(
+        y, counts, full_size = HeldExperts(c, jax.nn.relu, self.dtype, name="experts")(
             u.reshape(n * L, d), idx, p
         )
-        return x + y.reshape(n, L, d), counts
+        return x + y.reshape(n, L, d), counts, full_size
 
 
 class SparseExpertTrunk(nn.Module):
     """Token ids + attention mask -> per-token states (N, L, dim), and what
     the routers did: ``expert_tokens`` (layers, experts_held) int32, the
-    (token, choice) pairs that fell on each held expert, and
-    ``absent_share``, the share of all pairs that fell on absent experts."""
+    (token, choice) pairs that fell on each held expert; ``absent_share``,
+    the share of all pairs that fell on absent experts; ``full_size_chunks``,
+    how many (layer, chunk)s ran at the sorted buffer's full size, int32."""
 
     cfg: SparseTrunkConfig = SparseTrunkConfig()
     dtype: jnp.dtype = jnp.float32
@@ -424,17 +538,19 @@ class SparseExpertTrunk(nn.Module):
             rows = table[jnp.clip(local, 0, c.vocab_held - 1)]
             x = jnp.where(held[..., None], rows, 0).astype(self.dtype)
         layer_cls = nn.remat(_DecoderLayer) if self.remat else _DecoderLayer
-        counts = []
+        counts, full_size = [], []
         for i in range(c.n_layers):
-            x, n = layer_cls(c, c.is_global(i), self.dtype, name=f"layer_{i}")(
+            x, n, full = layer_cls(c, c.is_global(i), self.dtype, name=f"layer_{i}")(
                 x, attention_mask
             )
             counts.append(n)
+            full_size.append(full)
         x = RMSNorm(c.rms_norm_eps, self.dtype, name="final_norm")(x)
         expert_tokens = jnp.stack(counts)
         pairs = c.n_layers * input_ids.size * c.experts_per_token
         routing = {
             "expert_tokens": expert_tokens,
             "absent_share": 1.0 - jnp.sum(expert_tokens) / jnp.float32(pairs),
+            "full_size_chunks": jnp.sum(jnp.stack(full_size)),
         }
         return x, routing

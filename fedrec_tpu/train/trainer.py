@@ -2842,6 +2842,11 @@ class Trainer:
             first = self.cfg.model.trunk_first_expert
             for (layer, expert), n in np.ndenumerate(tokens.sum(axis=0)):
                 m_tokens.inc(float(n), layer=layer, expert=first + expert)
+            reg.counter(
+                "moe.full_size_chunks_total",
+                "(layer, chunk)s whose pairs on the held experts overflowed "
+                "the sorted buffer's small size and ran at the full one",
+            ).inc(float(sum(np.sum(r["moe.full_size_chunks"]) for r in rows)))
             reg.gauge(
                 "moe.absent_share",
                 "share of the last round's (token, choice) pairs that fell "

@@ -369,16 +369,20 @@ def test_joint_step_compiles_for_four_chips(topo):
 
 
 # ------------------------------------------ the sparse-expert trunk's layer
-@pytest.mark.parametrize("clients", [1, 2], ids=["one-client", "cohort-of-two"])
-def test_held_experts_compile_at_published_widths(topo, one_chip, clients):
-    """One chunk of the expert layer of ``st21b-ep4.b16`` (11,000 tokens, 6
-    choices, 16 of 64 experts of 2560 x 768 held), forward and backward: the
-    grouped products reach XLA:TPU's grouped-matmul kernel un-batched. It
-    refuses them a batch dimension, so under a cohort's ``vmap`` they run
-    client by client (``sparse_trunk.grouped_matmul``)."""
+# one chunk of the expert layer in the two routed cells: tokens, choices a
+# token, experts held of 64, hidden and expert widths, the activation
+ROUTED_CHUNKS = {
+    "st21b-ep4.b16": (11_000, 6, 16, 2560, 768, jax.nn.relu),
+    "xing29b-ep8.b2": (5_500, 4, 8, 3584, 1024, jax.nn.silu),
+}
+
+
+def _held_experts_chunk(one_chip, cell, clients=1):
+    """The chunk's loss and gradient (forward and backward), compiled for
+    one chip."""
     from fedrec_tpu.models import sparse_trunk
 
-    tokens, k, held, d, f = 11_000, 6, 16, 2560, 768
+    tokens, k, held, d, f, activation = ROUTED_CHUNKS[cell]
     lead = () if clients == 1 else (clients,)
     args = (
         _spec(lead + (tokens, d), "bfloat16", one_chip),
@@ -390,20 +394,63 @@ def test_held_experts_compile_at_published_widths(topo, one_chip, clients):
     )
 
     def loss(u, idx, p, w_gate, w_up, w_down):
-        y, sizes = sparse_trunk.held_experts_output(
-            u, idx, p, w_gate, w_up, w_down, 0, jax.nn.relu)
-        return jnp.sum(y.astype(jnp.float32)), sizes
+        y, sizes, full_size = sparse_trunk.held_experts_output(
+            u, idx, p, w_gate, w_up, w_down, 0, 64, activation)
+        return jnp.sum(y.astype(jnp.float32)), (sizes, full_size)
 
     def both(*a):
-        grad = jax.grad(loss, argnums=(0, 3, 4, 5), has_aux=True)
+        grad = jax.value_and_grad(loss, argnums=(0, 3, 4, 5), has_aux=True)
         return (jax.vmap(grad) if clients > 1 else grad)(*a)
 
-    text = _compile(both, *args).as_text()
+    return _compile(both, *args)
+
+
+def _batched_products(text: str) -> str:
+    return "".join(
+        line for line in text.splitlines()
+        if "ragged-dot(" in line and "lhs_batch_dims={0}" in line
+    )
+
+
+@pytest.mark.parametrize("clients", [1, 2], ids=["one-client", "cohort-of-two"])
+def test_held_experts_compile_at_published_widths(topo, one_chip, clients):
+    """One chunk of the expert layer of ``st21b-ep4.b16`` (11,000 tokens, 6
+    choices, 16 of 64 experts of 2560 x 768 held), forward and backward: the
+    grouped products reach XLA:TPU's grouped-matmul kernel un-batched. It
+    refuses them a batch dimension, so under a cohort's ``vmap`` they run
+    client by client (``sparse_trunk.grouped_matmul``), at both sizes of
+    the sorted buffer (the choice between them is a select there)."""
+    text = _held_experts_chunk(one_chip, "st21b-ep4.b16", clients).as_text()
     # three products forward, and for each its two transposes
     assert text.count('custom_call_target="tpu_custom_call"') >= 9
-    assert "lhs_batch_dims={0}" not in "".join(
-        line for line in text.splitlines() if "ragged-dot(" in line
-    )
+    assert _batched_products(text) == ""
+
+
+@pytest.mark.parametrize("cell", sorted(ROUTED_CHUNKS))
+def test_held_experts_choose_between_two_buffer_sizes(topo, one_chip, monkeypatch, cell):
+    """The chunk of each routed cell compiles with a conditional between the
+    sorted buffer's small size (33,280 / 5,632 rows) and its full one
+    (66,048 / 22,016), forward and backward, the grouped products un-batched
+    in both branches; the full-size branch is the larger, so the program
+    needs no more temporary memory than with one size, the full one."""
+    from fedrec_tpu.models import sparse_trunk
+
+    tokens, k, held, *_ = ROUTED_CHUNKS[cell]
+    small, full = sparse_trunk.buffer_rows(tokens * k, held, 64)
+    assert (small, full) == {"st21b-ep4.b16": (33_280, 66_048), "xing29b-ep8.b2": (5_632, 22_016)}[cell]
+    two_sizes = _held_experts_chunk(one_chip, cell)
+    text = two_sizes.as_text()
+    assert " conditional(" in text
+    assert f"[{small}," in text and f"[{full}," in text
+    # a branch: three products forward; in the backward pass the three again
+    # and their two transposes each
+    assert text.count('custom_call_target="tpu_custom_call"') >= 2 * (3 + 9)
+    assert _batched_products(text) == ""
+    monkeypatch.setattr(sparse_trunk, "EVEN_SHARE_ROOM", 64 // held)
+    one_size = _held_experts_chunk(one_chip, cell)
+    assert " conditional(" not in one_size.as_text() and f"[{small}," not in one_size.as_text()
+    assert (two_sizes.memory_analysis().temp_size_in_bytes
+            <= one_size.memory_analysis().temp_size_in_bytes)
 
 
 # ------------------------------ the latent trunk's whole step, one chip's share

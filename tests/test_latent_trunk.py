@@ -15,7 +15,7 @@ import pytest
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 
 from chipbench import reference_latent_trunk as ref  # noqa: E402
-from fedrec_tpu.models import latent_trunk  # noqa: E402
+from fedrec_tpu.models import latent_trunk, sparse_trunk  # noqa: E402
 from fedrec_tpu.models.bert import TextEncoder  # noqa: E402
 from fedrec_tpu.models.latent_trunk import LatentTrunkConfig  # noqa: E402
 
@@ -157,6 +157,28 @@ def test_each_planted_fault_is_seen(fault):
     assert float(jnp.max(jnp.abs(got - faulty))) > 0.02 * float(jnp.max(jnp.abs(got)))
 
 
+def test_one_expert_of_eight_runs_through_the_small_buffer(monkeypatch):
+    """1 of 8 experts held, tiles small enough for the sorted buffer to have
+    two sizes at test widths (96 pairs a layer: full 96 rows, small 24): the
+    trunk agrees with its reference and returns how many of its two routed
+    layers ran at the full size."""
+    monkeypatch.setattr(sparse_trunk, "ROW_TILE", 8)
+    assert sparse_trunk.buffer_rows(TITLES * LENGTH * 2, 1, 8) == (24, 96)
+    cfg = held(first_expert=3, experts_held=1)
+    te, params = encoder_and_params(cfg)
+    toks, t = tokens(), trunk_dict(cfg)
+    apply = lambda p, x: te.apply({"params": p}, x, mutable=["routing"])  # noqa: E731
+    assert "cond" in str(jax.make_jaxpr(apply)(params, toks))
+    with jax.default_matmul_precision("highest"):
+        want, g_want = vecs_and_grad_of(lambda p, x: ref.encode_news(p, x, t))(params, toks)
+        got, g_got = vecs_and_grad_of(lambda p, x: te.apply({"params": p}, x))(params, toks)
+        np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    assert max(rel_gaps(g_got, g_want)) < 2e-4
+    routing = apply(params, toks)[1]["routing"]
+    on_held = np.asarray(routing["expert_tokens"][0]).sum(axis=-1)       # (routed layers,)
+    assert int(routing["full_size_chunks"][0]) == int(np.sum(on_held > 24))
+
+
 def test_the_eight_shares_add_up_to_the_uncut_layer():
     """One routed feed-forward whole against its eight shares (1 of 8
     experts each). The shared expert is computed alike by every share and
@@ -174,7 +196,7 @@ def test_the_eight_shares_add_up_to_the_uncut_layer():
         for rank in range(8):
             cfg = LatentTrunkConfig(**TINY, first_expert=rank, experts_held=1, vocab_held=300)
             share = {**p, "experts": jax.tree_util.tree_map(lambda w: w[rank: rank + 1], p["experts"])}
-            out, counts = latent_trunk._RoutedFFN(cfg).apply({"params": share}, u)
+            out, counts, _ = latent_trunk._RoutedFFN(cfg).apply({"params": share}, u)
             total = total + (out - shared)
             pairs += int(jnp.sum(counts))
     np.testing.assert_allclose(total, uncut, rtol=2e-5, atol=2e-5)
@@ -224,7 +246,7 @@ def routed_layer_with_one_dominant_feature(cfg, bias=None):
 def test_no_token_is_dropped_when_every_token_picks_the_same_experts():
     cfg = LatentTrunkConfig(**TINY, first_expert=0, experts_held=4, vocab_held=300)
     ffn, p, u = routed_layer_with_one_dominant_feature(cfg)
-    out, counts = ffn.apply({"params": p}, u)
+    out, counts, _ = ffn.apply({"params": p}, u)
     assert counts.tolist() == [TITLES * LENGTH] * 2 + [0, 0]
     with jax.default_matmul_precision("highest"):
         want = ref.routed_ffn(p, u, trunk_dict(cfg), lambda v: v, None)
@@ -361,6 +383,9 @@ def test_trainer_round_with_the_trunk(clients, devices):
         snap = trainer.registry.snapshot()["metrics"]
         absent = snap["moe.absent_share"]["values"][0]["value"]
         assert 0.4 < absent < 0.95                        # 16 of 64 experts held
+        # 2,304 pairs a layer, a quarter of the experts held: within the
+        # small buffer's 1,536 rows of the full 2,560
+        assert snap["moe.full_size_chunks_total"]["values"][0]["value"] == 0
         cells = snap["moe.expert_tokens_total"]["values"]
         assert len(cells) == 2 * 16 and {c["labels"]["expert"] for c in cells} == {str(e) for e in range(16, 32)}
         steps = 32 // (8 * clients)

@@ -403,7 +403,7 @@ def _sparse_trunk_trainer(tmp_path):
     (lambda tmp: _trainer(tmp), 6),                   # two losses + four health.*
     (lambda tmp: _trainer(tmp, fed__strategy="grad_avg",
                           mesh=client_mesh(8, max_devices=4)), 6),
-    (_sparse_trunk_trainer, 8),                       # and the two moe.*
+    (_sparse_trunk_trainer, 9),                       # and the three moe.*
 ], ids=["param_avg", "grad_avg-cohorts", "sparse-expert-trunk"])
 def test_every_round_ends_in_one_read_of_host_arrays(tmp_path, monkeypatch, make, kept_keys):
     """Every round emits exactly one ``round_end`` span, inside its
@@ -429,7 +429,7 @@ def test_every_round_ends_in_one_read_of_host_arrays(tmp_path, monkeypatch, make
     assert len(seen["health"]) == rounds
     for kinds in seen["health"] + seen["routing"]:
         assert kinds and set(kinds) == {np.ndarray}
-    assert len(seen["routing"]) == (rounds if kept_keys == 8 else 0)
+    assert len(seen["routing"]) == (rounds if kept_keys == 9 else 0)
     hist = t.registry.snapshot()["metrics"]["train.round_end_seconds"]["values"]
     assert [cell["count"] for cell in hist] == [rounds]
 

@@ -146,15 +146,29 @@ def test_the_four_shares_add_up_to_the_uncut_layer():
         # x + attention, what every share computes alike: a share whose
         # experts' weights are nought adds nothing to it
         idle = {**layer_p, "experts": jax.tree_util.tree_map(jnp.zeros_like, layer_p["experts"])}
-        alike, _ = sparse_trunk._DecoderLayer(whole, True).apply({"params": idle}, x, mask)
+        alike, *_ = sparse_trunk._DecoderLayer(whole, True).apply({"params": idle}, x, mask)
         total, pairs = alike, 0
         for rank in range(4):
-            out, counts = share_output(2 * rank, 2)
+            out, counts, _ = share_output(2 * rank, 2)
             total = total + (out - alike)
             pairs += int(jnp.sum(counts))
     np.testing.assert_allclose(total, uncut, rtol=2e-5, atol=2e-5)
     # every (token, choice) pair fell on exactly one share's experts
     assert pairs == TITLES * LENGTH * whole.experts_per_token
+
+
+def dominated(params, cfg, columns):
+    """Feature 0 dominates every token's embedding, so after the norm it is
+    the same large positive number for all; the first layer's router reads
+    only that feature and ranks the experts alike for every token,
+    ``columns`` first."""
+    emb = np.asarray(params["trunk"]["embedding"]).copy()
+    emb[:, 0] = 50.0
+    router = np.zeros((cfg.dim, cfg.n_experts), np.float32)
+    router[0, columns] = np.arange(len(columns), 0, -1, dtype=np.float32)
+    layer0 = {**params["trunk"]["layer_0"], "router": jnp.asarray(router),
+              "attn_norm": {"scale": jnp.ones((cfg.dim,))}}
+    return {**params, "trunk": {**params["trunk"], "embedding": jnp.asarray(emb), "layer_0": layer0}}
 
 
 def test_no_token_is_dropped_when_every_token_picks_the_same_experts():
@@ -163,16 +177,7 @@ def test_no_token_is_dropped_when_every_token_picks_the_same_experts():
     cfg = SparseTrunkConfig(**{**TINY, "n_layers": 1}, first_expert=0, experts_held=4, vocab_held=300)
     te, params = encoder_and_params(cfg)
     toks, t = tokens(), trunk_dict(cfg)
-    # feature 0 dominates every token's embedding, so after the norm it is
-    # the same large positive number for all; a router that reads only that
-    # feature ranks the experts alike for every token: 0, 1, 2
-    emb = np.asarray(params["trunk"]["embedding"]).copy()
-    emb[:, 0] = 50.0
-    router = np.zeros((cfg.dim, cfg.n_experts), np.float32)
-    router[0, :3] = [3.0, 2.0, 1.0]
-    layer0 = {**params["trunk"]["layer_0"], "router": jnp.asarray(router),
-              "attn_norm": {"scale": jnp.ones((cfg.dim,))}}
-    params = {**params, "trunk": {**params["trunk"], "embedding": jnp.asarray(emb), "layer_0": layer0}}
+    params = dominated(params, cfg, [0, 1, 2])
     _, sown = te.apply({"params": params}, toks, mutable=["routing"])
     counts = np.asarray(sown["routing"]["expert_tokens"][0])
     assert counts.tolist() == [[TITLES * LENGTH] * 3 + [0]]
@@ -193,6 +198,134 @@ def test_chunked_expert_layer_equals_the_unchunked(monkeypatch):
     chunked, g_chunked = jax.value_and_grad(f)(params, toks)
     np.testing.assert_allclose(chunked, whole, rtol=1e-6)
     assert max(rel_gaps(g_chunked, g_whole)) < 1e-5
+
+
+# ------------------------------------------- the sorted buffer's two sizes
+QUARTER = dict(first_expert=2, experts_held=2, vocab_held=300)     # 2 of 8 held
+
+
+def small_tiles(monkeypatch):
+    """Tiles small enough for the buffer to have two sizes at test widths:
+    48 tokens x 3 choices = 144 pairs, full 144 rows, small 72 (twice the
+    even share of a quarter of the experts)."""
+    monkeypatch.setattr(sparse_trunk, "ROW_TILE", 8)
+
+
+def test_buffer_rows_follow_the_share_held():
+    # the two routed cells' chunks: 11,000 x 6 at 16 of 64, 5,500 x 4 at 8 of 64
+    assert sparse_trunk.buffer_rows(66_000, 16, 64) == (33_280, 66_048)
+    assert sparse_trunk.buffer_rows(22_000, 8, 64) == (5_632, 22_016)
+    # half the experts or more held: one size
+    assert sparse_trunk.buffer_rows(66_000, 32, 64) == (66_048, 66_048)
+    assert sparse_trunk.buffer_rows(66_000, 64, 64) == (66_048, 66_048)
+    assert sparse_trunk.buffer_rows(144, 4, 8) == (512, 512)
+
+
+@pytest.mark.parametrize("chunk_tokens", [None, 16], ids=["unchunked", "chunked"])
+def test_the_small_buffer_equals_the_full_one(monkeypatch, chunk_tokens):
+    """2 of 8 experts held: loss and gradients through the small buffer (72
+    rows, a conditional) equal those with the buffer forced to its full size
+    (144 rows, today's program), whole and in 3 chunks."""
+    small_tiles(monkeypatch)
+    if chunk_tokens:
+        monkeypatch.setattr(sparse_trunk, "MAX_CHUNK_TOKENS", chunk_tokens)
+    cfg = SparseTrunkConfig(**TINY, **QUARTER)
+    te, params = encoder_and_params(cfg)
+    toks = tokens()
+    pairs = (chunk_tokens or TITLES * LENGTH) * 3
+    assert sparse_trunk.buffer_rows(pairs, 2, 8) == (pairs // 2, pairs)
+
+    def f(p, x):
+        vecs, sown = te.apply({"params": p}, x, mutable=["routing"])
+        return jnp.sum(vecs ** 2), sown["routing"]
+
+    # a function of its own for each trace: one traced before the constant
+    # changed would be found again
+    (small, routing), g_small = jax.value_and_grad(f, has_aux=True)(params, toks)
+    assert "cond" in str(jax.make_jaxpr(lambda p, x: f(p, x))(params, toks))
+    # random routers at 5 layers: some chunk may overflow, most do not
+    assert int(routing["full_size_chunks"][0]) < 5 * (3 if chunk_tokens else 1)
+    monkeypatch.setattr(sparse_trunk, "EVEN_SHARE_ROOM", 4)       # room for every pair: one size
+    assert "cond" not in str(jax.make_jaxpr(lambda p, x: f(p, x))(params, toks))
+    (full, routing_full), g_full = jax.value_and_grad(f, has_aux=True)(params, toks)
+    assert int(routing_full["full_size_chunks"][0]) == 0
+    np.testing.assert_array_equal(routing["expert_tokens"][0], routing_full["expert_tokens"][0])
+    np.testing.assert_allclose(small, full, rtol=1e-6)
+    assert max(rel_gaps(g_small, g_full)) < 1e-5
+
+
+@pytest.mark.parametrize("chunk_tokens", [None, 16], ids=["unchunked", "chunked"])
+def test_a_chunk_that_overflows_the_small_buffer_runs_at_the_full_size(monkeypatch, chunk_tokens):
+    """Every token's top two choices are the two held experts: 96 of the 144
+    pairs land here, more than the small buffer's 72 rows. Output and
+    gradients still equal the float32 reference, every pair is counted and
+    ``full_size_chunks`` counts the chunks."""
+    small_tiles(monkeypatch)
+    if chunk_tokens:
+        monkeypatch.setattr(sparse_trunk, "MAX_CHUNK_TOKENS", chunk_tokens)
+    cfg = SparseTrunkConfig(**{**TINY, "n_layers": 1}, **QUARTER)
+    te, params = encoder_and_params(cfg)
+    params = dominated(params, cfg, [2, 3, 5])
+    toks, t = tokens(), trunk_dict(cfg)
+    with jax.default_matmul_precision("highest"):
+        got, sown = jax.jit(lambda p, x: te.apply({"params": p}, x, mutable=["routing"]))(params, toks)
+        np.testing.assert_allclose(got, ref.encode_news(params, toks, t), rtol=2e-5, atol=2e-5)
+        g_want = grad_of(lambda p, x: ref.encode_news(p, x, t))(params, toks)
+        g_got = grad_of(lambda p, x: te.apply({"params": p}, x))(params, toks)
+    # one dominant feature leaves attention's q and k a gradient of near
+    # cancellation: 1.2e-4 at either size of the buffer, the other leaves 3e-5
+    assert max(rel_gaps(g_got, g_want)) < 5e-4
+    assert np.asarray(sown["routing"]["expert_tokens"][0]).tolist() == [[TITLES * LENGTH] * 2]
+    assert int(sown["routing"]["full_size_chunks"][0]) == (3 if chunk_tokens else 1)
+
+
+def test_an_even_router_stays_in_the_small_buffer(monkeypatch):
+    """Every token's choices are experts 0, 2, 4: one pair in three on a held
+    expert (2), 48 rows of the small buffer's 72."""
+    small_tiles(monkeypatch)
+    cfg = SparseTrunkConfig(**{**TINY, "n_layers": 1}, **QUARTER)
+    te, params = encoder_and_params(cfg)
+    params = dominated(params, cfg, [0, 2, 4])
+    toks, t = tokens(), trunk_dict(cfg)
+    with jax.default_matmul_precision("highest"):
+        got, sown = te.apply({"params": params}, toks, mutable=["routing"])
+        np.testing.assert_allclose(got, ref.encode_news(params, toks, t), rtol=2e-5, atol=2e-5)
+    assert np.asarray(sown["routing"]["expert_tokens"][0]).tolist() == [[TITLES * LENGTH, 0]]
+    assert int(sown["routing"]["full_size_chunks"][0]) == 0
+
+
+@pytest.mark.parametrize("held", [4, 8])
+def test_half_the_experts_or_more_lower_without_a_conditional(monkeypatch, held):
+    small_tiles(monkeypatch)
+    cfg = SparseTrunkConfig(**TINY, experts_held=held, vocab_held=300)
+    te, params = encoder_and_params(cfg)
+    text = grad_of(lambda p, x: te.apply({"params": p}, x)).lower(params, tokens()).as_text()
+    assert "stablehlo.case" not in text and "stablehlo.if" not in text
+
+
+def test_a_cohort_under_vmap_runs_both_sizes_and_stays_exact(monkeypatch):
+    """Two clients under ``vmap``, one whose router overflows the small
+    buffer and one whose does not: the conditional is a select, each
+    client's output and gradient are what it gets alone."""
+    small_tiles(monkeypatch)
+    cfg = SparseTrunkConfig(**{**TINY, "n_layers": 1}, **QUARTER)
+    te, params = encoder_and_params(cfg)
+    clients = [dominated(params, cfg, [2, 3, 5]), dominated(params, cfg, [0, 2, 4])]
+    stacked = jax.tree_util.tree_map(lambda *x: jnp.stack(x), *clients)
+    toks = tokens()
+
+    def f(p):
+        vecs, sown = te.apply({"params": p}, toks, mutable=["routing"])
+        return jnp.sum(vecs ** 2), sown["routing"]["full_size_chunks"][0]
+
+    (loss, full), grads = jax.vmap(jax.value_and_grad(f, has_aux=True))(stacked)
+    assert full.tolist() == [1, 0]
+    for i, alone in enumerate(clients):
+        (want, _), g_want = jax.value_and_grad(f, has_aux=True)(alone)
+        np.testing.assert_allclose(loss[i], want, rtol=1e-6)
+        # batched products sum in another order: 3e-5 on the leaves of near
+        # cancellation
+        assert max(rel_gaps(jax.tree_util.tree_map(lambda x: x[i], grads), g_want)) < 2e-4
 
 
 def test_an_id_outside_the_held_vocabulary_embeds_to_zero():
@@ -277,6 +410,9 @@ def test_trainer_round_with_the_trunk(clients, devices):
         snap = trainer.registry.snapshot()["metrics"]
         absent = snap["moe.absent_share"]["values"][0]["value"]
         assert 0.4 < absent < 0.95                        # 16 of 64 experts held
+        # 3,456 pairs a layer, a quarter of the experts held: the routers'
+        # share stays within the small buffer's 2,048 rows of the full 3,584
+        assert snap["moe.full_size_chunks_total"]["values"][0]["value"] == 0
         cells = snap["moe.expert_tokens_total"]["values"]
         assert len(cells) == 4 * 16 and {c["labels"]["expert"] for c in cells} == {str(e) for e in range(16, 32)}
         steps = 32 // (8 * clients)
