@@ -24,7 +24,7 @@ class DataConfig:
     dataset: str = "mind"              # "mind" | "adressa" | "synthetic"
     npratio: int = 4                   # negatives per impression
     max_his_len: int = 50              # click-history cap (pad id 0 = <unk>)
-    max_title_len: int = 50            # tokens per news title
+    max_title_len: int = 50            # tokens per news text (a title: 50; a body: 1,024)
     batch_size: int = 64
     shuffle: bool = True
     seed: int = 0
@@ -105,14 +105,26 @@ class ModelConfig:
     #                     the published widths; reads the same fields, with
     #                     trunk_ffn one routed expert's width, and
     #                     trunk_dense_layers)
+    #   "window_moe"    — causal decoder whose full and window layers differ
+    #                     in head count, rotary and mask (grouped-query
+    #                     attention with normed q and k and a softplus gate
+    #                     a head, over a blocked core that holds no L x L
+    #                     array), a leading dense layer, then a sigmoid
+    #                     router over SwiGLU experts beside a shared expert;
+    #                     for texts longer than its window
+    #                     (models/window_trunk.py: WindowTrunkConfig holds
+    #                     the published widths, the two head counts and the
+    #                     layer kinds; reads the same fields, with
+    #                     trunk_heads the full layers' query heads)
     text_trunk: str = "distilbert"
     # routed trunks only: the share of every layer's experts held here (one
     # chip of an expert-parallel group): ids first..first+held-1 of the
     # family's experts; the router still scores all of them. 0 held = all.
     trunk_first_expert: int = 0
     trunk_experts_held: int = 0
-    # latent_moe only: the leading layers whose feed-forward is dense
-    # (published: first_k_dense_replace = 2); the others are routed
+    # latent_moe and window_moe: the leading layers whose feed-forward is
+    # dense (published: latent_moe's first_k_dense_replace = 2, window_moe's
+    # one leading "dense" entry of mlp_layer_types); the others are routed
     trunk_dense_layers: int = 2
     # trunk architecture for "finetune" mode (defaults = distilbert-base;
     # shrink for tests). dim is bert_hidden above.

@@ -273,6 +273,7 @@ TRUNK_COUNTERS = {
     "absent_share": "moe.absent_share",
     "full_size_chunks": "moe.full_size_chunks",
     "residual_mix_err": "trunk.residual_mix_err",
+    "attention_scores_computed_share": "trunk.attention_scores_computed_share",
 }
 
 
@@ -287,11 +288,15 @@ def trunk_families() -> dict[str, tuple]:
     from fedrec_tpu.models.sparse_trunk import (
         SparseExpertTrunk, SparseTrunkConfig, sparse_trunk_config_from,
     )
+    from fedrec_tpu.models.window_trunk import (
+        WindowMoETrunk, WindowTrunkConfig, window_trunk_config_from,
+    )
 
     return {
         "distilbert": (trunk_config_from, DistilBertConfig, DistilBert),
         "sparse_expert": (sparse_trunk_config_from, SparseTrunkConfig, SparseExpertTrunk),
         "latent_moe": (latent_trunk_config_from, LatentTrunkConfig, LatentMoETrunk),
+        "window_moe": (window_trunk_config_from, WindowTrunkConfig, WindowMoETrunk),
     }
 
 
@@ -321,7 +326,8 @@ def make_text_encoder(model_cfg) -> "TextEncoder":
 class TextEncoder(nn.Module):
     """Full text tower: trunk + additive-attention head. The trunk is the
     module ``trunk_families`` names for ``trunk_cfg``'s class: DistilBERT,
-    or a routed decoder (``models.sparse_trunk``, ``models.latent_trunk``)
+    or a routed decoder (``models.sparse_trunk``, ``models.latent_trunk``,
+    ``models.window_trunk``)
     that returns ``(states, counters)``; the counters are sown into the
     ``routing`` collection (``apply(..., mutable=["routing"])`` reads them).
 

@@ -163,24 +163,27 @@ def latent_trunk_config_from(model_cfg) -> LatentTrunkConfig:
 
 
 # ------------------------------------------------------------------ rotary
-def yarn_inv_freq(c: LatentTrunkConfig) -> jnp.ndarray:
+def yarn_inv_freq(
+    rope_dim: int, theta: float, factor: float, original_max: int,
+    beta_fast: float, beta_slow: float,
+) -> jnp.ndarray:
     """The rotary frequencies under YaRN: (rope_dim / 2,). Frequencies that
     turn more than ``beta_fast`` times inside the original context stay,
     those that turn less than ``beta_slow`` times are divided by ``factor``,
     a linear ramp blends the ones between. Nothing in it reads a position."""
-    half = c.rope_dim // 2
-    inv = c.rope_theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    half = rope_dim // 2
+    inv = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
 
     def correction_dim(turns: float) -> float:
-        return (c.rope_dim * math.log(c.rope_original_max / (turns * 2 * math.pi))
-                / (2 * math.log(c.rope_theta)))
+        return (rope_dim * math.log(original_max / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
 
-    low = max(math.floor(correction_dim(c.rope_beta_fast)), 0)
-    high = min(math.ceil(correction_dim(c.rope_beta_slow)), c.rope_dim - 1)
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), rope_dim - 1)
     if low == high:
         high += 0.001
     ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low) / (high - low), 0, 1)
-    return inv / c.rope_factor * ramp + inv * (1 - ramp)
+    return inv / factor * ramp + inv * (1 - ramp)
 
 
 def _mscale(factor: float, mscale: float) -> float:
@@ -198,7 +201,10 @@ def rope(x: jnp.ndarray, c: LatentTrunkConfig) -> jnp.ndarray:
     """x (N, L, ..., rope_dim): half-split pairs rotated by position x
     YaRN frequency, position = index along L."""
     half = c.rope_dim // 2
-    angle = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * yarn_inv_freq(c)
+    angle = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * yarn_inv_freq(
+        c.rope_dim, c.rope_theta, c.rope_factor, c.rope_original_max,
+        c.rope_beta_fast, c.rope_beta_slow,
+    )
     shape = (1, x.shape[1]) + (1,) * (x.ndim - 3) + (half,)
     scale = yarn_mscale(c)[1]
     cos = (jnp.cos(angle) * scale).reshape(shape)

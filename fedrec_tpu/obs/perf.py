@@ -127,6 +127,20 @@ def flops_per_train_step(
     pool = B * (2 * H * D * Q + 2 * H * Q)
     score = B * 2 * C * D
     fwd = text + mha + pool + score
+    if (
+        cfg.model.text_encoder_mode == "finetune"
+        and cfg.model.text_trunk == "window_moe"
+    ):
+        # the one trunk this model prices (the others' steps are counted at
+        # their head alone): its required products a token, every encoded
+        # text's L tokens through it
+        from fedrec_tpu.models.window_trunk import (
+            required_flops_per_token, window_trunk_config_from,
+        )
+
+        fwd += size * L * required_flops_per_token(
+            window_trunk_config_from(cfg.model), L
+        )
     return 3.0 * fwd  # fwd + ~2x fwd for backward
 
 

@@ -2870,6 +2870,18 @@ class Trainer:
             ).set(float(max(
                 np.max(r["trunk.residual_mix_err"]) for r in rows
             )))
+        if "trunk.attention_scores_computed_share" in rows[0]:
+            from fedrec_tpu.models.window_trunk import KINDS
+
+            g_share = reg.gauge(
+                "trunk.attention_scores_computed_share",
+                "score elements the blocked attention core computed over "
+                "the L^2 of a head's dense square, by kind of layer",
+                labels=("kind",),
+            )
+            share = rows[-1]["trunk.attention_scores_computed_share"]
+            for kind, value in zip(KINDS, share.reshape(-1, len(KINDS))[0]):
+                g_share.set(float(value), kind=kind)
 
     # ------------------------------------------- aggregation topologies
     def _agg_param_stacks(self) -> tuple[Any, Any]:

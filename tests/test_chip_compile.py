@@ -505,3 +505,38 @@ def test_latent_trunk_step_fits_one_chip_at_published_widths(topo):
     text = compiled.as_text()
     # 4 routed layers x 3 products x (forward, rematerialised, two transposes)
     assert text.count('custom_call_target="tpu_custom_call"') >= 48
+
+
+# ---------------------------- the window trunk's attention, one chunk of texts
+@pytest.mark.parametrize("kind,heads", [("full", 48), ("window", 64)])
+def test_window_trunk_attention_chunk_holds_no_square(topo, one_chip, kind, heads):
+    """One chunk (5 texts of 1,024 tokens) of ``laguna33b-ep8.b1``'s attention
+    sublayer at published widths (48 / 64 query heads over 8 key/value heads
+    of 128, window 512), value and gradient, compiles for one described v5e
+    chip: the blocked core's largest scores are one query block of 128
+    against its band (at most 640 keys in a window layer, 1,024 in a full
+    one), never a text's 1,024 x 1,024 square, and the chunk's temporaries
+    stay under 1.2 GB (the cell runs one text a chunk; five is the larger program)."""
+    from fedrec_tpu.models import window_trunk
+
+    cfg = window_trunk.WindowTrunkConfig(n_layers=5, experts_held=32, vocab_held=12544)
+    assert cfg.heads(kind) == heads
+    module = window_trunk._Attention(cfg, kind, jnp.bfloat16)
+    x, mask = _spec((5, 1024, 2048), "bfloat16", one_chip), _spec((5, 1024), "int32", one_chip)
+    params = jax.eval_shape(lambda x, m: module.init(jax.random.PRNGKey(0), x, m), x, mask)
+    params = jax.tree_util.tree_map(lambda a: _spec(a.shape, a.dtype, one_chip), params)
+
+    def loss(p, x, m):
+        return jnp.sum(module.apply(p, x, m).astype(jnp.float32))
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(params, x, mask).compile()
+    text = compiled.as_text()
+    # no L x L array, forward or backward: no array of a head's scores has
+    # the text's 1,024 queries beside its 1,024 keys
+    import re
+
+    shapes = {tuple(map(int, dims.split(","))) for dims in re.findall(r"(?:f32|bf16)\[([\d,]+)\]", text)}
+    assert not any(len(s) >= 4 and s.count(1024) >= 2 for s in shapes)
+    band = 640 if kind == "window" else 1024
+    assert f"f32[5,8,{heads // 8},128,{band}]" in text or f"f32[5,8,{band},128,{heads // 8}]" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.2e9

@@ -297,7 +297,8 @@ def test_yarn_leaves_fast_frequencies_and_divides_slow_ones():
     rotary's, 23-31 are divided by 64, the ramp blends the ones between;
     m = 0.1 ln 64 + 1; and the reference computes the same."""
     c = LatentTrunkConfig()
-    got = np.asarray(latent_trunk.yarn_inv_freq(c))
+    got = np.asarray(latent_trunk.yarn_inv_freq(
+        c.rope_dim, c.rope_theta, c.rope_factor, c.rope_original_max, c.rope_beta_fast, c.rope_beta_slow))
     plain = 10000.0 ** (-np.arange(32) / 32)
     np.testing.assert_allclose(got[:11], plain[:11], rtol=1e-6)
     np.testing.assert_allclose(got[23:], plain[23:] / 64, rtol=1e-6)
