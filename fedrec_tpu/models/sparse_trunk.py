@@ -38,17 +38,21 @@ follows the share of the experts held: ``EVEN_SHARE_ROOM`` (twice) what an
 even router sends here, a half of the full size with 16 of 64 experts held,
 a quarter with 8 of 64. Every pass over the buffer (the row gathers, the
 selects that zero its unwritten rows, the products' outputs and all their
-transposes) costs by its rows, whatever they hold. A chunk whose router sent
-more pairs here than the small size holds runs at the full size, one
-``lax.cond`` between the two sizes of the same body on the count the sort
-gives (``_either_size``), so a router that drifts is served exactly, at the
-worst case's cost; ``full_size_chunks`` counts those chunks. Twice, because
-the share on the held experts read within 0.7 to 1.5 of the even share over
-the benchmark's seeds (PERF.md section 6, PR 33 and 34). Where half the
-experts or more are held the two sizes are one and there is no conditional.
-Under an in-device cohort's ``vmap`` the count is per client, the
-conditional becomes a select and both sizes run for every client: exact, and
-slower than one size by the small one (test widths only so far).
+transposes) costs by its rows, whatever they hold. What runs over a token's
+k choices (the weighted sum of the products' output, and the cotangents'
+way back to the tokens) is k gathers of (T, d) rows into one float32 sum:
+no array is laid out pair by pair (``to_expert_order``, ``weighted_sum``).
+A chunk whose router sent more pairs here than the small size holds runs at
+the full size, one ``lax.cond`` between the two sizes of the same body on
+the count the sort gives (``_either_size``), so a router that drifts is
+served exactly, at the worst case's cost; ``full_size_chunks`` counts those
+chunks. Twice, because the share on the held experts read within 0.7 to 1.5
+of the even share over the benchmark's seeds (PERF.md section 6, PR 33 and
+34). Where half the experts or more are held the two sizes are one and there
+is no conditional. Under an in-device cohort's ``vmap`` the count is per
+client, the conditional becomes a select and both sizes run for every
+client: exact, and slower than one size by the small one (test widths only
+so far).
 
 The layer works through the tokens in chunks of at most ``MAX_CHUNK_TOKENS``,
 each chunk rematerialised in the backward pass: memory is bounded by one
@@ -230,50 +234,80 @@ def route(
     return idx.astype(jnp.int32), jax.nn.softmax(top, axis=-1)
 
 
-# Rows move between pair order (token t's choice c is pair t*k + c) and
-# expert order (pairs sorted by held expert, then the absent ones, then
-# padding) by gathers only: the sort gives the permutation and its inverse,
-# so each gather's cotangent goes back by a gather too, where the transpose
-# of a gather is a scatter (which a TPU runs row by row). The buffer may be
-# cut short of the absent pairs (``buffer_rows``): ``order`` is cut to its
-# rows, and a ``back`` that points past them is clipped and counted for
-# nothing.
-@partial(jax.custom_vjp, nondiff_argnums=(3,))
-def to_expert_order(u, order, back, k):
+# Rows move between token order and expert order (the (token, choice) pairs,
+# token t's choice c being pair t*k + c, sorted by held expert, then the
+# absent ones, then padding) by row gathers only, and no array is ever laid
+# out in pair order: ``order`` (rows,) names the pair of every row of the
+# buffer, ``back`` (k, T), choice-major, the row of every pair, and whatever
+# runs over a token's k choices is k passes over (T, d) rows into one float32
+# sum. (A (T, k, d) array has k = 4, 6 or 8 rows in a tile of 8 or 16, and
+# every reshape between it and (T*k, d) moves all of it: PERF.md section 6,
+# PR 36.) Each gather's cotangent goes back by a gather too, where the
+# transpose of a gather is a scatter (which a TPU runs row by row). The
+# buffer may be cut short of the absent pairs (``buffer_rows``): ``order`` is
+# cut to its rows, and a ``back`` that points past them is clipped and
+# counted for nothing.
+@jax.custom_vjp
+def to_expert_order(u, order, back):
     """(T, d) token rows -> (rows, d): row i is the token of pair order[i]."""
-    return u[jnp.minimum(order // k, u.shape[0] - 1)]
+    return u[jnp.minimum(order // back.shape[0], u.shape[0] - 1)]
 
 
-def _to_expert_order_bwd(k, back, ct):
-    rows, d = ct.shape
-    per_pair = ct[jnp.minimum(back, rows - 1)].reshape(-1, k, d)
-    per_pair = jnp.where((back < rows).reshape(-1, k, 1), per_pair, 0)
-    return jnp.sum(per_pair, axis=1, dtype=jnp.float32).astype(ct.dtype), None, None
+def _to_expert_order_bwd(back, ct):
+    rows = ct.shape[0]
+    total = 0.0
+    for choice in back:
+        picked = ct[jnp.minimum(choice, rows - 1)].astype(jnp.float32)
+        total = total + jnp.where((choice < rows)[:, None], picked, 0)
+    return total.astype(ct.dtype), None, None
 
 
 to_expert_order.defvjp(
-    lambda u, order, back, k: (to_expert_order(u, order, back, k), back),
+    lambda u, order, back: (to_expert_order(u, order, back), back),
     _to_expert_order_bwd,
 )
 
 
 @jax.custom_vjp
-def to_pair_order(y, order, back):
-    """(rows, d) in expert order -> (T*k, d) in pair order; a pair past the
-    buffer reads its last row (the caller gives that pair no weight)."""
-    return y[jnp.minimum(back, y.shape[0] - 1)]
+def weighted_sum(down, p, order, back):
+    """(rows, d) in expert order, weights (T, k) -> (T, d):
+    ``y[t] = sum_c p[t, c] * down[back[c, t]]``, the products of the rows'
+    dtype summed in float32; a pair past the buffer is on an absent expert
+    and adds nothing."""
+    return _weighted_sum_fwd(down, p, order, back)[0]
 
 
-def _to_pair_order_bwd(order, ct):
-    # the padding entries sort last: a buffer cut short holds none of them
-    padding = max(order.shape[0] - ct.shape[0], 0)
-    return jnp.pad(ct, ((0, padding), (0, 0)))[order], None, None
+def _weighted_sum_fwd(down, p, order, back):
+    rows = down.shape[0]
+    total = 0.0
+    for c, choice in enumerate(back):
+        weight = jnp.where(choice < rows, p[:, c], 0).astype(down.dtype)
+        picked = down[jnp.minimum(choice, rows - 1)].astype(jnp.float32)
+        total = total + picked * weight.astype(jnp.float32)[:, None]
+    # the sum is one pass over the k gathered arrays as long as it ends
+    # here: left open, XLA:TPU fuses a caller's reshape of the result into it
+    # and then converts each gathered array to float32 in a kernel of its own
+    # (laguna33b-ep8.b1: 51 ms a step; PERF.md section 6, PR 36)
+    return lax.optimization_barrier(total.astype(down.dtype)), (down, p, order, back)
 
 
-to_pair_order.defvjp(
-    lambda y, order, back: (to_pair_order(y, order, back), order),
-    _to_pair_order_bwd,
-)
+def _weighted_sum_bwd(res, ct):
+    down, p, order, back = res
+    rows, (t, k) = order.shape[0], p.shape
+    # a row of the buffer takes its own pair's weight and its token's
+    # cotangent; the padding entries (they sort last, past every pair) none
+    pair = jnp.minimum(order, t * k - 1)
+    weight = jnp.where(order < t * k, p.reshape(-1)[pair], 0).astype(ct.dtype)
+    of_token = ct[pair // k].astype(jnp.float32)
+    d_down = of_token * weight.astype(jnp.float32)[:, None]
+    # <ct[t], down[row of pair (t, c)]>, summed over d row by row of the
+    # buffer and read back pair by pair: scalars, not rows
+    along = jnp.sum(of_token * down.astype(jnp.float32), axis=-1)
+    d_p = jnp.where(back < rows, along[jnp.minimum(back, rows - 1)], 0).T
+    return d_down.astype(ct.dtype), d_p.astype(p.dtype), None, None
+
+
+weighted_sum.defvjp(_weighted_sum_fwd, _weighted_sum_bwd)
 
 
 def buffer_rows(pairs: int, held: int, n_experts: int) -> tuple[int, int]:
@@ -322,14 +356,13 @@ grouped_matmul.defvjp(
 )
 
 
-def _at_rows(rows, k, activation, ints, u, p, w_gate, w_up, w_down):
+def _at_rows(rows, activation, ints, u, p, w_gate, w_up, w_down):
     """One chunk's output through a sorted buffer of ``rows`` rows: the body
     of ``held_experts_output`` at one of its sizes. Rows past the held groups
     are pairs on absent experts (and padding): a grouped product leaves them
     unwritten, so they are zeroed on the way in and out (and, by the same
     selects transposed, in the backward pass)."""
     order, back, sizes = ints
-    t = u.shape[0]
     with jax.named_scope("moe_route"):
         order = order[:rows]
         # a no-op wherever this size's result is used; under a cohort's vmap
@@ -338,20 +371,14 @@ def _at_rows(rows, k, activation, ints, u, p, w_gate, w_up, w_down):
         ends = jnp.minimum(jnp.cumsum(sizes), rows)
         sizes = jnp.diff(ends, prepend=0)
         here = (jnp.arange(rows) < ends[-1])[:, None]
-        xs = jnp.where(here, to_expert_order(u, order, back, k), 0)
+        xs = jnp.where(here, to_expert_order(u, order, back), 0)
     with jax.named_scope("moe_experts"):
         gate = jnp.where(here, grouped_matmul(xs, w_gate, sizes), 0)
         up = jnp.where(here, grouped_matmul(xs, w_up, sizes), 0)
         down = grouped_matmul(activation(gate) * up, w_down, sizes)
         down = jnp.where(here, down, 0)
     with jax.named_scope("moe_combine"):
-        per_choice = to_pair_order(down, order, back).reshape(t, k, -1)
-        # a pair past the buffer is on an absent expert: it adds nothing
-        weight = jnp.where((back < rows).reshape(t, k), p, 0)
-        return jnp.einsum(
-            "tkd,tk->td", per_choice, weight.astype(down.dtype),
-            preferred_element_type=jnp.float32,
-        ).astype(down.dtype)
+        return weighted_sum(down, p, order, back)
 
 
 # The choice between the buffer's two sizes, differentiated by hand: left to
@@ -360,23 +387,23 @@ def _at_rows(rows, k, activation, ints, u, p, w_gate, w_up, w_down):
 # size. Here the forward keeps the chunk's inputs and the backward
 # conditional runs the taken size's forward again beside its transposes,
 # which is what the chunk's (and the layer's) rematerialisation does anyway.
-@partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
-def _either_size(sizes, k, activation, overflow, ints, *floats):
+@partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _either_size(sizes, activation, overflow, ints, *floats):
     small, full = sizes
     return lax.cond(
         overflow,
-        partial(_at_rows, full, k, activation),
-        partial(_at_rows, small, k, activation),
+        partial(_at_rows, full, activation),
+        partial(_at_rows, small, activation),
         ints, *floats,
     )
 
 
-def _either_size_bwd(sizes, k, activation, res, ct):
+def _either_size_bwd(sizes, activation, res, ct):
     overflow, ints, floats = res
 
     def transposes(rows):
         def run(ints, floats, ct):
-            return jax.vjp(partial(_at_rows, rows, k, activation, ints), *floats)[1](ct)
+            return jax.vjp(partial(_at_rows, rows, activation, ints), *floats)[1](ct)
         return run
 
     small, full = sizes
@@ -385,8 +412,8 @@ def _either_size_bwd(sizes, k, activation, res, ct):
 
 
 _either_size.defvjp(
-    lambda sizes, k, activation, overflow, ints, *floats: (
-        _either_size(sizes, k, activation, overflow, ints, *floats),
+    lambda sizes, activation, overflow, ints, *floats: (
+        _either_size(sizes, activation, overflow, ints, *floats),
         (overflow, ints, floats),
     ),
     _either_size_bwd,
@@ -427,15 +454,15 @@ def held_experts_output(
         # on a row count that is not a multiple of its tile
         group = jnp.pad(group, (0, full - t * k), constant_values=held)
         order = jnp.argsort(group, stable=True)
-        back = jnp.argsort(order)[: t * k]
+        back = jnp.argsort(order)[: t * k].reshape(t, k).T
         sizes = jnp.sum(
             group[:, None] == jnp.arange(held)[None, :], axis=0, dtype=jnp.int32
         )
     ints, floats = (order, back, sizes), (u, p, w_gate, w_up, w_down)
     if small == full:
-        return _at_rows(full, k, activation, ints, *floats), sizes, jnp.int32(0)
+        return _at_rows(full, activation, ints, *floats), sizes, jnp.int32(0)
     overflow = jnp.sum(sizes) > small
-    y = _either_size((small, full), k, activation, overflow, ints, *floats)
+    y = _either_size((small, full), activation, overflow, ints, *floats)
     return y, sizes, overflow.astype(jnp.int32)
 
 

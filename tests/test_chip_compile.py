@@ -369,11 +369,17 @@ def test_joint_step_compiles_for_four_chips(topo):
 
 
 # ------------------------------------------ the sparse-expert trunk's layer
-# one chunk of the expert layer in the two routed cells: tokens, choices a
-# token, experts held of 64, hidden and expert widths, the activation
+# one chunk of the expert layer in the three routed cells: tokens, choices a
+# token, experts held and in all, hidden and expert widths, the activation
 ROUTED_CHUNKS = {
-    "st21b-ep4.b16": (11_000, 6, 16, 2560, 768, jax.nn.relu),
-    "xing29b-ep8.b2": (5_500, 4, 8, 3584, 1024, jax.nn.silu),
+    "st21b-ep4.b16": (11_000, 6, 16, 64, 2560, 768, jax.nn.relu),
+    "xing29b-ep8.b2": (5_500, 4, 8, 64, 3584, 1024, jax.nn.silu),
+    "laguna33b-ep8.b1": (11_264, 8, 32, 256, 2048, 512, jax.nn.silu),
+}
+# the sorted buffer's small and full size in each
+BUFFER_ROWS = {
+    "st21b-ep4.b16": (33_280, 66_048), "xing29b-ep8.b2": (5_632, 22_016),
+    "laguna33b-ep8.b1": (22_528, 90_112),
 }
 
 
@@ -382,7 +388,7 @@ def _held_experts_chunk(one_chip, cell, clients=1):
     one chip."""
     from fedrec_tpu.models import sparse_trunk
 
-    tokens, k, held, d, f, activation = ROUTED_CHUNKS[cell]
+    tokens, k, held, experts, d, f, activation = ROUTED_CHUNKS[cell]
     lead = () if clients == 1 else (clients,)
     args = (
         _spec(lead + (tokens, d), "bfloat16", one_chip),
@@ -395,7 +401,7 @@ def _held_experts_chunk(one_chip, cell, clients=1):
 
     def loss(u, idx, p, w_gate, w_up, w_down):
         y, sizes, full_size = sparse_trunk.held_experts_output(
-            u, idx, p, w_gate, w_up, w_down, 0, 64, activation)
+            u, idx, p, w_gate, w_up, w_down, 0, experts, activation)
         return jnp.sum(y.astype(jnp.float32)), (sizes, full_size)
 
     def both(*a):
@@ -429,15 +435,15 @@ def test_held_experts_compile_at_published_widths(topo, one_chip, clients):
 @pytest.mark.parametrize("cell", sorted(ROUTED_CHUNKS))
 def test_held_experts_choose_between_two_buffer_sizes(topo, one_chip, monkeypatch, cell):
     """The chunk of each routed cell compiles with a conditional between the
-    sorted buffer's small size (33,280 / 5,632 rows) and its full one
-    (66,048 / 22,016), forward and backward, the grouped products un-batched
-    in both branches; the full-size branch is the larger, so the program
-    needs no more temporary memory than with one size, the full one."""
+    sorted buffer's small size and its full one (``BUFFER_ROWS``), forward
+    and backward, the grouped products un-batched in both branches; the
+    full-size branch is the larger, so the program needs no more temporary
+    memory than with one size, the full one."""
     from fedrec_tpu.models import sparse_trunk
 
-    tokens, k, held, *_ = ROUTED_CHUNKS[cell]
-    small, full = sparse_trunk.buffer_rows(tokens * k, held, 64)
-    assert (small, full) == {"st21b-ep4.b16": (33_280, 66_048), "xing29b-ep8.b2": (5_632, 22_016)}[cell]
+    tokens, k, held, experts, *_ = ROUTED_CHUNKS[cell]
+    small, full = sparse_trunk.buffer_rows(tokens * k, held, experts)
+    assert (small, full) == BUFFER_ROWS[cell]
     two_sizes = _held_experts_chunk(one_chip, cell)
     text = two_sizes.as_text()
     assert " conditional(" in text
@@ -446,11 +452,31 @@ def test_held_experts_choose_between_two_buffer_sizes(topo, one_chip, monkeypatc
     # and their two transposes each
     assert text.count('custom_call_target="tpu_custom_call"') >= 2 * (3 + 9)
     assert _batched_products(text) == ""
-    monkeypatch.setattr(sparse_trunk, "EVEN_SHARE_ROOM", 64 // held)
+    monkeypatch.setattr(sparse_trunk, "EVEN_SHARE_ROOM", experts // held)
     one_size = _held_experts_chunk(one_chip, cell)
     assert " conditional(" not in one_size.as_text() and f"[{small}," not in one_size.as_text()
     assert (two_sizes.memory_analysis().temp_size_in_bytes
             <= one_size.memory_analysis().temp_size_in_bytes)
+
+
+@pytest.mark.parametrize("cell", sorted(ROUTED_CHUNKS))
+def test_held_experts_lay_no_rows_out_in_pair_order(topo, one_chip, cell):
+    """The compiled chunk of each routed cell, forward and backward, holds no
+    value of shape ``[T,k,d]`` or ``[T*k,d]``: with 4, 6 or 8 choices as the
+    second-minor axis every row tile of 8 (float32) or 16 (bfloat16) is
+    padded, and each reshape between the two moves the array (PERF.md
+    section 6, PR 36). What runs over a token's choices is k passes over
+    ``[T,d]``: the only row counts beside the tokens' are the buffer's two
+    sizes (in ``laguna33b-ep8.b1`` the pairs are whole tiles, so the full
+    size has ``T*k`` rows itself)."""
+    import re
+
+    tokens, k, _, _, d, *_ = ROUTED_CHUNKS[cell]
+    text = _held_experts_chunk(one_chip, cell).as_text()
+    shapes = {tuple(map(int, dims.split(","))) for dims in re.findall(r"\w+\[([\d,]+)\]", text)}
+    assert not {(tokens, k, d), (k, tokens, d)} & shapes
+    assert {s[0] for s in shapes if len(s) == 2 and s[1] == d and s[0] >= tokens} == {
+        tokens, *BUFFER_ROWS[cell]}
 
 
 # ------------------------------ the latent trunk's whole step, one chip's share

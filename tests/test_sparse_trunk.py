@@ -328,6 +328,84 @@ def test_a_cohort_under_vmap_runs_both_sizes_and_stays_exact(monkeypatch):
         assert max(rel_gaps(jax.tree_util.tree_map(lambda x: x[i], grads), g_want)) < 2e-4
 
 
+# ------------------------------ the combine and the gathers, choice by choice
+def pair_order_case(k, tokens_=13, d=8, held=3, experts=8, cut=16, seed=0):
+    """One chunk's integers as ``held_experts_output`` builds them and random
+    float32 rows: 13 tokens (not a multiple of 16), a buffer of ``cut`` rows,
+    short of the pairs, so that some ``back`` point past it; ``order`` keeps
+    padding entries (pair numbers past T*k) when uncut."""
+    rng = np.random.default_rng(seed)
+    idx = np.stack([rng.permutation(experts)[:k] for _ in range(tokens_)])
+    full = -(-tokens_ * k // 8) * 8 + 8                 # a tile of padding at least
+    group = np.where(idx.reshape(-1) < held, idx.reshape(-1), held)
+    group = np.pad(group, (0, full - tokens_ * k), constant_values=held)
+    order = np.argsort(group, kind="stable")
+    back = np.argsort(order)[: tokens_ * k]
+    rows = cut or full
+    assert (back >= rows).any() == bool(cut) and (order[:rows] >= tokens_ * k).any() != bool(cut)
+    f32 = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)  # noqa: E731
+    p = jax.nn.softmax(f32(tokens_, k), axis=-1)
+    return (jnp.asarray(order[:rows], jnp.int32), jnp.asarray(back, jnp.int32),
+            f32(rows, d), p, f32(tokens_, d))
+
+
+def in_pair_order(down, p, u, order, back, k):
+    """The same layer with its rows laid out in pair order, written plainly:
+    gather every pair's row, (T, k, d), a weighted sum over k; the way into
+    expert order as the plain gather whose transpose JAX derives."""
+    t, rows = p.shape[0], down.shape[0]
+    weight = jnp.where((back < rows).reshape(t, k), p, 0)
+    y = jnp.einsum("tkd,tk->td", down[jnp.minimum(back, rows - 1)].reshape(t, k, -1), weight)
+    token = jnp.minimum(order // k, t - 1)
+    xs = jnp.where((order < t * k)[:, None], u[token], 0)
+    return y, xs
+
+
+@pytest.mark.parametrize("cut", [16, 0], ids=["buffer-cut-short", "padding-in-order"])
+@pytest.mark.parametrize("k", [4, 6, 8])
+def test_choice_by_choice_equals_the_pair_order_formulation(k, cut):
+    """``weighted_sum`` and ``to_expert_order`` with their hand-written
+    derivatives against the pair-order formulation and ``jax.grad``, in
+    float32: forward, and the gradients with respect to ``down``, ``p`` and
+    ``u``. A pair past a buffer cut short adds nothing and takes no gradient;
+    a padding entry of ``order`` takes none."""
+    order, back, down, p, u = pair_order_case(k, cut=cut, seed=k)
+    t = p.shape[0]
+    choice_major = back.reshape(t, k).T
+    mix = jnp.asarray(np.random.default_rng(1).normal(size=(t, 1)), jnp.float32)
+    # padding rows of the buffer are zeroed by the caller (``here``): the
+    # plain gather states that itself, so the program's side is held to it
+    real = (order < t * k)[:, None]
+
+    def got(down, p, u):
+        y = sparse_trunk.weighted_sum(down, p, order, choice_major)
+        xs = jnp.where(real, sparse_trunk.to_expert_order(u, order, choice_major), 0)
+        return y, xs
+
+    def want(down, p, u):
+        return in_pair_order(down, p, u, order, back, k)
+
+    def loss(f):
+        def scalar(down, p, u):
+            y, xs = f(down, p, u)
+            return jnp.sum(mix * y ** 2) + jnp.sum(jnp.cos(xs) * jnp.arange(xs.shape[0])[:, None])
+        return scalar
+
+    for a, b in zip(got(down, p, u), want(down, p, u)):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+    g_got = jax.grad(loss(got), argnums=(0, 1, 2))(down, p, u)
+    g_want = jax.grad(loss(want), argnums=(0, 1, 2))(down, p, u)
+    for a, b in zip(g_got, g_want):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
+    rows = down.shape[0]
+    past = np.asarray(back.reshape(t, k) >= rows)
+    if cut:
+        # pairs past the buffer take no weight gradient
+        assert past.any() and not np.asarray(g_got[1])[past].any()
+    else:
+        assert not np.asarray(g_got[0])[np.asarray(order) >= t * k].any()
+
+
 def test_an_id_outside_the_held_vocabulary_embeds_to_zero():
     cfg = SparseTrunkConfig(**{**TINY, "n_layers": 1}, experts_held=8, vocab_first=100, vocab_held=100)
     trunk = sparse_trunk.SparseExpertTrunk(cfg)
