@@ -14,6 +14,7 @@ fine-tune) is the caller's choice — see ``fedrec_tpu.train``.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import optax
 from flax import linen as nn
@@ -33,7 +34,8 @@ def score_candidates(cand_vecs: jnp.ndarray, user_vec: jnp.ndarray) -> jnp.ndarr
     The reference's ``torch.bmm(candidate_vecs, user_vector.unsqueeze(-1))``
     (``model.py:121``) as one einsum; XLA maps it onto the MXU.
     """
-    return jnp.einsum("...cd,...d->...c", cand_vecs, user_vec)
+    with jax.named_scope("score_loss"):
+        return jnp.einsum("...cd,...d->...c", cand_vecs, user_vec)
 
 
 def score_loss(
@@ -53,10 +55,11 @@ def score_loss(
     # would re-quantize): under a bfloat16 model the softmax/log lose ~3
     # decimal digits, quantizing the loss metric (visibly: a constant
     # 0.65625 across rounds) and coarsening gradients near convergence
-    scores = scores.astype(jnp.float32)
-    logits = nn.sigmoid(scores) if sigmoid_before_ce else scores
-    per_row = optax.softmax_cross_entropy_with_integer_labels(logits, labels)
-    return jnp.mean(per_row) if reduce else per_row
+    with jax.named_scope("score_loss"):
+        scores = scores.astype(jnp.float32)
+        logits = nn.sigmoid(scores) if sigmoid_before_ce else scores
+        per_row = optax.softmax_cross_entropy_with_integer_labels(logits, labels)
+        return jnp.mean(per_row) if reduce else per_row
 
 
 class NewsRecommender(nn.Module):

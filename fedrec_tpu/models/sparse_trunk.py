@@ -486,13 +486,15 @@ class HeldExperts(nn.Module):
             1.0, "fan_in", "normal", in_axis=-2, out_axis=-1, batch_axis=(0,)
         )
         held, d, f = c.experts_held, c.dim, c.expert_dim
-        weights = tuple(
-            self.param(name, init, shape).astype(self.dtype)
-            for name, shape in (
-                ("w_gate", (held, d, f)), ("w_up", (held, d, f)),
-                ("w_down", (held, f, d)),
+        # the copies of the three stacks in the dtype to compute in
+        with jax.named_scope("weight_cast"):
+            weights = tuple(
+                self.param(name, init, shape).astype(self.dtype)
+                for name, shape in (
+                    ("w_gate", (held, d, f)), ("w_up", (held, d, f)),
+                    ("w_down", (held, f, d)),
+                )
             )
-        )
 
         def chunk(args):
             return held_experts_output(
@@ -504,10 +506,13 @@ class HeldExperts(nn.Module):
             y, sizes, full_size = chunk((u, idx, p))
         else:
             split = lambda x: x.reshape(n, -1, x.shape[-1])  # noqa: E731
-            y, sizes, full_size = lax.map(
-                jax.checkpoint(chunk), (split(u), split(idx), split(p))
-            )
-            y, sizes, full_size = y.reshape(u.shape), jnp.sum(sizes, axis=0), jnp.sum(full_size)
+            # the loop's own ops (a chunk's slice in, its rows stacked
+            # out); the chunk's ops keep their innermost scopes
+            with jax.named_scope("chunk_stack"):
+                y, sizes, full_size = lax.map(
+                    jax.checkpoint(chunk), (split(u), split(idx), split(p))
+                )
+                y, sizes, full_size = y.reshape(u.shape), jnp.sum(sizes, axis=0), jnp.sum(full_size)
         return checkpoint_name(y, HELD_EXPERTS_OUTPUT), sizes, full_size
 
 

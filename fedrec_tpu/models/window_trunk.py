@@ -339,8 +339,13 @@ class _OverTextChunks(nn.Module):
             step, variable_broadcast="params", split_rngs={"params": False},
         )
         split = lambda a: a.reshape((chunks, n // chunks) + a.shape[1:])  # noqa: E731
-        _, (y, *counters) = scan(body(*self.args, name="chunk"), None, (split(x), split(mask)))
-        return (y.reshape(n, L, d), *(jnp.sum(v, axis=0) for v in counters))
+        # the loop's own ops (a chunk's slice in, its rows stacked out); the
+        # body's ops keep their innermost scopes
+        with jax.named_scope("chunk_stack"):
+            _, (y, *counters) = scan(
+                body(*self.args, name="chunk"), None, (split(x), split(mask))
+            )
+            return (y.reshape(n, L, d), *(jnp.sum(v, axis=0) for v in counters))
 
 
 class WindowMoETrunk(nn.Module):

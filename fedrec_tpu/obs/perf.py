@@ -404,10 +404,11 @@ def append_jsonl_record(path, record: dict) -> None:
 class PerfMonitor:
     """Per-round efficiency gauges + triggered capture windows.
 
-    Constructed by the Trainer only when ``obs.perf.enabled``; reads the
+    Constructed by the Trainer only when ``obs.perf.enabled``; takes the
     round's ``batch_build``/``h2d``/``dispatch``/``aggregate``/``eval``
-    span timings straight off the tracer (the same spans the trace
-    artifact carries — no second clock), prices the round with the
+    span timings from the Trainer's one digest of the round's spans (the
+    same spans the trace artifact carries — no second clock, no second
+    pass over the events), prices the round with the
     analytic FLOPs model, and publishes:
 
     * ``perf.samples_per_sec`` / ``perf.mfu`` / ``perf.hbm_fraction``
@@ -520,7 +521,6 @@ class PerfMonitor:
         self._steps_counter = self.registry.counter(
             "train.steps_total", "train-step batches dispatched"
         )
-        self._mark_events = 0
         self._mark_steps = 0.0
         self._mark_dropped = 0
         self._rates: list[float] = []
@@ -557,28 +557,26 @@ class PerfMonitor:
         self._g_step_flops.set(self.flops_per_step)
 
     def begin_round(self) -> None:
-        """Mark the tracer/step-counter positions a round's digest diffs
-        against; call at round entry."""
-        self._mark_events = self.tracer.event_count()
+        """Mark the step-counter and dropped-span positions a round's
+        digest diffs against; call at round entry."""
         self._mark_steps = self._steps_counter.value()
         self._mark_dropped = self.tracer.dropped
 
     def observe_round(
-        self, round_idx: int, wall_s: float
+        self, round_idx: int, wall_s: float, span_seconds: dict[str, float]
     ) -> dict[str, Any]:
-        """Digest the round that just finished:
-        publish the gauges and return the per-round log keys
-        (``perf.samples_per_sec`` / ``perf.mfu`` / ``perf.verdict``)."""
+        """Digest the round that just finished: publish the gauges and
+        return the per-round log keys (``perf.samples_per_sec`` /
+        ``perf.mfu`` / ``perf.verdict``). ``span_seconds``: the round's
+        spans summed by name, the Trainer's one digest of them
+        (``obs.tracing.RoundDigest``)."""
         steps = self._steps_counter.value() - self._mark_steps
         # a saturated tracer ring (obs.trace_capacity) drops NEW spans —
         # this round's phase sums would then be silently empty, and an
         # input-bound round would masquerade as 'headroom'. Missing data
         # publishes NO verdict, never a wrong one.
         traced = self.tracer.dropped == self._mark_dropped
-        phases = {p: 0.0 for p in self.PHASES}
-        for ev in self.tracer.events_since(self._mark_events):
-            if ev.get("ph") == "X" and ev.get("name") in phases:
-                phases[ev["name"]] += float(ev.get("dur", 0.0)) / 1e6
+        phases = {p: span_seconds.get(p, 0.0) for p in self.PHASES}
         out: dict[str, Any] = {}
         # the eval span is excluded from the efficiency denominators so an
         # eval-cadence round's MFU/throughput stays comparable to a
@@ -671,9 +669,9 @@ class PerfMonitor:
             return None
         logdir = self.obs_dir / f"perf_capture_r{round_idx:04d}"
         try:
-            import jax
+            from fedrec_tpu.utils.profiling import start_device_trace
 
-            jax.profiler.start_trace(str(logdir))
+            start_device_trace(logdir)
         except Exception:  # noqa: BLE001 — e.g. train.profile already tracing
             self._c_capture_failures.inc()
             return None
@@ -700,9 +698,9 @@ class PerfMonitor:
     def _stop_capture(self, last_round_idx: int) -> None:
         active, self._active = self._active, None
         try:
-            import jax
+            from fedrec_tpu.utils.profiling import stop_device_trace
 
-            jax.profiler.stop_trace()
+            stop_device_trace()
         except Exception:  # noqa: BLE001
             self._c_capture_failures.inc()
             return

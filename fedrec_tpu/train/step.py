@@ -96,11 +96,16 @@ def _tree_global_norm(*trees: Any) -> jnp.ndarray:
 
 def _unstack(tree: Any) -> Any:
     """Strip the local leading block dim (size 1) inside shard_map."""
-    return jax.tree_util.tree_map(lambda x: x[0], tree)
+    with jax.named_scope("client_axis"):
+        return jax.tree_util.tree_map(lambda x: x[0], tree)
 
 
 def _restack(tree: Any) -> Any:
-    return jax.tree_util.tree_map(lambda x: x[None], tree)
+    """The block dim back on. No op of its own once compiled: XLA names the
+    fusion that writes a leaf of the new state (Adam's sweep, the parameter
+    add) after this, its last, op."""
+    with jax.named_scope("client_axis"):
+        return jax.tree_util.tree_map(lambda x: x[None], tree)
 
 
 def _apply_update_fault(tree: Any, code: jnp.ndarray, scale: jnp.ndarray) -> Any:
@@ -340,17 +345,20 @@ def _encode_gathered(
         frozen = lax.stop_gradient(token_states)
 
         def encode(ids):
-            return checkpoint_name(
-                fused_gather_encode(
-                    frozen, ids, news_params, dtype=model.cfg.dtype
-                ),
-                "token_gather",
-            )
+            with jax.named_scope("news_gather"):
+                return checkpoint_name(
+                    fused_gather_encode(
+                        frozen, ids, news_params, dtype=model.cfg.dtype
+                    ),
+                    "token_gather",
+                )
     else:
         def encode(ids):
-            states = checkpoint_name(
-                lax.stop_gradient(gather_fn(token_states, ids)), "token_gather"
-            )
+            with jax.named_scope("news_gather"):
+                states = checkpoint_name(
+                    lax.stop_gradient(gather_fn(token_states, ids)),
+                    "token_gather",
+                )
 
             def head(params, rows):
                 return model.apply(
@@ -360,10 +368,13 @@ def _encode_gathered(
                 )
 
             if batch_of_one:
-                return jax.vmap(head)(
-                    jax.tree_util.tree_map(lambda x: x[None], news_params),
-                    states[None],
-                )[0]
+                # the batch axis' own ops (its transpose sums the head's
+                # gradients over the one client) are the head's
+                with jax.named_scope("text_head"):
+                    return jax.vmap(head)(
+                        jax.tree_util.tree_map(lambda x: x[None], news_params),
+                        states[None],
+                    )[0]
             return head(news_params, states)
 
     u = uniq.shape[0]
@@ -505,20 +516,22 @@ def _batch_news_vecs(
                 f"the batch's {b * (c + h)} news slots"
             )
     else:
-        ids = jnp.concatenate([candidates.reshape(-1), history.reshape(-1)])
         if n_news is None:
             n_news = token_states.shape[0]
-        uniq, inv = jnp.unique(
-            ids, size=min(ids.shape[0], n_news), fill_value=0,
-            return_inverse=True,
-        )
+        with jax.named_scope("news_dedup"):
+            ids = jnp.concatenate([candidates.reshape(-1), history.reshape(-1)])
+            uniq, inv = jnp.unique(
+                ids, size=min(ids.shape[0], n_news), fill_value=0,
+                return_inverse=True,
+            )
     vecs = _encode_gathered(
         model, news_params, token_states, uniq, chunk, fused=fused,
         gather_fn=gather_fn, batch_of_one=batch_of_one,
     )
-    flat = vecs[inv]
-    cand_vecs = flat[: b * c].reshape(b, c, -1)
-    his_vecs = flat[b * c :].reshape(b, h, -1)
+    with jax.named_scope("news_gather"):
+        flat = vecs[inv]
+        cand_vecs = flat[: b * c].reshape(b, c, -1)
+        his_vecs = flat[b * c :].reshape(b, h, -1)
     return cand_vecs, his_vecs
 
 
@@ -537,8 +550,10 @@ def _encode_unique_tokens(
     empty for a dense trunk), under their ``TRUNK_COUNTERS`` metric names.
     """
     size = min(ids.shape[0], tokens_table.shape[0])
-    uniq, inv = jnp.unique(ids, size=size, fill_value=0, return_inverse=True)
-    toks = tokens_table[uniq]  # (size, 2, L)
+    with jax.named_scope("news_dedup"):
+        uniq, inv = jnp.unique(ids, size=size, fill_value=0, return_inverse=True)
+    with jax.named_scope("news_gather"):
+        toks = tokens_table[uniq]  # (size, 2, L)
     train = dropout_rng is not None
     vecs, sown = text_encoder.apply(
         {"params": news_params},
@@ -548,7 +563,8 @@ def _encode_unique_tokens(
         mutable=["routing"],
     )  # (size, D)
     routing = {TRUNK_COUNTERS[k]: v[0] for k, v in sown.get("routing", {}).items()}
-    return vecs[inv], routing
+    with jax.named_scope("news_gather"):
+        return vecs[inv], routing
 
 
 def _batch_news_vecs_tokens(
@@ -564,12 +580,14 @@ def _batch_news_vecs_tokens(
     with the trunk's routing counters."""
     b, c = candidates.shape
     h = history.shape[1]
-    ids = jnp.concatenate([candidates.reshape(-1), history.reshape(-1)])
+    with jax.named_scope("news_dedup"):
+        ids = jnp.concatenate([candidates.reshape(-1), history.reshape(-1)])
     flat, routing = _encode_unique_tokens(
         text_encoder, news_params, tokens_table, ids, dropout_rng
     )
-    cand_vecs = flat[: b * c].reshape(b, c, -1)
-    his_vecs = flat[b * c :].reshape(b, h, -1)
+    with jax.named_scope("news_gather"):
+        cand_vecs = flat[: b * c].reshape(b, c, -1)
+        his_vecs = flat[b * c :].reshape(b, h, -1)
     return cand_vecs, his_vecs, routing
 
 
@@ -727,6 +745,25 @@ def _reshard_state_out(fn: Callable, state_shardings: Any) -> Callable:
 
 
 # ------------------------------------------------------------- train steps
+# The words under which every op of ``jit_sharded_step`` and
+# ``jit_sharded_sync`` that takes time is found on a device trace (an op's
+# ``tf_op`` path; the innermost, the one named last, is the op's scope):
+# ``jax.named_scope``s opened here, in ``models/bert.py`` and in the three
+# routed trunks, and the Flax module names ``text_head``, ``user_encoder``
+# and ``trunk`` (the rest of a trunk: residual adds, the final norm).
+DEVICE_SCOPES = (
+    # this file
+    "news_dedup", "news_gather", "score_loss", "grad_sync", "dp_clip",
+    "dp_noise", "optimizer", "health_sentry", "param_sync", "client_axis",
+    # the towers, by module name
+    "text_head", "user_encoder", "trunk",
+    # the routed trunks (models/sparse_trunk.py, latent_trunk.py, window_trunk.py)
+    "trunk_embed", "trunk_attention", "moe_route", "moe_experts", "moe_combine",
+    "weight_cast", "chunk_stack", "residual_mix", "latent_attention",
+    "dense_ffn", "shared_expert", "window_attention", "attention_core",
+)
+
+
 def _build_local_step(
     model: NewsRecommender,
     cfg: ExperimentConfig,
@@ -934,10 +971,12 @@ def _build_local_step(
                 def per_example_loss(packed, cand_row, his_row, label, ex_rng):
                     user_params, news_params = packed
                     c = cand_row.shape[0]
-                    ids = jnp.concatenate([cand_row, his_row])
+                    with jax.named_scope("news_gather"):
+                        ids = jnp.concatenate([cand_row, his_row])
+                        rows = table[ids]
                     vecs = model.apply(
                         {"params": {"text_head": news_params}},
-                        table[ids],
+                        rows,
                         method=NewsRecommender.encode_news,
                     )
                     scores = model.apply(
@@ -956,26 +995,30 @@ def _build_local_step(
                 batch_args = (
                     batch["candidates"], batch["history"], batch["labels"], ex_rngs,
                 )
+                # the clipping's own ops (norms, scales, the mean over
+                # examples); the loss inside keeps its innermost scopes
                 if dp_user_only:
-                    out = per_example_clipped_grads(
-                        lambda up, c, h, l, r: per_example_loss(
-                            (up, state.news_params), c, h, l, r
-                        ),
-                        state.user_params,
-                        batch_args,
-                        cfg.privacy.clip_norm,
-                        with_stats=sentry,
-                    )
+                    with jax.named_scope("dp_clip"):
+                        out = per_example_clipped_grads(
+                            lambda up, c, h, l, r: per_example_loss(
+                                (up, state.news_params), c, h, l, r
+                            ),
+                            state.user_params,
+                            batch_args,
+                            cfg.privacy.clip_norm,
+                            with_stats=sentry,
+                        )
                     loss, user_g = out[0], out[1]
                     news_g = None  # head frozen: no grad exists to leak
                 else:
-                    out = per_example_clipped_grads(
-                        per_example_loss,
-                        (state.user_params, state.news_params),
-                        batch_args,
-                        cfg.privacy.clip_norm,
-                        with_stats=sentry,
-                    )
+                    with jax.named_scope("dp_clip"):
+                        out = per_example_clipped_grads(
+                            per_example_loss,
+                            (state.user_params, state.news_params),
+                            batch_args,
+                            cfg.privacy.clip_norm,
+                            with_stats=sentry,
+                        )
                     loss, (user_g, news_g) = out[0], out[1]
                 dp_stats = out[2] if sentry else None
             else:
@@ -1044,63 +1087,73 @@ def _build_local_step(
                 if n_seq > 1:
                     # each seq shard holds a partial param grad (its history
                     # slice); sum -> full grad, replicated over seq
-                    user_g = jax.tree_util.tree_map(
-                        lambda g: lax.psum(g, seq_ax), user_g
-                    )
-                    news_g = jax.tree_util.tree_map(
-                        lambda g: lax.psum(g, seq_ax), news_g
-                    )
+                    with jax.named_scope("grad_sync"):
+                        user_g = jax.tree_util.tree_map(
+                            lambda g: lax.psum(g, seq_ax), user_g
+                        )
+                        news_g = jax.tree_util.tree_map(
+                            lambda g: lax.psum(g, seq_ax), news_g
+                        )
             if noise_fn is not None:
-                if news_g is None:
-                    (user_g,) = noise_fn((user_g,), noise_rng)
-                else:
-                    user_g, news_g = noise_fn((user_g, news_g), noise_rng)
+                with jax.named_scope("dp_noise"):
+                    if news_g is None:
+                        (user_g,) = noise_fn((user_g,), noise_rng)
+                    else:
+                        user_g, news_g = noise_fn((user_g, news_g), noise_rng)
             # sentry sees the PER-CLIENT grads (post-noise, pre-sync): the
             # synced mean is what steps the optimizer, but a diverging or
             # poisoned client is only visible before the collective blends
             # its gradient into the cohort's
             sentry_grads = (user_g, news_g)
-            user_g = strategy.sync_grads(user_g, sync_axes)
-            u_updates, opt_user = opt_user_tx.update(user_g, state.opt_user, state.user_params)
-            if chaos:
-                # fault AT the update boundary: the sentry below sees the
-                # faulted update, so detection (and the quarantine path)
-                # fires exactly as it would on a real bad client
-                u_updates = _apply_update_fault(
-                    u_updates, batch["chaos.code"], batch["chaos.scale"]
+            with jax.named_scope("grad_sync"):
+                user_g = strategy.sync_grads(user_g, sync_axes)
+            with jax.named_scope("optimizer"):
+                u_updates, opt_user = opt_user_tx.update(
+                    user_g, state.opt_user, state.user_params
                 )
+                if chaos:
+                    # fault AT the update boundary: the sentry below sees
+                    # the faulted update, so detection (and the quarantine
+                    # path) fires exactly as it would on a real bad client
+                    u_updates = _apply_update_fault(
+                        u_updates, batch["chaos.code"], batch["chaos.scale"]
+                    )
             n_updates = None
             if news_g is None:
                 new_news_params, opt_news = state.news_params, state.opt_news
             else:
-                news_g = strategy.sync_grads(news_g, sync_axes)
-                n_updates, opt_news = opt_news_tx.update(
-                    news_g, state.opt_news, state.news_params
-                )
-                if chaos:
-                    n_updates = _apply_update_fault(
-                        n_updates, batch["chaos.code"], batch["chaos.scale"]
+                with jax.named_scope("grad_sync"):
+                    news_g = strategy.sync_grads(news_g, sync_axes)
+                with jax.named_scope("optimizer"):
+                    n_updates, opt_news = opt_news_tx.update(
+                        news_g, state.opt_news, state.news_params
                     )
-                new_news_params = jax.tree_util.tree_map(
-                    lambda p, u: p + u, state.news_params, n_updates
-                )
+                    if chaos:
+                        n_updates = _apply_update_fault(
+                            n_updates, batch["chaos.code"], batch["chaos.scale"]
+                        )
+                    new_news_params = jax.tree_util.tree_map(
+                        lambda p, u: p + u, state.news_params, n_updates
+                    )
             sentry_updates = (u_updates, n_updates)
-            new_state = state.replace(
-                step=state.step + 1,
-                user_params=jax.tree_util.tree_map(
-                    lambda p, u: p + u, state.user_params, u_updates
-                ),
-                news_params=new_news_params,
-                opt_user=opt_user,
-                opt_news=opt_news,
-                rng=rng,
-            )
+            with jax.named_scope("optimizer"):
+                new_state = state.replace(
+                    step=state.step + 1,
+                    user_params=jax.tree_util.tree_map(
+                        lambda p, u: p + u, state.user_params, u_updates
+                    ),
+                    news_params=new_news_params,
+                    opt_user=opt_user,
+                    opt_news=opt_news,
+                    rng=rng,
+                )
 
         elif mode == "decoupled":
             # table is the (N, D) news-vector table; user tower trains on
             # gathered vectors, embedding grads accumulate per-nid
-            cand_vecs0 = table[batch["candidates"]]
-            his_vecs0 = table[batch["history"]]
+            with jax.named_scope("news_gather"):
+                cand_vecs0 = table[batch["candidates"]]
+                his_vecs0 = table[batch["history"]]
 
             def loss_fn(user_params, cand_vecs, his_vecs):
                 scores = model.apply(
@@ -1117,17 +1170,21 @@ def _build_local_step(
             )(state.user_params, cand_vecs0, his_vecs0)
 
             if noise_fn is not None:
-                user_g, cand_g, his_g = noise_fn((user_g, cand_g, his_g), noise_rng)
+                with jax.named_scope("dp_noise"):
+                    user_g, cand_g, his_g = noise_fn(
+                        (user_g, cand_g, his_g), noise_rng
+                    )
             sentry_grads = (user_g, cand_g, his_g)
 
             # per-nid scatter-add (reference process_news_grad, main.py:20-42)
             d = cand_g.shape[-1]
-            ids = jnp.concatenate(
-                [batch["candidates"].reshape(-1), batch["history"].reshape(-1)]
-            )
-            grads_flat = jnp.concatenate(
-                [cand_g.reshape(-1, d), his_g.reshape(-1, d)]
-            )
+            with jax.named_scope("news_gather"):
+                ids = jnp.concatenate(
+                    [batch["candidates"].reshape(-1), batch["history"].reshape(-1)]
+                )
+                grads_flat = jnp.concatenate(
+                    [cand_g.reshape(-1, d), his_g.reshape(-1, d)]
+                )
             if state.news_grad_accum.ndim != 2:
                 raise ValueError(
                     "the decoupled step accumulates per-news gradients, but "
@@ -1135,50 +1192,59 @@ def _build_local_step(
                     f"model.text_encoder_mode={cfg.model.text_encoder_mode!r} "
                     "(init_client_state keeps one only for 'table')"
                 )
-            accum = state.news_grad_accum.at[ids].add(grads_flat)
+            with jax.named_scope("news_gather"):
+                # the gather's transpose, kept across the epoch's steps
+                accum = state.news_grad_accum.at[ids].add(grads_flat)
 
-            user_g = strategy.sync_grads(user_g, sync_axes)
-            u_updates, opt_user = opt_user_tx.update(user_g, state.opt_user, state.user_params)
-            if chaos:
-                u_updates = _apply_update_fault(
-                    u_updates, batch["chaos.code"], batch["chaos.scale"]
+            with jax.named_scope("grad_sync"):
+                user_g = strategy.sync_grads(user_g, sync_axes)
+            with jax.named_scope("optimizer"):
+                u_updates, opt_user = opt_user_tx.update(
+                    user_g, state.opt_user, state.user_params
                 )
-            sentry_updates = (u_updates,)
-            new_state = state.replace(
-                step=state.step + 1,
-                user_params=jax.tree_util.tree_map(
-                    lambda p, u: p + u, state.user_params, u_updates
-                ),
-                opt_user=opt_user,
-                rng=rng,
-                news_grad_accum=accum,
-            )
+                if chaos:
+                    u_updates = _apply_update_fault(
+                        u_updates, batch["chaos.code"], batch["chaos.scale"]
+                    )
+                sentry_updates = (u_updates,)
+                new_state = state.replace(
+                    step=state.step + 1,
+                    user_params=jax.tree_util.tree_map(
+                        lambda p, u: p + u, state.user_params, u_updates
+                    ),
+                    opt_user=opt_user,
+                    rng=rng,
+                    news_grad_accum=accum,
+                )
         else:
             raise ValueError(f"unknown step mode {mode!r}")
 
-        mean_loss = lax.pmean(loss, axis_name=sync_axes)
+        with jax.named_scope("score_loss"):
+            mean_loss = lax.pmean(loss, axis_name=sync_axes)
         metrics = {"loss": loss, "mean_loss": mean_loss}
         # a routed trunk's counters, under the names ``models.bert``'s
         # ``TRUNK_COUNTERS`` gives them (``moe.*``, ``trunk.*``)
         metrics.update(routing)
         if sentry:
-            grad_norm = _tree_global_norm(*sentry_grads)
-            update_norm = _tree_global_norm(*sentry_updates)
-            param_norm = _tree_global_norm(
-                new_state.user_params, new_state.news_params
-            )
-            finite = (
-                jnp.isfinite(loss)
-                & jnp.isfinite(grad_norm)
-                & jnp.isfinite(update_norm)
-                & jnp.isfinite(param_norm)
-            )
+            with jax.named_scope("health_sentry"):
+                grad_norm = _tree_global_norm(*sentry_grads)
+                update_norm = _tree_global_norm(*sentry_updates)
+                param_norm = _tree_global_norm(
+                    new_state.user_params, new_state.news_params
+                )
+                finite = (
+                    jnp.isfinite(loss)
+                    & jnp.isfinite(grad_norm)
+                    & jnp.isfinite(update_norm)
+                    & jnp.isfinite(param_norm)
+                )
+                # int32 sentinel, not bool: the host stacks it over steps
+                # and sums it — "how many step×client cells went non-finite"
+                nonfinite = 1 - finite.astype(jnp.int32)
             metrics["health.grad_norm"] = grad_norm
             metrics["health.update_norm"] = update_norm
             metrics["health.param_norm"] = param_norm
-            # int32 sentinel, not bool: the host stacks it over steps and
-            # sums it — "how many step×client cells went non-finite"
-            metrics["health.nonfinite"] = 1 - finite.astype(jnp.int32)
+            metrics["health.nonfinite"] = nonfinite
             if dp_stats is not None:
                 metrics["health.clip_rate"] = dp_stats["clip_rate"]
                 metrics["health.clip_max_norm"] = dp_stats["max_norm"]
@@ -1537,9 +1603,10 @@ def build_param_sync(
             check_vma=False,
         )
         def sharded_sync_c(stacked_state, weights, entry_u, entry_n):
-            return _cohort_call(
-                local_sync, k, 4, stacked_state, weights, entry_u, entry_n
-            )
+            with jax.named_scope("param_sync"):
+                return _cohort_call(
+                    local_sync, k, 4, stacked_state, weights, entry_u, entry_n
+                )
 
         return jax.jit(_reshard_state_out(sharded_sync_c, state_shardings))
 
@@ -1551,7 +1618,8 @@ def build_param_sync(
         check_vma=False,
     )
     def sharded_sync(stacked_state, weights):
-        return _cohort_call(local_sync, k, 2, stacked_state, weights)
+        with jax.named_scope("param_sync"):
+            return _cohort_call(local_sync, k, 2, stacked_state, weights)
 
     # NOT donated (unlike the train step): sync runs once per round, so the
     # transient double-buffer is cheap, and callers legitimately hold the

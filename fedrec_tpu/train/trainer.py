@@ -68,6 +68,7 @@ from fedrec_tpu.obs import (
     rotate_jsonl,
     sample_device_memory,
 )
+from fedrec_tpu.obs.tracing import RoundDigest
 from fedrec_tpu.utils.logging import MetricLogger
 from fedrec_tpu.utils.profiling import profile_if
 
@@ -831,14 +832,9 @@ class Trainer:
             buckets=(0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 25.0, 50.0,
                      100.0, 250.0, 500.0, 1000.0),
         )
-        self._m_round_end_secs = self.registry.histogram(
-            "train.round_end_seconds",
-            "host seconds a round's end takes once its last device program "
-            "is done (the round_end span): the one read of the steps' "
-            "metrics, the loss, the health digest, the routing counters",
-            buckets=(0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25,
-                     0.5, 1.0),
-        )
+        # the one digest of a round's spans (train.round_span_seconds{span},
+        # the slow-round record): obs.perf reads the same sums
+        self._round_digest = RoundDigest(self.tracer, self.registry)
         self._g_encode_rows = self.registry.gauge(
             "train.encode_rows",
             "news rows a client-step gathers and encodes: the size R the "
@@ -2555,10 +2551,16 @@ class Trainer:
         )
 
     def train_round(self, round_idx: int) -> RoundResult:
-        """One federated round, wrapped in a ``fed_round`` host
-        span AND a ``jax.profiler.StepTraceAnnotation`` carrying the same
-        round number — so the obs trace and a captured device trace
-        (train.profile) are correlatable round-for-round."""
+        """One federated round, wrapped in a ``fed_round`` host span AND a
+        ``jax.profiler.StepTraceAnnotation`` carrying the same round
+        number. The span's children tile it, each with the round's number:
+        ``round_prologue``, a step's ``batch_build`` / ``h2d`` / ``dispatch``
+        / ``step_keep`` (each with the step's index in the round: the n-th
+        execution of the step's program in that round on a device trace),
+        ``aggregate``, ``device_wait``, ``round_end``, ``round_epilogue``
+        (from ``round_end``'s close to the round's; ``eval``, when due,
+        inside it); what is left under none of them is the ``unspanned`` of
+        the round's digest (``obs.tracing.RoundDigest``)."""
         import time as _time
 
         t0 = _time.perf_counter()
@@ -2567,25 +2569,39 @@ class Trainer:
         self._ensure_cohort(round_idx)
         if self.perf is not None:
             self.perf.begin_round()
-        with self.tracer.span(
+        tracer = self.tracer
+        with tracer.span(
             "fed_round", step_num=round_idx, num_rounds=1,
             **self._round_span_args(),
         ), jax.profiler.StepTraceAnnotation("fed_round", step_num=round_idx):
-            result = self._train_round_inner(round_idx)
+            self._round_digest.begin()
+            # the epilogue runs from round_end's close: the inner frame's
+            # death (a round's few hundred device arrays let go) is in it
+            result, t_epilogue = self._train_round_inner(round_idx)
+            self._eval_if_due(result)
             # HBM gauges at the round boundary, attributed (as an instant
             # event) to this fed_round span; no-op on allocator-less CPU
-            sample_device_memory(
-                self.registry, self.tracer, fed_round=round_idx
-            )
+            sample_device_memory(self.registry, tracer, fed_round=round_idx)
             self._perf_sample_components(round_idx)
+            span_seconds = self._round_digest.close(round_idx, t_epilogue)
+            now = tracer.now()
+            tracer.add_span(
+                "round_epilogue", dur_s=now - t_epilogue, end=now,
+                round=round_idx,
+            )
         wall = _time.perf_counter() - t0
         self._m_round_secs.observe(wall)
         if self.perf is not None:
-            self.perf.observe_round(round_idx, wall)
+            self.perf.observe_round(round_idx, wall, span_seconds)
         return result
 
-    def _train_round_inner(self, round_idx: int) -> RoundResult:
+    def _train_round_inner(self, round_idx: int) -> tuple[RoundResult, float]:
+        """The round up to ``round_end``'s close, and that close on the
+        tracer's clock."""
         cfg = self.cfg
+        tracer = self.tracer
+        # the prologue: everything up to where the first batch is asked for
+        t_open = tracer.now()
         weights_np = self._round_weights(round_idx)
         weights = jnp.asarray(weights_np)
         chaos_extra = self._chaos_batch_keys(round_idx)
@@ -2632,10 +2648,9 @@ class Trainer:
         }
         # each host-deduped step's largest distinct count over its clients
         distinct: list[int] = []
+        kept_arrays = 0
 
-        tracer = self.tracer
-
-        def keep_metrics(metrics) -> None:
+        def keep_metrics(metrics) -> int:
             losses.append(metrics["mean_loss"])
             kept["raw_losses"].append(metrics["loss"])
             started = [metrics["mean_loss"], metrics["loss"]]
@@ -2656,6 +2671,7 @@ class Trainer:
             # the host
             for leaf in started:
                 leaf.copy_to_host_async()
+            return len(started)
 
         if self._host_dedup and self._encode_rows is None:
             self._choose_encode_rows(round_idx * cfg.fed.local_epochs)
@@ -2665,30 +2681,42 @@ class Trainer:
             table = self._feature_table()
             it = self._epoch_batch_iter(epoch_idx, chaos_extra, distinct)
             src = iter(it)
+            t_build = tracer.now()
+            # a later local epoch's table and iterator are its own opening
+            tracer.add_span(
+                "round_prologue" if local_epoch == 0 else "epoch_open",
+                dur_s=t_build - t_open, end=t_build, round=round_idx,
+            )
             try:
                 while True:
                     # the consumer-side wait IS the batch-build cost when
                     # prefetch is off, and the residual (unhidden) build
                     # cost when it is on — either way the span to watch
-                    t_build = tracer.now()
                     try:
                         batch = next(src)
                     except StopIteration:
                         break
+                    # a step's four spans tile it: each opens at the
+                    # reading that closed the one before, so the few
+                    # microseconds between two of them (the tracer's own
+                    # bookkeeping, the recorder, the counter, the dedup
+                    # entries' lookup) lie inside the later one
+                    step = {"round": round_idx, "step": step_in_round}
+                    t_built = tracer.now()
                     tracer.add_span(
-                        "batch_build", dur_s=tracer.now() - t_build,
-                        epoch=epoch_idx,
+                        "batch_build", dur_s=t_built - t_build, end=t_built,
+                        epoch=epoch_idx, **step,
                     )
-                    if self.flightrec is not None:
-                        self.flightrec.record(
-                            batch, round_idx, epoch_idx, step_in_round
-                        )
-                    step_in_round += 1
-                    self._count_steps(1)
-                    with tracer.span("h2d", n=1):
+                    with tracer.span("h2d", since=t_built, n=1, **step) as moved:
+                        # before the dispatch, so that a step that raises
+                        # is in the recorder's dump and in the count
+                        if self.flightrec is not None:
+                            self.flightrec.record(
+                                batch, round_idx, epoch_idx, step_in_round
+                            )
+                        step_in_round += 1
+                        self._count_steps(1)
                         sharded = shard_fed_batch(self.mesh, batch, cfg)
-                    if self._perf_keep_batch:
-                        self._perf_last_batch = sharded
                     # what the step will encode, by the step's own rule
                     entries = batch_host_dedup(sharded)
                     encode = (
@@ -2696,11 +2724,23 @@ class Trainer:
                          "slots": entries[1].shape[-1]}
                         if entries else {}
                     )
-                    with tracer.span("dispatch", kind="step", n=1, **encode):
+                    with tracer.span(
+                        "dispatch", since=moved.end, kind="step", n=1,
+                        **encode, **step,
+                    ) as sent:
                         self.state, metrics = self.train_step(
                             self.state, sharded, table
                         )
-                    keep_metrics(metrics)
+                    # the step's bookkeeping on the host, behind its enqueue
+                    if self._perf_keep_batch:
+                        self._perf_last_batch = sharded
+                    arrays = keep_metrics(metrics)
+                    kept_arrays += arrays
+                    t_build = tracer.now()
+                    tracer.add_span(
+                        "step_keep", dur_s=t_build - sent.end, end=t_build,
+                        arrays=arrays, **step,
+                    )
             finally:
                 # a dispatch error mid-epoch must not leak the producer
                 # thread (Prefetcher.close is idempotent; bare generators
@@ -2709,10 +2749,14 @@ class Trainer:
                 if close is not None:
                     close()
             if self.mode == "decoupled":
-                self.state, tables = self.news_update(self.state, self.token_states)
-                self._table = self._replicate_table(
-                    jax.tree_util.tree_map(lambda x: x[0], tables)
-                )
+                with tracer.span("news_update", round=round_idx, epoch=epoch_idx):
+                    self.state, tables = self.news_update(
+                        self.state, self.token_states
+                    )
+                    self._table = self._replicate_table(
+                        jax.tree_util.tree_map(lambda x: x[0], tables)
+                    )
+            t_open = tracer.now()
 
         if self.strategy.sync_params_every_round and (
             self._agg_async or self._agg_hier_host
@@ -2773,25 +2817,30 @@ class Trainer:
                 # sliced XLA:CPU rig that trips the 40 s collective
                 # termination deadline (observed; steps drain incrementally
                 # through per-value readbacks everywhere else).
-                if losses:
-                    jax.block_until_ready(losses[-1])
-                mean = jax.tree_util.tree_map(np.asarray, self._client0_params())
-                new_u, new_n = self.server_opt.step(round_start_global, mean)
-                self.set_global_params(
-                    jax.tree_util.tree_map(jnp.asarray, new_u),
-                    jax.tree_util.tree_map(jnp.asarray, new_n),
-                )
+                with tracer.span("server_step", round=round_idx):
+                    if losses:
+                        jax.block_until_ready(losses[-1])
+                    mean = jax.tree_util.tree_map(
+                        np.asarray, self._client0_params()
+                    )
+                    new_u, new_n = self.server_opt.step(round_start_global, mean)
+                    self.set_global_params(
+                        jax.tree_util.tree_map(jnp.asarray, new_u),
+                        jax.tree_util.tree_map(jnp.asarray, new_n),
+                    )
             elif self.mode == "decoupled":
-                self._refresh_table()
+                with tracer.span("table_refresh", round=round_idx):
+                    self._refresh_table()
 
         # the round's last device programs (the sync or the last step, and
-        # a decoupled round's table refresh): that wait is the device's
-        jax.block_until_ready((self.state, self._table))
+        # a decoupled round's table refresh): the host waits, the chip works
+        with tracer.span("device_wait", round=round_idx) as waited:
+            jax.block_until_ready((self.state, self._table))
         # the round's end, the host's alone: the chip waits for it
-        t_end = tracer.now()
         with tracer.span(
-            "round_end", arrays=len(jax.tree_util.tree_leaves(kept)), reads=1
-        ):
+            "round_end", since=waited.end, round=round_idx,
+            arrays=kept_arrays, reads=1,
+        ) as ended:
             # ONE read: every step but the last sent its arrays long ago
             host = jax.device_get(kept)
             # the round's loss: the flat mean over every (step, client) cell
@@ -2814,10 +2863,7 @@ class Trainer:
                 self._set_encode_rows(max(distinct))
             if host["routing_rows"]:
                 self._publish_routing(host["routing_rows"])
-        self._m_round_end_secs.observe(tracer.now() - t_end)
-        result = RoundResult(round_idx, train_loss)
-        self._eval_if_due(result)
-        return result
+        return RoundResult(round_idx, train_loss), ended.end
 
     def _publish_routing(self, rows: list[dict]) -> None:
         """The round's counters of a routed trunk to the registry, from the

@@ -10,13 +10,40 @@ the default path a second time.  Host-side round structure goes through
 :mod:`fedrec_tpu.obs.tracing` instead; the Trainer annotates each round
 with ``jax.profiler.StepTraceAnnotation("fed_round", step_num=...)`` so
 the device trace captured here is round-addressable.
+
+Every device trace the program takes starts and stops HERE
+(:func:`start_device_trace` / :func:`stop_device_trace`: ``profile_if``
+below and ``obs.perf.PerfMonitor``'s capture windows), and carries the
+host's clock: a :data:`CLOCK_MARK` annotation right after the start and
+right before the stop, each with ``t_ns`` = ``time.perf_counter_ns()``.
+An annotation's own start on the trace's timeline less its ``t_ns`` is the
+one offset that places any span of the obs tracer's ``trace.json``
+(``otherData.epoch_perf_counter_ns`` + ``ts``) on the device trace.
 """
 
 from __future__ import annotations
 
 import contextlib
+import time
 
 import jax
+
+CLOCK_MARK = "fedrec_clock"
+
+
+def _stamp_clock() -> None:
+    with jax.profiler.TraceAnnotation(CLOCK_MARK, t_ns=time.perf_counter_ns()):
+        pass
+
+
+def start_device_trace(logdir) -> None:
+    jax.profiler.start_trace(str(logdir))
+    _stamp_clock()
+
+
+def stop_device_trace() -> None:
+    _stamp_clock()
+    jax.profiler.stop_trace()
 
 
 @contextlib.contextmanager
@@ -35,8 +62,8 @@ def profile_if(enabled: bool, logdir: str | None = None):
         yield None
         return
     logdir = logdir or "/tmp/fedrec_tpu_trace"
-    jax.profiler.start_trace(logdir)
+    start_device_trace(logdir)
     try:
         yield logdir
     finally:
-        jax.profiler.stop_trace()
+        stop_device_trace()

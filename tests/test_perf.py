@@ -17,6 +17,7 @@ import pytest
 
 from fedrec_tpu.config import ExperimentConfig
 from fedrec_tpu.obs import MetricsRegistry, Tracer, set_registry, set_tracer
+from fedrec_tpu.obs.tracing import span_seconds
 from fedrec_tpu.obs.perf import (
     CHIP_PEAKS,
     PEAK_FLOPS,
@@ -322,7 +323,7 @@ def test_monitor_round_digest_no_peaks(fresh_obs):
     tr.add_span("batch_build", dur_s=0.30)
     tr.add_span("h2d", dur_s=0.10)
     tr.add_span("dispatch", dur_s=0.20)
-    out = mon.observe_round(0, wall_s=1.0)
+    out = mon.observe_round(0, 1.0, span_seconds(tr.events()))
     assert out["perf.samples_per_sec"] == pytest.approx(
         4 * cfg.fed.num_clients * cfg.data.batch_size, rel=1e-6
     )
@@ -338,9 +339,11 @@ def test_monitor_round_digest_no_peaks(fresh_obs):
     ) == 1.0
     # second round, dispatch-dominant -> 'device' (no chip peaks)
     mon.begin_round()
+    mark = tr.event_count()
     steps.inc(4)
     tr.add_span("dispatch", dur_s=0.5)
-    assert mon.observe_round(1, wall_s=0.6)["perf.verdict"] == "device"
+    digest = span_seconds(tr.events_since(mark))
+    assert mon.observe_round(1, 0.6, digest)["perf.verdict"] == "device"
 
 
 def test_monitor_untraced_round_publishes_no_verdict(fresh_obs):
@@ -355,7 +358,7 @@ def test_monitor_untraced_round_publishes_no_verdict(fresh_obs):
     mon.begin_round()
     steps.inc(4)
     tr.add_span("batch_build", dur_s=0.4)  # dropped
-    out = mon.observe_round(0, wall_s=1.0)
+    out = mon.observe_round(0, 1.0, {})
     assert "perf.verdict" not in out
     assert out["perf.samples_per_sec"] > 0  # wall-based gauges still land
     from fedrec_tpu.obs.report import snapshot_value
@@ -378,7 +381,7 @@ def test_monitor_mfu_with_chip_peaks_and_eval_exclusion(fresh_obs):
     steps.inc(8)
     tr.add_span("dispatch", dur_s=1.0)
     tr.add_span("eval", dur_s=1.0)
-    out = mon.observe_round(0, wall_s=3.0)
+    out = mon.observe_round(0, 3.0, span_seconds(tr.events()))
     flops = 8 * cfg.fed.num_clients * flops_per_train_step(cfg, cfg.data.batch_size, 64)
     peak = peak_flops("TPU v4", cfg.model.dtype)
     # denominator is wall MINUS the eval span (2.0 s, not 3.0); the
@@ -472,11 +475,11 @@ def test_monitor_efficiency_drop_trigger(fresh_obs, tmp_path):
     for r in range(3):  # healthy rounds build the trailing mean
         mon.begin_round()
         steps.inc(4)
-        mon.observe_round(r, wall_s=1.0)
+        mon.observe_round(r, 1.0, {})
         assert mon.capture_before_round(r + 1) is None or r < 2
     mon.begin_round()
     steps.inc(1)  # 4x slower round -> > 50% below trailing mean
-    mon.observe_round(3, wall_s=1.0)
+    mon.observe_round(3, 1.0, {})
     logdir = mon.capture_before_round(4)
     assert logdir is not None
     mon.capture_after_round(4)
@@ -511,7 +514,7 @@ def test_perf_detail_report_and_cli(fresh_obs, tmp_path, capsys):
     mon.begin_round()
     steps.inc(4)
     tr.add_span("dispatch", dur_s=0.4)
-    out = mon.observe_round(0, wall_s=0.5)
+    out = mon.observe_round(0, 0.5, span_seconds(tr.events()))
     mon.cost(_FakeJitted({"flops": 1e9, "bytes accessed": 5e8}), (), {},
              "train_step")
     live_array_components({"params": {}}, registry=reg)
@@ -548,7 +551,7 @@ def test_fleet_report_carries_perf(fresh_obs, tmp_path):
     mon.begin_round()
     steps.inc(4)
     tr.add_span("dispatch", dur_s=0.4)
-    mon.observe_round(0, wall_s=0.5)
+    mon.observe_round(0, 0.5, span_seconds(tr.events()))
     obs = _write_obs_dir(tmp_path, reg)
     (obs / "trace.json").write_text(json.dumps(tr.to_chrome()))
     workers = load_fleet_dir(obs)

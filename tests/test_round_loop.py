@@ -41,11 +41,11 @@ def _leaves(tree):
     return [np.asarray(x) for x in jax.tree_util.tree_leaves(tree)]
 
 
-def _trainer(tmp_path, tag="t", num_train=128, mesh=None, **over):
+def _trainer(tmp_path, tag="t", num_train=128, mesh=None, tracer=None, **over):
     """A small ``Trainer`` on the shared synthetic fixture. Joint mode and
     ``param_avg`` unless overridden; no evaluation, no snapshots."""
     set_registry(MetricsRegistry())
-    set_tracer(Tracer())
+    set_tracer(tracer or Tracer())
     cfg = small_cfg(
         optim__user_lr=3e-3, optim__news_lr=3e-3,
         model__text_encoder_mode="head", fed__strategy="param_avg",
@@ -390,11 +390,11 @@ def _spy_host_rows(t, monkeypatch):
     return seen
 
 
-def _sparse_trunk_trainer(tmp_path):
+def _sparse_trunk_trainer(tmp_path, tracer=None):
     from test_sparse_trunk import trunk_cfg, trunk_data
 
     set_registry(MetricsRegistry())
-    set_tracer(Tracer())
+    set_tracer(tracer or Tracer())
     cfg = trunk_cfg(1)
     return Trainer(cfg, trunk_data(cfg), None, mesh=client_mesh(1, max_devices=1))
 
@@ -410,7 +410,8 @@ def test_every_round_ends_in_one_read_of_host_arrays(tmp_path, monkeypatch, make
     ``fed_round``, with ``reads == 1`` and ``arrays == steps x kept keys``;
     the health digest and the routing counters are handed ``numpy.ndarray``
     leaves only (no read of a device array is left to them); the histogram
-    ``train.round_end_seconds`` is observed once a round."""
+    ``train.round_span_seconds{span="round_end"}`` observes the span's
+    interval once a round."""
     t = make(tmp_path)
     seen = _spy_host_rows(t, monkeypatch)
     steps = _step_metrics(t)
@@ -420,8 +421,9 @@ def test_every_round_ends_in_one_read_of_host_arrays(tmp_path, monkeypatch, make
     per_round = len(steps) // rounds
     spans = _round_end_spans(t)
     assert [e["args"] for e in spans] == [
-        {"arrays": per_round * kept_keys, "reads": 1}
-    ] * rounds
+        {"round": r, "arrays": per_round * kept_keys, "reads": 1}
+        for r in range(rounds)
+    ]
     fed_rounds = [e for e in t.tracer.events() if e.get("name") == "fed_round"]
     for end, whole in zip(spans, fed_rounds):
         assert whole["ts"] <= end["ts"]
@@ -430,8 +432,10 @@ def test_every_round_ends_in_one_read_of_host_arrays(tmp_path, monkeypatch, make
     for kinds in seen["health"] + seen["routing"]:
         assert kinds and set(kinds) == {np.ndarray}
     assert len(seen["routing"]) == (rounds if kept_keys == 9 else 0)
-    hist = t.registry.snapshot()["metrics"]["train.round_end_seconds"]["values"]
-    assert [cell["count"] for cell in hist] == [rounds]
+    hist = t.registry.snapshot()["metrics"]["train.round_span_seconds"]["values"]
+    (cell,) = [c for c in hist if c["labels"] == {"span": "round_end"}]
+    assert cell["count"] == rounds
+    assert cell["sum"] == pytest.approx(sum(e["dur"] for e in spans) / 1e6)
 
 
 def _nonfinite(metrics, client):
