@@ -40,8 +40,14 @@ a quarter with 8 of 64. Every pass over the buffer (the row gathers, the
 selects that zero its unwritten rows, the products' outputs and all their
 transposes) costs by its rows, whatever they hold. What runs over a token's
 k choices (the weighted sum of the products' output, and the cotangents'
-way back to the tokens) is k gathers of (T, d) rows into one float32 sum:
-no array is laid out pair by pair (``to_expert_order``, ``weighted_sum``).
+way back to the tokens) is one Pallas kernel, ``ops.pair_sum``: it copies
+the held groups' rows once, fetches for each token only the rows of its
+pairs on held experts (a quarter or an eighth of the pairs; the rest are
+not read), sums them in float32 in choice order and writes the token's row
+once. No array is laid out pair by pair (``to_expert_order``,
+``weighted_sum``). The backward pass takes the way back to the tokens last,
+after every weight's gradient (``_at_rows_bwd``), so the kernel's copy of
+the buffer's cotangent is never live beside the products' intermediates.
 A chunk whose router sent more pairs here than the small size holds runs at
 the full size, one ``lax.cond`` between the two sizes of the same body on
 the count the sort gives (``_either_size``), so a router that drifts is
@@ -70,6 +76,8 @@ import jax.numpy as jnp
 from flax import linen as nn
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
+
+from fedrec_tpu.ops.pair_sum import pair_sum
 
 # the expert layer's sorted buffer is whole tiles of ROW_TILE rows: at its
 # full size chunk x experts_per_token rows, at its small size EVEN_SHARE_ROOM
@@ -236,59 +244,41 @@ def route(
 
 # Rows move between token order and expert order (the (token, choice) pairs,
 # token t's choice c being pair t*k + c, sorted by held expert, then the
-# absent ones, then padding) by row gathers only, and no array is ever laid
-# out in pair order: ``order`` (rows,) names the pair of every row of the
-# buffer, ``back`` (k, T), choice-major, the row of every pair, and whatever
-# runs over a token's k choices is k passes over (T, d) rows into one float32
-# sum. (A (T, k, d) array has k = 4, 6 or 8 rows in a tile of 8 or 16, and
-# every reshape between it and (T*k, d) moves all of it: PERF.md section 6,
-# PR 36.) Each gather's cotangent goes back by a gather too, where the
+# absent ones, then padding) and no array is ever laid out in pair order:
+# ``order`` (rows,) names the pair of every row of the buffer, ``back`` (k, T),
+# choice-major, the row of every pair, and ``held`` the rows of the held
+# groups. (A (T, k, d) array has k = 4, 6 or 8 rows in a tile of 8 or 16, and
+# every reshape between it and (T*k, d) moves all of it: PERF.md section 6.)
+# What runs over a token's k choices is ``ops.pair_sum``: the rows of
+# the pairs on held experts, read and summed a token at a time, one row
+# written a token; a row at or past ``held`` is not read (the products leave
+# it unwritten). Each derivative is a gather or a pair sum too, where the
 # transpose of a gather is a scatter (which a TPU runs row by row). The
 # buffer may be cut short of the absent pairs (``buffer_rows``): ``order`` is
-# cut to its rows, and a ``back`` that points past them is clipped and
-# counted for nothing.
+# cut to its rows, and a ``back`` that points past them is past ``held`` too.
 @jax.custom_vjp
-def to_expert_order(u, order, back):
+def to_expert_order(u, order, back, held):
     """(T, d) token rows -> (rows, d): row i is the token of pair order[i]."""
     return u[jnp.minimum(order // back.shape[0], u.shape[0] - 1)]
 
 
-def _to_expert_order_bwd(back, ct):
-    rows = ct.shape[0]
-    total = 0.0
-    for choice in back:
-        picked = ct[jnp.minimum(choice, rows - 1)].astype(jnp.float32)
-        total = total + jnp.where((choice < rows)[:, None], picked, 0)
-    return total.astype(ct.dtype), None, None
+def _to_expert_order_bwd(res, ct):
+    back, held = res
+    return pair_sum(ct, back, None, held), None, None, None
 
 
 to_expert_order.defvjp(
-    lambda u, order, back: (to_expert_order(u, order, back), back),
+    lambda u, order, back, held: (to_expert_order(u, order, back, held), (back, held)),
     _to_expert_order_bwd,
 )
 
 
 @jax.custom_vjp
-def weighted_sum(down, p, order, back):
+def weighted_sum(down, p, order, back, held):
     """(rows, d) in expert order, weights (T, k) -> (T, d):
-    ``y[t] = sum_c p[t, c] * down[back[c, t]]``, the products of the rows'
-    dtype summed in float32; a pair past the buffer is on an absent expert
-    and adds nothing."""
-    return _weighted_sum_fwd(down, p, order, back)[0]
-
-
-def _weighted_sum_fwd(down, p, order, back):
-    rows = down.shape[0]
-    total = 0.0
-    for c, choice in enumerate(back):
-        weight = jnp.where(choice < rows, p[:, c], 0).astype(down.dtype)
-        picked = down[jnp.minimum(choice, rows - 1)].astype(jnp.float32)
-        total = total + picked * weight.astype(jnp.float32)[:, None]
-    # the sum is one pass over the k gathered arrays as long as it ends
-    # here: left open, XLA:TPU fuses a caller's reshape of the result into it
-    # and then converts each gathered array to float32 in a kernel of its own
-    # (laguna33b-ep8.b1: 51 ms a step; PERF.md section 6, PR 36)
-    return lax.optimization_barrier(total.astype(down.dtype)), (down, p, order, back)
+    ``y[t] = sum_c p[t, c] * down[back[c, t]]`` over the pairs in the held
+    groups, the products of the rows' dtype summed in float32."""
+    return pair_sum(down, back, p, held)
 
 
 def _weighted_sum_bwd(res, ct):
@@ -304,10 +294,13 @@ def _weighted_sum_bwd(res, ct):
     # buffer and read back pair by pair: scalars, not rows
     along = jnp.sum(of_token * down.astype(jnp.float32), axis=-1)
     d_p = jnp.where(back < rows, along[jnp.minimum(back, rows - 1)], 0).T
-    return d_down.astype(ct.dtype), d_p.astype(p.dtype), None, None
+    return d_down.astype(ct.dtype), d_p.astype(p.dtype), None, None, None
 
 
-weighted_sum.defvjp(_weighted_sum_fwd, _weighted_sum_bwd)
+weighted_sum.defvjp(
+    lambda down, p, order, back, held: (pair_sum(down, back, p, held), (down, p, order, back)),
+    _weighted_sum_bwd,
+)
 
 
 def buffer_rows(pairs: int, held: int, n_experts: int) -> tuple[int, int]:
@@ -356,29 +349,73 @@ grouped_matmul.defvjp(
 )
 
 
-def _at_rows(rows, activation, ints, u, p, w_gate, w_up, w_down):
-    """One chunk's output through a sorted buffer of ``rows`` rows: the body
-    of ``held_experts_output`` at one of its sizes. Rows past the held groups
-    are pairs on absent experts (and padding): a grouped product leaves them
-    unwritten, so they are zeroed on the way in and out (and, by the same
-    selects transposed, in the backward pass)."""
+def _route(rows, ints):
+    """The buffer's ``order`` cut to ``rows``, ``back``, the held groups' sizes
+    within the buffer, the rows they fill (``held``) and which rows those are.
+    The cut is a no-op wherever this size's result is used; under a cohort's
+    vmap both sizes run, and the groups of a client whose pairs overflow this
+    one must not reach past the buffer."""
     order, back, sizes = ints
-    with jax.named_scope("moe_route"):
-        order = order[:rows]
-        # a no-op wherever this size's result is used; under a cohort's vmap
-        # both sizes run, and the groups of a client whose pairs overflow
-        # this one must not reach past the buffer
-        ends = jnp.minimum(jnp.cumsum(sizes), rows)
-        sizes = jnp.diff(ends, prepend=0)
-        here = (jnp.arange(rows) < ends[-1])[:, None]
-        xs = jnp.where(here, to_expert_order(u, order, back), 0)
+    ends = jnp.minimum(jnp.cumsum(sizes), rows)
+    return order[:rows], back, jnp.diff(ends, prepend=0), ends[-1], (jnp.arange(rows) < ends[-1])[:, None]
+
+
+def _to_expert_order(u, route):
+    """The buffer's rows, those past the held groups zero."""
+    order, back, _, held, here = route
+    return jnp.where(here, to_expert_order(u, order, back, held), 0)
+
+
+def _experts(activation, route, xs, p, w_gate, w_up, w_down):
+    order, back, sizes, held, here = route
     with jax.named_scope("moe_experts"):
         gate = jnp.where(here, grouped_matmul(xs, w_gate, sizes), 0)
         up = jnp.where(here, grouped_matmul(xs, w_up, sizes), 0)
         down = grouped_matmul(activation(gate) * up, w_down, sizes)
         down = jnp.where(here, down, 0)
     with jax.named_scope("moe_combine"):
-        return weighted_sum(down, p, order, back)
+        return weighted_sum(down, p, order, back, held)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _at_rows(rows, activation, ints, u, p, w_gate, w_up, w_down):
+    """One chunk's output through a sorted buffer of ``rows`` rows: the body
+    of ``held_experts_output`` at one of its sizes. Rows past the held groups
+    are pairs on absent experts (and padding): a grouped product leaves them
+    unwritten, so they are zeroed on the way in and out (and, by the same
+    selects transposed, in the backward pass, but for the way back to the
+    tokens, which does not read them)."""
+    with jax.named_scope("moe_route"):
+        route = _route(rows, ints)
+        xs = _to_expert_order(u, route)
+    return _experts(activation, route, xs, p, w_gate, w_up, w_down)
+
+
+def _at_rows_bwd(rows, activation, res, ct):
+    """The forward again, its transposes, and the tokens' cotangent as the
+    pair sum of the rows' (``to_expert_order``'s transpose). The sum waits
+    for every weight's gradient: it copies the held rows of the buffer's
+    cotangent, and taken earlier that copy would be live beside the grouped
+    products' intermediates and the weights' transposed copies (one more
+    buffer-sized array at the chunk's peak)."""
+    ints, u, *floats = res
+    with jax.named_scope("moe_route"):
+        route = _route(rows, ints)
+        xs = _to_expert_order(u, route)
+    d_xs, *d_floats = jax.vjp(partial(_experts, activation, route), xs, *floats)[1](ct)
+    d_xs, d_floats = lax.optimization_barrier((d_xs, d_floats))
+    with jax.named_scope("moe_route"):
+        # the rows past the held groups are not read: their select's
+        # transpose is left out
+        order, back, _, held, _ = route
+        d_u, = jax.vjp(lambda u: to_expert_order(u, order, back, held), u)[1](d_xs)
+    return None, d_u, *d_floats
+
+
+_at_rows.defvjp(
+    lambda rows, activation, ints, *floats: (_at_rows(rows, activation, ints, *floats), (ints, *floats)),
+    _at_rows_bwd,
+)
 
 
 # The choice between the buffer's two sizes, differentiated by hand: left to
@@ -403,7 +440,7 @@ def _either_size_bwd(sizes, activation, res, ct):
 
     def transposes(rows):
         def run(ints, floats, ct):
-            return jax.vjp(partial(_at_rows, rows, activation, ints), *floats)[1](ct)
+            return _at_rows_bwd(rows, activation, (ints, *floats), ct)[1:]
         return run
 
     small, full = sizes

@@ -10,9 +10,14 @@ first-class TPU kernels:
     allocates, reference ``attention.py:38``).
   * ``additive_pool`` — fused learned-query additive pooling (tanh-MLP scores
     + softmax + weighted sum in one VMEM pass; reference ``attention.py:14-26``).
+  * ``pair_sum`` — the expert layer's sum over a token's (token, choice) pairs
+    on held experts: reads only those rows, writes one row a token (the
+    combine's forward and the route's backward in every routed trunk).
 
-Both run in Pallas interpret mode on CPU (tests) and compiled on TPU, and are
-routed from the Flax modules via ``ModelConfig.use_pallas``.
+All run in Pallas interpret mode on CPU (tests) and compiled on TPU. The
+first two are routed from the Flax modules via ``ModelConfig.use_pallas``;
+``pair_sum`` (its own module, ``ops/pair_sum.py``) runs wherever a routed
+trunk does, with no option.
 """
 
 from fedrec_tpu.ops.attention_kernels import additive_pool, flash_attention

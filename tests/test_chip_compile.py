@@ -13,6 +13,7 @@ TPU library inside the ``topo`` fixture, and no other worker may.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -419,7 +420,7 @@ def _batched_products(text: str) -> str:
 
 
 @pytest.mark.parametrize("clients", [1, 2], ids=["one-client", "cohort-of-two"])
-def test_held_experts_compile_at_published_widths(topo, one_chip, clients):
+def test_held_experts_compile_at_published_widths(topo, one_chip, compiled_kernels, clients):
     """One chunk of the expert layer of ``st21b-ep4.b16`` (11,000 tokens, 6
     choices, 16 of 64 experts of 2560 x 768 held), forward and backward: the
     grouped products reach XLA:TPU's grouped-matmul kernel un-batched. It
@@ -433,7 +434,7 @@ def test_held_experts_compile_at_published_widths(topo, one_chip, clients):
 
 
 @pytest.mark.parametrize("cell", sorted(ROUTED_CHUNKS))
-def test_held_experts_choose_between_two_buffer_sizes(topo, one_chip, monkeypatch, cell):
+def test_held_experts_choose_between_two_buffer_sizes(topo, one_chip, compiled_kernels, monkeypatch, cell):
     """The chunk of each routed cell compiles with a conditional between the
     sorted buffer's small size and its full one (``BUFFER_ROWS``), forward
     and backward, the grouped products un-batched in both branches; the
@@ -460,15 +461,18 @@ def test_held_experts_choose_between_two_buffer_sizes(topo, one_chip, monkeypatc
 
 
 @pytest.mark.parametrize("cell", sorted(ROUTED_CHUNKS))
-def test_held_experts_lay_no_rows_out_in_pair_order(topo, one_chip, cell):
+def test_held_experts_lay_no_rows_out_in_pair_order(topo, one_chip, compiled_kernels, cell):
     """The compiled chunk of each routed cell, forward and backward, holds no
     value of shape ``[T,k,d]`` or ``[T*k,d]``: with 4, 6 or 8 choices as the
     second-minor axis every row tile of 8 (float32) or 16 (bfloat16) is
     padded, and each reshape between the two moves the array (PERF.md
-    section 6, PR 36). What runs over a token's choices is k passes over
-    ``[T,d]``: the only row counts beside the tokens' are the buffer's two
-    sizes (in ``laguna33b-ep8.b1`` the pairs are whole tiles, so the full
-    size has ``T*k`` rows itself)."""
+    section 6). What runs over a token's choices is the pair sum
+    (``ops/pair_sum.py``): no gather of ``[T,d]`` rows is left, a buffer
+    size has two ``pair_sum`` kernels (the combine's forward and the route's
+    backward; the rematerialised forward's is dead) and the copies of the
+    held rows before them, and the only row counts beside the tokens' are
+    the buffer's two sizes (in ``laguna33b-ep8.b1`` the pairs are whole
+    tiles, so the full size has ``T*k`` rows itself)."""
     import re
 
     tokens, k, _, _, d, *_ = ROUTED_CHUNKS[cell]
@@ -477,6 +481,33 @@ def test_held_experts_lay_no_rows_out_in_pair_order(topo, one_chip, cell):
     assert not {(tokens, k, d), (k, tokens, d)} & shapes
     assert {s[0] for s in shapes if len(s) == 2 and s[1] == d and s[0] >= tokens} == {
         tokens, *BUFFER_ROWS[cell]}
+    gathered = re.findall(r"= \w+\[([\d,]+)\]\S* gather\(", text)
+    assert f"{tokens},{d}" not in gathered and gathered
+    kernels = re.findall(r"%(pair_sum\w*)\.\d+ = \w+\[(\d+),", text)
+    assert sorted(kernels) == sorted(
+        [("pair_sum", str(tokens))] * 4
+        + [("pair_sum_rows", str(rows)) for rows in BUFFER_ROWS[cell] for _ in range(2)])
+
+
+@pytest.mark.parametrize("weighted", [True, False], ids=["combine", "route-backward"])
+@pytest.mark.parametrize("cell", sorted(ROUTED_CHUNKS))
+def test_pair_sum_compiles_at_the_cells_widths(topo, one_chip, compiled_kernels, cell, weighted):
+    """The pair sum alone over a chunk of each routed cell (bfloat16 rows,
+    the small buffer): the row copy and the sum are Mosaic kernels, and the
+    copied rows stay in HBM beside the sum's VMEM slots."""
+    import importlib
+
+    pair_sum = importlib.import_module("fedrec_tpu.ops.pair_sum").pair_sum
+    tokens, k, _, _, d, *_ = ROUTED_CHUNKS[cell]
+    rows = BUFFER_ROWS[cell][0]
+
+    def fn(src, back, w, held):
+        return pair_sum(src, back, w if weighted else None, held)
+
+    text = _compile(fn, _spec((rows, d), "bfloat16", one_chip), _spec((k, tokens), "int32", one_chip),
+                    _spec((tokens, k), "float32", one_chip), _spec((), "int32", one_chip)).as_text()
+    assert re.search(rf"%pair_sum_rows\.\d+ = u32\[{rows},1,{d // 2}\]", text)
+    assert re.search(rf"%pair_sum\.\d+ = bf16\[{tokens},{d}\]", text)
 
 
 # ------------------------------ the latent trunk's whole step, one chip's share

@@ -18,6 +18,7 @@ from chipbench import reference_latent_trunk as ref  # noqa: E402
 from fedrec_tpu.models import latent_trunk, sparse_trunk  # noqa: E402
 from fedrec_tpu.models.bert import TextEncoder  # noqa: E402
 from fedrec_tpu.models.latent_trunk import LatentTrunkConfig  # noqa: E402
+from test_sparse_trunk import conditionals  # noqa: E402
 
 # one leading dense layer and two routed ones; a context of 16 positions
 # "trained on", so that YaRN's ramp falls inside the four frequencies
@@ -168,7 +169,7 @@ def test_one_expert_of_eight_runs_through_the_small_buffer(monkeypatch):
     te, params = encoder_and_params(cfg)
     toks, t = tokens(), trunk_dict(cfg)
     apply = lambda p, x: te.apply({"params": p}, x, mutable=["routing"])  # noqa: E731
-    assert "cond" in str(jax.make_jaxpr(apply)(params, toks))
+    assert conditionals(jax.make_jaxpr(apply)(params, toks))
     with jax.default_matmul_precision("highest"):
         want, g_want = vecs_and_grad_of(lambda p, x: ref.encode_news(p, x, t))(params, toks)
         got, g_got = vecs_and_grad_of(lambda p, x: te.apply({"params": p}, x))(params, toks)
