@@ -56,11 +56,16 @@ counts those layers). The shared expert is computed whole. The embedding
 holds ``vocab_held`` rows from ``vocab_first``; an id outside the slice
 embeds to zero.
 
+The mixer is ``ops/residual_mix.py``: Pallas kernels that read the state
+once to read it into ``F`` (norm, maps, Sinkhorn and ``x_in`` together) and
+once to write ``F``'s output back, and once each way back.
+
 Rematerialisation (``remat=True``) is per sublayer, mixer included: the
 backward pass keeps each sublayer's input ``X`` and runs its forward once
 more, except the held experts', whose output is kept beside ``X``
-(``HeldExperts`` runs its own forward again beside its transposes). Nothing
-else is rematerialised or chunked beyond ``HeldExperts``' own chunks.
+(``HeldExperts`` runs its own forward again beside its transposes); the
+mixer's maps come out of the pass that reads ``x_in`` again. Nothing else is
+rematerialised or chunked beyond ``HeldExperts``' own chunks.
 """
 
 from __future__ import annotations
@@ -76,6 +81,7 @@ from jax import lax
 from fedrec_tpu.models.sparse_trunk import (
     HELD_EXPERTS_OUTPUT, HeldExperts, RMSNorm, attention_allowed,
 )
+from fedrec_tpu.ops import residual_mix
 
 HI = lax.Precision.HIGHEST
 
@@ -326,20 +332,31 @@ class _RoutedFFN(nn.Module):
 
 
 # ---------------------------------------------------------------- the mixer
-def _sinkhorn_step(m: jnp.ndarray, eps: float) -> jnp.ndarray:
-    """One Sinkhorn iteration on (n, n, T): the columns, then the rows."""
-    m = m / (jnp.sum(m, axis=0, keepdims=True) + eps)
-    return m / (jnp.sum(m, axis=1, keepdims=True) + eps)
+def mix_spec(c: LatentTrunkConfig) -> residual_mix.MixSpec:
+    """The mixer's fixed numbers, as the configuration gives them."""
+    return residual_mix.MixSpec(c.n_streams, float(c.rms_norm_eps), c.sinkhorn_iters,
+                                float(c.hc_eps), float(c.res_clamp[0]), float(c.res_clamp[1]))
+
+
+class _NormScale(nn.Module):
+    """The learned scale of the mixer's norm over a token's n*d vector; the
+    norm itself runs inside the mixer's kernels."""
+
+    @nn.compact
+    def __call__(self, width: int) -> jnp.ndarray:
+        return self.param("scale", nn.initializers.ones, (width,))
 
 
 class _Mixer(nn.Module):
     """The three maps of one sublayer's residual mixing, from the token's own
-    state (T, n*d): ``pre`` (n, T) reads the streams into the sublayer, ``post``
-    (n, T) writes its output back, ``res`` (n, n, T) mixes the streams, made
-    doubly stochastic by Sinkhorn's iterations. Tokens lie along the last
-    axis so that the n x n arithmetic runs over whole lanes. Float32
-    throughout. Also returns how far from doubly stochastic ``res`` still
-    is: the largest |sum - 1| of a row or column over the tokens."""
+    state (T, n*d): ``pre`` reads the streams into the sublayer, ``post``
+    writes its output back, ``res`` mixes the streams, made doubly
+    stochastic by Sinkhorn's iterations; float32 throughout
+    (``ops/residual_mix.py``: the equations and the kernels). Returns the
+    sublayer's float32 input ``x_in`` (T, d), the per-token maps (T, 128),
+    the state for ``residual_mix.write_back`` and how far from doubly
+    stochastic ``res`` still is: the largest |sum - 1| of a row or column
+    over the tokens."""
 
     cfg: LatentTrunkConfig
 
@@ -347,33 +364,21 @@ class _Mixer(nn.Module):
     def __call__(self, x: jnp.ndarray) -> tuple[jnp.ndarray, ...]:
         c = self.cfg
         n, width = c.n_streams, c.n_streams * c.dim
-        z = RMSNorm(c.rms_norm_eps, jnp.float32, name="norm")(x)
         init = nn.initializers.variance_scaling(1.0, "fan_in", "normal")
-        proj = jnp.concatenate([
-            self.param("proj_pre", init, (width, n)),
-            self.param("proj_post", init, (width, n)),
-            self.param("proj_res", init, (width, n * n)),
-        ], axis=-1)
-        alpha = self.param("alpha", nn.initializers.constant(0.01), (3,))
-        bias_pre = self.param("bias_pre", nn.initializers.zeros, (n,))
-        bias_post = self.param("bias_post", nn.initializers.zeros, (n,))
-        # at rest the streams keep to themselves: Sinkhorn of exp(4 I) is
-        # near the identity
-        bias_res = self.param(
-            "bias_res", lambda _, shape: 4.0 * jnp.eye(*shape), (n, n)
-        )
-        raw = jnp.einsum("tc,cm->mt", z, proj, precision=HI)        # (n + n + n*n, T)
-        pre = jax.nn.sigmoid(alpha[0] * raw[:n] + bias_pre[:, None])
-        post = 2.0 * jax.nn.sigmoid(alpha[1] * raw[n: 2 * n] + bias_post[:, None])
-        res = alpha[2] * raw[2 * n:].reshape(n, n, -1) + bias_res[:, :, None]
-        m = jnp.exp(jnp.clip(res, *c.res_clamp))
-        m = lax.fori_loop(0, c.sinkhorn_iters, lambda _, m: _sinkhorn_step(m, c.hc_eps), m)
-        off = lax.stop_gradient(m)
-        err = jnp.maximum(
-            jnp.max(jnp.abs(jnp.sum(off, axis=0) - 1.0)),
-            jnp.max(jnp.abs(jnp.sum(off, axis=1) - 1.0)),
-        )
-        return pre, post, m, err
+        params = {
+            "norm": {"scale": _NormScale(name="norm")(width)},
+            "proj_pre": self.param("proj_pre", init, (width, n)),
+            "proj_post": self.param("proj_post", init, (width, n)),
+            "proj_res": self.param("proj_res", init, (width, n * n)),
+            "alpha": self.param("alpha", nn.initializers.constant(0.01), (3,)),
+            "bias_pre": self.param("bias_pre", nn.initializers.zeros, (n,)),
+            "bias_post": self.param("bias_post", nn.initializers.zeros, (n,)),
+            # at rest the streams keep to themselves: Sinkhorn of exp(4 I)
+            # is near the identity
+            "bias_res": self.param("bias_res", lambda _, shape: 4.0 * jnp.eye(*shape), (n, n)),
+        }
+        x_in, maps, x = residual_mix.read_in(x, params, mix_spec(c))
+        return x_in, maps, x, jnp.max(residual_mix.unpacked_maps(maps, n)[3])
 
 
 class _Sublayer(nn.Module):
@@ -393,17 +398,14 @@ class _Sublayer(nn.Module):
     @nn.compact
     def __call__(self, x: jnp.ndarray, mask: jnp.ndarray) -> tuple[jnp.ndarray, ...]:
         c = self.cfg
-        n, d = c.n_streams, c.dim
+        d = c.dim
         norm = lambda name: RMSNorm(c.rms_norm_eps, self.dtype, name=name)  # noqa: E731
-        per_token = lambda w: w[:, None]                         # noqa: E731 - (T,) -> (T, 1)
         with jax.named_scope("residual_mix"):
-            pre, post, m, err = _Mixer(c, name="mixer")(x)
-            streams = [x[:, j * d: (j + 1) * d].astype(jnp.float32) for j in range(n)]
-            # float32 into the sublayer's norm: rounded to bfloat16 first,
-            # x_in is no longer parallel to the streams where they are all
-            # alike (the first sublayer), and the norm's backward leaves
+            # x_in in float32 into the sublayer's norm: rounded to bfloat16
+            # first, it is no longer parallel to the streams where they are
+            # all alike (the first sublayer), and the norm's backward leaves
             # `pre` a gradient of pure rounding where the true one is nought
-            x_in = sum(per_token(pre[j]) * streams[j] for j in range(n))
+            x_in, maps, x, err = _Mixer(c, name="mixer")(x)
         extra = ()
         if self.kind == "attention":
             with jax.named_scope("latent_attention"):
@@ -417,12 +419,7 @@ class _Sublayer(nn.Module):
                 u = norm("norm")(x_in)
             out, *extra = _RoutedFFN(c, self.dtype, name="ffn")(u)
         with jax.named_scope("residual_mix"):
-            out32 = out.astype(jnp.float32)
-            mixed = jnp.concatenate([
-                sum(per_token(m[i, j]) * streams[j] for j in range(n))
-                + per_token(post[i]) * out32
-                for i in range(n)
-            ], axis=-1).astype(self.dtype)
+            mixed = residual_mix.write_back(x, out, maps)
         return (mixed, err, *extra)
 
 

@@ -13,11 +13,15 @@ first-class TPU kernels:
   * ``pair_sum`` — the expert layer's sum over a token's (token, choice) pairs
     on held experts: reads only those rows, writes one row a token (the
     combine's forward and the route's backward in every routed trunk).
+  * ``residual_mix`` — the latent trunk's residual mixer: one pass over a
+    token block's streams reads them into a sublayer (norm, maps, Sinkhorn),
+    one writes its output back, one each way back.
 
 All run in Pallas interpret mode on CPU (tests) and compiled on TPU. The
 first two are routed from the Flax modules via ``ModelConfig.use_pallas``;
-``pair_sum`` (its own module, ``ops/pair_sum.py``) runs wherever a routed
-trunk does, with no option.
+``pair_sum`` and ``residual_mix`` (their own modules, ``ops/pair_sum.py``,
+``ops/residual_mix.py``) run wherever a routed or a latent trunk does, with
+no option.
 """
 
 from fedrec_tpu.ops.attention_kernels import additive_pool, flash_attention

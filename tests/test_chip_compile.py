@@ -510,6 +510,50 @@ def test_pair_sum_compiles_at_the_cells_widths(topo, one_chip, compiled_kernels,
     assert re.search(rf"%pair_sum\.\d+ = bf16\[{tokens},{d}\]", text)
 
 
+# ------------------------------- the latent trunk's mixer, at the cell's shapes
+# a v5e core's VMEM (``jax.experimental.pallas.tpu.get_tpu_info``)
+V5E_VMEM_BYTES = 128 * 1024 * 1024
+
+
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+def test_residual_mix_compiles_at_the_cells_widths(topo, one_chip, compiled_kernels, direction):
+    """``xing29b-ep8.b2``'s mixer alone (5,500 tokens, four streams of 3,584,
+    a bfloat16 state): the read-in and the write-back forward, the write-back's
+    and the read-in's derivatives backward, each a Mosaic kernel that fits the
+    VMEM it asks for (Mosaic refuses one that does not), each under the scope
+    ``residual_mix`` that ``residual_mix_device_ms`` reads, though called
+    under none here."""
+    from fedrec_tpu.ops import residual_mix
+
+    tokens, n, d = 5500, 4, 3584
+    width = n * d
+    spec = residual_mix.MixSpec(n, 1e-6, 20, 1e-6, -30.0, 30.0)
+    params = {"norm": {"scale": (width,)}, "proj_pre": (width, n), "proj_post": (width, n),
+              "proj_res": (width, n * n), "alpha": (3,), "bias_pre": (n,), "bias_post": (n,),
+              "bias_res": (n, n)}
+    params = jax.tree_util.tree_map(lambda s: _spec(s, "float32", one_chip), params,
+                                    is_leaf=lambda s: isinstance(s, tuple))
+    x, out = _spec((tokens, width), "bfloat16", one_chip), _spec((tokens, d), "bfloat16", one_chip)
+
+    def forward(x, out, p):
+        x_in, maps, x = residual_mix.read_in(x, p, spec)
+        return residual_mix.write_back(x, out, maps), x_in
+
+    def loss(x, out, p):
+        x_next, x_in = forward(x, out, p)
+        return jnp.sum(x_next.astype(jnp.float32)) + jnp.sum(x_in)
+
+    fn = forward if direction == "forward" else jax.grad(loss, argnums=(0, 1, 2))
+    text = _compile(fn, x, out, params).as_text()
+    assert residual_mix.VMEM_LIMIT <= V5E_VMEM_BYTES
+    calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    names = sorted(re.search(r"%(residual_mix\w*)\.\d+ = ", line).group(1) for line in calls)
+    assert names == (["residual_mix_in", "residual_mix_out"] if direction == "forward"
+                     else ["residual_mix_in", "residual_mix_in_bwd", "residual_mix_out_bwd"])
+    for line in calls:
+        assert re.search(r'op_name="[^"]*/residual_mix/', line), line[:200]
+
+
 # ------------------------------ the latent trunk's whole step, one chip's share
 # what the runtime leaves a program on one v5e chip (``bytes_limit`` of
 # ``device.memory_stats()``: PERF.md section 4)

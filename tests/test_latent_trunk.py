@@ -18,6 +18,7 @@ from chipbench import reference_latent_trunk as ref  # noqa: E402
 from fedrec_tpu.models import latent_trunk, sparse_trunk  # noqa: E402
 from fedrec_tpu.models.bert import TextEncoder  # noqa: E402
 from fedrec_tpu.models.latent_trunk import LatentTrunkConfig  # noqa: E402
+from fedrec_tpu.ops import residual_mix  # noqa: E402
 from test_sparse_trunk import conditionals  # noqa: E402
 
 # one leading dense layer and two routed ones; a context of 16 positions
@@ -125,27 +126,77 @@ def test_forward_loss_and_gradients_match_the_reference_in_float32(pad, streams)
     assert not np.any(bias(g_got)) and not np.any(bias(g_want))   # the choice is not differentiated
 
 
-def test_bfloat16_stays_near_the_reference():
+def test_bfloat16_stays_near_the_reference(monkeypatch):
     """bfloat16 compute rounds every activation and the four-stream state to
     8 bits of mantissa at every sublayer, and with 48 tokens a near-tie in a
     router that moves one token to another expert moves its title's vector:
-    on seeds 0-2 the vectors' worst element reads 5-14% of the largest, the
-    median element 0.3-0.7%, the vectors as a whole 3-8%. A float32 program
-    reads 1e-6; the chip's cell holds bfloat16 to its limits at 5,500
-    tokens, where one token is nothing."""
+    on seeds 0-3 the vectors' worst element reads 5-12% of the largest, the
+    median element 0.4-0.7%. A float32 program reads 1e-6; the chip's cell
+    holds bfloat16 to its limits at 5,500 tokens, where one token is
+    nothing. A moved token moves every gradient behind it (seed 0: token 45
+    of the first routed layer takes expert 2 for 3, 0.0043 under the
+    reference's own second choice; the median leaf then reads 31%), so the
+    gradients are held to the reference routed as the program routed, and
+    only where each choice of the program lies within 1e-2 of the
+    reference's own: on seeds 0-3 the worst leaf then reads 9-21%, the
+    median 4.4-5.4%. (On seeds 0, 1 and 3 the program's lowest choice lies
+    at most 0.006 under the reference's; on seed 2 one token of the second
+    routed layer lies 0.042 under, where bfloat16 has moved that layer's
+    scores by up to 0.041.)"""
     cfg = held()
     te, params = encoder_and_params(cfg, "bfloat16")
     toks, t = tokens(), trunk_dict(cfg)
-    want, g_want = vecs_and_grad_of(lambda p, x: ref.encode_news(p, x, t))(params, toks)
+    # the program's choices, routed layer by routed layer (the rematerialised
+    # forward repeats them)
+    chosen, route = {}, latent_trunk.route_sigmoid
+
+    def recorded(u, *rest):
+        idx, weights = route(u, *rest)
+        layer = len(chosen)
+        chosen[layer] = None
+        jax.debug.callback(lambda i: chosen.__setitem__(layer, np.asarray(i)), idx)
+        return idx, weights
+
+    monkeypatch.setattr(latent_trunk, "route_sigmoid", recorded)
     got, g_got = vecs_and_grad_of(lambda p, x: te.apply({"params": p}, x))(params, toks)
+    assert len(chosen) == cfg.n_routed_layers
+    own_routing = ref.routing
+
+    def reference(p, x, follow, program_chose):
+        """The reference choosing its own experts, or (``follow``) the
+        program's, one compiled program for both; with the vectors, how far
+        under the reference's own k-th biased score each routed layer's
+        lowest program choice lies (0 where the two choose alike)."""
+        layers, below = iter(program_chose), []
+
+        def routing(p, u, t, fault=None):
+            idx = jnp.where(follow, next(layers), own_routing(p, u, t)[0])
+            scores = jax.nn.sigmoid(jnp.einsum("td,de->te", u, p["router"], precision=ref.HI))
+            biased = scores + p["router_bias"]
+            kth = jax.lax.top_k(biased, t["experts_per_token"])[0][:, -1]
+            below.append(jnp.max(kth - jnp.min(jnp.take_along_axis(biased, idx, axis=-1), axis=-1)))
+            picked = jnp.take_along_axis(scores, idx, axis=-1)
+            return idx, t["routed_scale"] * picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-20)
+
+        monkeypatch.setattr(ref, "routing", routing)
+        return ref.encode_news(p, x, t), jnp.stack(below)
+
+    program_chose = [chosen[k] for k in sorted(chosen)]
+    both = jax.jit(lambda p, x, follow, chose: jax.grad(
+        lambda p: (lambda v, b: (jnp.sum(v ** 2), (v, b)))(*reference(p, x, follow, chose)),
+        has_aux=True)(p)[::-1])
+    (want, _), _ = both(params, toks, False, program_chose)
+    (_, below), g_want = both(params, toks, True, program_chose)
+    # the reference follows the program only where the program's choice is
+    # a near-tie in the reference's own scores
+    assert float(jnp.max(below)) < 1e-2
     off = jnp.abs(got.astype(jnp.float32) - want)
     largest = float(jnp.max(jnp.abs(want)))
     assert float(jnp.max(off)) < 0.25 * largest and float(jnp.median(off)) < 0.02 * largest
     assert float(jnp.linalg.norm(off)) < 0.15 * float(jnp.linalg.norm(want))
     assert all(x.dtype == jnp.float32 for x in jax.tree_util.tree_leaves(g_got))
     gaps = rel_gaps(g_got, g_want)
-    # (a moved token moves every gradient behind it: the median leaf reads 12%)
-    assert max(gaps) < 0.6 and float(np.median(gaps)) < 0.2
+    assert max(gaps) < 0.35 and float(np.median(gaps)) < 0.1
 
 
 @pytest.mark.parametrize("fault", [f for f in ref.FAULTS if f])
@@ -216,14 +267,15 @@ def test_the_mixing_matrix_is_doubly_stochastic_after_twenty_iterations_not_afte
         p = seeded(mixer.init(jax.random.PRNGKey(0), x)["params"])
         p = {**p, "alpha": jnp.asarray([1.0, 1.0, 0.3]), "bias_res": 0.3 * p["bias_res"],
              "proj_res": mixer.init(jax.random.PRNGKey(0), x)["params"]["proj_res"]}
-        pre, post, m, err = mixer.apply({"params": p}, x)
-        assert m.shape == (streams, streams, 40) and pre.shape == post.shape == (streams, 40)
+        _, maps, _, err = mixer.apply({"params": p}, x)
+        pre, post, m, _ = residual_mix.unpacked_maps(maps, streams)
+        assert m.shape == (40, streams, streams) and pre.shape == post.shape == (40, streams)
         assert float(jnp.min(m)) > 0 and 0 < float(jnp.min(pre)) and float(jnp.max(post)) < 2
-        cols, rows = jnp.sum(m, axis=0), jnp.sum(m, axis=1)
+        cols, rows = jnp.sum(m, axis=1), jnp.sum(m, axis=2)
         off = float(jnp.maximum(jnp.max(jnp.abs(cols - 1)), jnp.max(jnp.abs(rows - 1))))
         assert float(err) == pytest.approx(off)
         want = ref.mixer_maps(p, x.reshape(40, streams, cfg.dim), trunk_dict(cfg))[2]
-        np.testing.assert_allclose(jnp.moveaxis(m, -1, 0), want, rtol=1e-5, atol=1e-6)
+        np.testing.assert_allclose(m, want, rtol=1e-5, atol=1e-6)
         return off
 
     assert sums_off(20) < 1e-5
